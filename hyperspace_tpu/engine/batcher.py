@@ -620,7 +620,11 @@ class QueryBatcher:
             if key0 in self._warmed:
                 return
             self._warmed.add(key0)
-        dtypes = [batch.column(c).data.dtype for c in sig.columns]
+        # dtypes of the VALUES the predicate reads (a carried float64
+        # column decodes to float64), without decoding anything here
+        dtypes = [np.dtype(np.float64) if col.carries_bits
+                  else col.raw.dtype
+                  for col in map(batch.column, sig.columns)]
         flags = [batch.column(c).validity is not None
                  for c in sig.columns]
         self._warm(sig, batch.num_rows, dtypes, flags, conf)

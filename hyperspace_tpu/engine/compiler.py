@@ -225,6 +225,13 @@ class ExpressionCompiler:
         """Evaluate a value expression to a full DeviceColumn of the given
         logical dtype (the projection entry point)."""
         from hyperspace_tpu.io.columnar import HOST_NP_DTYPES
+        bare = e
+        while isinstance(bare, E.Alias):
+            bare = bare.child
+        if isinstance(bare, E.Column):
+            col = self.batch.column(bare.name)
+            if col.dtype == out_dtype:
+                return col  # renamed, not computed: the column moves as is
         s = self.string_column(e)
         if s is not None:
             if out_dtype != "string":

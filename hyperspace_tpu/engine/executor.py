@@ -9,7 +9,7 @@ import uuid
 
 import hyperspace_tpu.engine  # noqa: F401  (x64 config)
 from hyperspace_tpu.engine.physical import PhysicalNode, plan_physical
-from hyperspace_tpu.io.columnar import ColumnBatch
+from hyperspace_tpu.io.columnar import ColumnBatch, fetched
 from hyperspace_tpu.plan.nodes import LogicalPlan
 
 # Profiler capture naming: fast back-to-back queries can share a
@@ -104,7 +104,7 @@ def _resolve_scalar_subqueries(plan: LogicalPlan, conf) -> None:
                 np.asarray(col.validity)[0]):
             sub.resolve(None)
             continue
-        raw = np.asarray(col.data)[0]
+        raw = fetched(np.asarray(col.raw), col.dtype)[0]
         if col.is_string:
             sub.resolve(str(col.dictionary[int(raw)]))
         elif field.dtype == "bool":
@@ -147,7 +147,7 @@ def execute_plan(plan: LogicalPlan,
         # validity masks and dictionary hashes included, or their
         # compute/transfers land after the capture closes.
         for col in out.columns.values():
-            for arr in (col.data, col.validity,
+            for arr in (col.raw, col.validity,
                         *(col.dict_hashes or ())):
                 if hasattr(arr, "block_until_ready"):
                     arr.block_until_ready()

@@ -383,7 +383,7 @@ def _batch_nbytes(batch) -> int:
     validity; dictionaries are shared and small)."""
     total = 0
     for col in batch.columns.values():
-        total += getattr(col.data, "nbytes", 0)
+        total += getattr(col.raw, "nbytes", 0)
         if col.validity is not None:
             total += getattr(col.validity, "nbytes", 0)
     return total

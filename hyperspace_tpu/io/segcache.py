@@ -248,7 +248,7 @@ def _batch_nbytes(batch) -> int:
     halves of string dictionary hashes)."""
     total = 0
     for col in batch.columns.values():
-        total += int(getattr(col.data, "nbytes", 0))
+        total += int(getattr(col.raw, "nbytes", 0))
         if col.validity is not None:
             total += int(getattr(col.validity, "nbytes", 0))
         if col.dict_hashes is not None:

@@ -27,18 +27,36 @@ _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
 
 
+_SRC_PATH = os.path.join(_HERE, "hyperspace_host.cpp")
+
+
 def _build() -> bool:
-    src = os.path.join(_HERE, "hyperspace_host.cpp")
+    """Compile the library next to its source. The output lands under a
+    per-process temp name and is renamed into place, so concurrent
+    builders (test workers) never load a half-written file."""
+    tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
     try:
         subprocess.run(
             ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-pthread",
-             "-o", _SO_PATH, src],
+             "-o", tmp, _SRC_PATH],
             check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO_PATH)
         return True
-    except Exception as exc:
+    except (OSError, subprocess.SubprocessError) as exc:
         logger.warning("Native host library build failed (falling back to "
                        "Python): %s", exc)
+        if os.path.exists(tmp):
+            os.remove(tmp)
         return False
+
+
+def _stale() -> bool:
+    """No library yet, or one older than the committed source: what runs
+    is built from what git commits, never a leftover git-ignored .so."""
+    try:
+        return os.path.getmtime(_SO_PATH) < os.path.getmtime(_SRC_PATH)
+    except OSError:
+        return True
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -47,7 +65,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
         if _lib is not None or _load_attempted:
             return _lib
         _load_attempted = True
-        if not os.path.exists(_SO_PATH) and not _build():
+        if _stale() and not _build():
             return None
         try:
             lib = ctypes.CDLL(_SO_PATH)

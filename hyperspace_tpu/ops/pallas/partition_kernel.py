@@ -10,8 +10,8 @@ tile mixes its key lanes (the same fmix32/hash-combine chain as
 histogram entirely in registers/VMEM before a single [B] store.
 
 Like `hash_kernel.py`, chunking uses `lax.map` over fixed tiles rather
-than a Pallas grid (grids fail to legalize on the remote-compile
-toolchain targeted here); the kernel compiles once and loops.
+than a Pallas grid: the kernel compiles once and loops (grid form
+unmeasured on the chip).
 """
 
 from __future__ import annotations

@@ -24,7 +24,8 @@ import numpy as np
 
 import hyperspace_tpu._jax_config  # noqa: F401
 from hyperspace_tpu.exceptions import HyperspaceException
-from hyperspace_tpu.io.columnar import ColumnBatch, DeviceColumn
+from hyperspace_tpu.io.columnar import (ColumnBatch, DeviceColumn,
+                                        fetched)
 from hyperspace_tpu.parallel.mesh import total_shards
 from hyperspace_tpu.parallel.scan import shard_batch
 from hyperspace_tpu.plan.nodes import AggSpec
@@ -258,7 +259,7 @@ def _combine_partials(batch, out, group_columns, aggregates, specs_meta,
         src = rep.column(name)
         f = batch.schema.field(name)
         columns[f.name] = DeviceColumn(
-            data=np.asarray(src.data), dtype=src.dtype,
+            data=fetched(np.asarray(src.raw), src.dtype), dtype=src.dtype,
             validity=(np.asarray(src.validity)
                       if src.validity is not None else None),
             dictionary=src.dictionary, dict_hashes=src.dict_hashes)

@@ -234,7 +234,9 @@ def distributed_build(batch: ColumnBatch, key_columns: Sequence[str],
     reg = telemetry.get_registry()
     span_ts = tracer.now_us() if tracer is not None else 0.0
 
-    tree, aux = batch_to_tree(batch)
+    # Payload rides the exchange in its carried form (float64 as int64
+    # bits: exact through the all_to_all); only the keys are computed on.
+    tree, aux = batch_to_tree(batch, computes_on=key_names)
     # Host-resident sources build the padded tree in numpy and place
     # every leaf with the row sharding DIRECTLY (pipelined transfer
     # engine, all shards' puts issued before the first block) — each

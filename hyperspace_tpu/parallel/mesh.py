@@ -48,19 +48,12 @@ DCN_AXIS = "dcn"
 
 def compat_shard_map(body, mesh, in_specs, out_specs,
                      check_vma: bool = False):
-    """`jax.shard_map` across jax versions: newer jax exports it
-    top-level with `check_vma`; older jax ships
-    `jax.experimental.shard_map` with the same semantics under
-    `check_rep`. ONE shim here so every mesh kernel stays
-    version-agnostic."""
-    try:
-        from jax import shard_map as sm
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+    """`jax.shard_map`, constructed HERE only: the one seam every mesh
+    kernel's layout goes through (the coverage lint bans raw
+    construction elsewhere)."""
+    from jax import shard_map
+    return shard_map(body, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(num_devices: Optional[int] = None,

@@ -57,13 +57,10 @@ def shard_batch(batch: ColumnBatch, mesh):
     with telemetry.span("mesh:place", "mesh", rows=n, shards=n_shards):
         columns: Dict[str, DeviceColumn] = {}
         for name, col in batch.columns.items():
-            columns[name] = DeviceColumn(
-                data=place(col.data, 0),
-                dtype=col.dtype,
-                validity=(place(col.validity, False)
-                          if col.validity is not None else None),
-                dictionary=col.dictionary,
-                dict_hashes=col.dict_hashes)
+            columns[name] = col.with_raw(
+                place(col.carry, 0),
+                (place(col.validity, False)
+                 if col.validity is not None else None))
         row_valid = place(np.ones(n, dtype=bool), False)
     return ColumnBatch(batch.schema, columns), row_valid
 

@@ -337,35 +337,34 @@ def shard_bucket_ordered(batch: ColumnBatch, lengths, mesh) -> ShardedBatch:
     columns = {}
     if batch.is_host:
         for name, col in batch.columns.items():
-            data = np.zeros((n_shards * C,) + col.data.shape[1:],
-                            dtype=col.data.dtype)
-            data[valid] = col.data
+            src = col.carry
+            data = np.zeros((n_shards * C,) + src.shape[1:],
+                            dtype=src.dtype)
+            data[valid] = src
             v = None
             if col.validity is not None:
                 v = np.zeros(n_shards * C, dtype=bool)
                 v[valid] = col.validity
                 v = engine.put(v, device=sharding)
-            columns[name] = DeviceColumn(
-                data=engine.put(data, device=sharding), dtype=col.dtype,
-                validity=v, dictionary=col.dictionary,
-                dict_hashes=col.dict_hashes)
+            columns[name] = col.with_raw(
+                engine.put(data, device=sharding), v)
         row_valid = engine.put(valid, device=sharding)
     else:
         idx_dev = engine.put(np.minimum(idx, max(n - 1, 0)),
                              device=sharding)
         row_valid = engine.put(valid, device=sharding)
         for name, col in batch.columns.items():
+            src = jnp.asarray(col.carry)
             data = jnp.where(
-                _expand_mask(row_valid, col.data.ndim),
-                jnp.take(jnp.asarray(col.data), idx_dev, axis=0), 0)
+                _expand_mask(row_valid, src.ndim),
+                jnp.take(src, idx_dev, axis=0), 0)
             v = None
             if col.validity is not None:
                 v = jnp.take(jnp.asarray(col.validity), idx_dev) & row_valid
-            columns[name] = DeviceColumn(
-                data=engine.put(data, device=sharding), dtype=col.dtype,
-                validity=(engine.put(v, device=sharding)
-                          if v is not None else None),
-                dictionary=col.dictionary, dict_hashes=col.dict_hashes)
+            columns[name] = col.with_raw(
+                engine.put(data, device=sharding),
+                (engine.put(v, device=sharding)
+                 if v is not None else None))
     flat = ColumnBatch(batch.schema, columns)
     return ShardedBatch(flat, row_valid, mesh, C, len(lengths),
                         lengths=lengths)
@@ -699,10 +698,10 @@ def _fill_device_shard(files: List[str], cols, schema, rows: int, C: int,
         # Empty range: all-padding shard, created device-locally.
         import jax.numpy as jnp
 
-        from hyperspace_tpu.io.columnar import HOST_NP_DTYPES
+        from hyperspace_tpu.io.columnar import carried_np_dtype
         cols_out = {}
         for f in out_schema.fields:
-            dt = HOST_NP_DTYPES[f.dtype]
+            dt = carried_np_dtype(f.dtype)
             cols_out[f.name] = {
                 "data": _on_device(device, partial(jnp.zeros, C, dt)),
                 "validity": None}
@@ -725,8 +724,9 @@ def _fill_device_shard(files: List[str], cols, schema, rows: int, C: int,
     jobs = []
     for f in out_schema.fields:
         col = host.columns[f.name]
-        data = np.zeros((C,) + col.data.shape[1:], dtype=col.data.dtype)
-        data[:rows] = col.data
+        src = col.carry
+        data = np.zeros((C,) + src.shape[1:], dtype=src.dtype)
+        data[:rows] = src
         entry = {"data": data}
         if col.validity is not None:
             v = np.zeros(C, dtype=bool)
@@ -1734,7 +1734,8 @@ def repartition_sharded(batch: ColumnBatch, key_columns: Sequence[str],
     n = batch.num_rows
     local = -(-n // n_shards)
     padded = local * n_shards
-    tree, aux = batch_to_tree(batch)
+    key_names = tuple(batch.schema.field(c).name for c in key_columns)
+    tree, aux = batch_to_tree(batch, computes_on=key_names)
 
     def pad(a):
         return jnp.pad(jnp.asarray(a),
@@ -1759,7 +1760,6 @@ def repartition_sharded(batch: ColumnBatch, key_columns: Sequence[str],
     in_tree = jax.tree_util.tree_map(
         lambda a: engine.put(a, device=sharding), in_tree)
 
-    key_names = tuple(batch.schema.field(c).name for c in key_columns)
     reg = telemetry.get_registry()
     factor = capacity_factor
     while True:
@@ -1889,7 +1889,7 @@ def sharded_filter(sh: ShardedBatch, expression) -> ColumnBatch:
 
     reg = telemetry.get_registry()
     count_string_predicate_lookups(expression, sh.batch)
-    tree, aux = batch_to_tree(sh.batch)
+    tree, aux = batch_to_tree(sh.batch, computes_on=())
     schema = sh.batch.schema
 
     def step(t, valid):

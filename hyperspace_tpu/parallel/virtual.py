@@ -3,8 +3,8 @@
 Multi-chip behavior is validated the way the reference validates
 distribution — a real local multi-way runtime in one process (`local[4]`
 SparkSession, reference `SparkInvolvedSuite.scala:29-35`): here, an
-n-device virtual CPU mesh. Used by `tests/conftest.py` and the driver's
-`__graft_entry__.dryrun_multichip` gate.
+n-device virtual CPU mesh. Used by `tests/conftest.py` and
+`__graft_entry__.dryrun_multichip`.
 """
 
 from __future__ import annotations
@@ -13,48 +13,40 @@ from __future__ import annotations
 def ensure_devices(n_devices: int) -> None:
     """Make `jax.devices()` report at least ``n_devices`` devices.
 
-    Real hardware with enough chips is used as-is. Otherwise the live
-    backends are dropped and CPU is re-initialized with a forced device
-    count. ``clear_backends`` MUST precede the config updates — jax
-    refuses ``jax_num_cpu_devices`` changes while backends are live.
+    Enough live devices are used as-is. Otherwise, ON THE CPU BACKEND
+    ONLY, the live backends are dropped and CPU is re-initialized with a
+    forced device count. ``clear_backends`` MUST precede the config
+    update — jax refuses ``jax_num_cpu_devices`` changes while backends
+    are live.
 
-    PROCESS-DESTRUCTIVE in the fallback path: it pins jax_platforms=cpu
-    for the rest of the process and invalidates every live jax array and
-    compiled computation. Call it before any device work (tests do it at
-    conftest import; the dryrun gate does it first thing). On jax
-    versions without the ``jax_num_cpu_devices`` config (< 0.5) the
-    device count is forced through ``XLA_FLAGS`` instead — that path
-    DOES write ``os.environ`` (inherited by subprocesses), the flag XLA
-    reads at CPU-client init.
+    On an accelerator with too few chips this RAISES: swapping a live
+    TPU for virtual CPU devices would let a chip run measure the CPU
+    under the chip's name. A caller that wants the virtual mesh says so
+    before jax initializes (``JAX_PLATFORMS=cpu``).
+
+    PROCESS-DESTRUCTIVE in the re-initializing path: it invalidates
+    every live jax array and compiled computation. Call it before any
+    device work (tests do it at conftest import; the dryrun gate does it
+    first thing).
     """
     import jax
 
-    if not hasattr(jax.config, "jax_num_cpu_devices"):
-        # Older jax: the only knob is the XLA host-platform flag, and
-        # XLA parses XLA_FLAGS ONCE per process — it must be in the
-        # environment before the first backend init (clear_backends +
-        # re-init does NOT re-read it). ensure_devices is documented to
-        # run before any device work, so set it ahead of our own probe.
-        import os
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags
-                + f" --xla_force_host_platform_device_count={n_devices}"
-            ).strip()
-
-    try:
-        if len(jax.devices()) >= n_devices:
-            return
-    except RuntimeError:
-        pass
+    have = len(jax.devices())
+    if have >= n_devices:
+        return
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"ensure_devices({n_devices}): the live backend is {backend!r} "
+            f"with {have} device(s); refusing to replace it with virtual "
+            f"CPU devices. Set JAX_PLATFORMS=cpu before starting to run on "
+            f"a virtual mesh.")
 
     import jax.extend.backend
 
     jax.extend.backend.clear_backends()
     jax.config.update("jax_platforms", "cpu")
-    if hasattr(jax.config, "jax_num_cpu_devices"):
-        jax.config.update("jax_num_cpu_devices", n_devices)
+    jax.config.update("jax_num_cpu_devices", n_devices)
     if len(jax.devices()) < n_devices:
         raise RuntimeError(
             f"virtual mesh bootstrap failed: have {len(jax.devices())} "

@@ -70,21 +70,17 @@ _sig_lock = threading.Lock()
 
 _tls = threading.local()
 
-# Warm-start compilation: the persistent-cache dir currently wired into
-# jax (None = not configured). One process-wide setting — jax's
-# compilation cache is global, so co-resident sessions share it (same
-# caveat as the transfer-engine knobs).
-_persistent_dir: Optional[str] = None
 _persistent_lock = threading.Lock()
 
 
 def persistent_cache_dir() -> Optional[str]:
-    """The configured persistent compilation cache dir, or None."""
-    return _persistent_dir
+    """The persistent compilation cache dir jax is using, or None."""
+    import jax
+    return jax.config.jax_compilation_cache_dir or None
 
 
-def configure_persistent_cache(conf) -> bool:
-    """Wire JAX's persistent compilation cache behind
+def configure_persistent_cache(conf) -> None:
+    """Point JAX's persistent compilation cache at
     `spark.hyperspace.compile.cache.dir` (called at session init, next
     to `transfer.configure`). Every `instrumented_jit` entry point then
     participates for free — jax keys persisted executables below its
@@ -93,48 +89,37 @@ def configure_persistent_cache(conf) -> bool:
     of paying the trace (the PR-3 warm `compile.traces == 0` property,
     surviving process restarts; the restored-from-disk dispatch still
     re-runs the traced body, so it counts as one trace with near-zero
-    `compile.seconds` rather than a cache hit).
+    `compile.seconds` rather than a cache hit). One process-wide
+    setting — jax's compilation cache is global, so co-resident
+    sessions share it (same caveat as the transfer-engine knobs).
 
-    The size/compile-time eligibility floors are dropped so the
-    engine's small bucketed kernels qualify. Returns True iff the cache
-    is (now) active; an unset knob or a jax build without the option
-    degrades to False with a warning — warm-start is an optimization,
-    never a startup failure. Counted as
+    The ENVIRONMENT outranks the knob: when JAX_COMPILATION_CACHE_DIR is
+    set, jax already reads it and the knob is only logged as ignored —
+    whoever launches the process places the cache, and no code moves it
+    (`_jax_config.py` holds the default dir, and drops jax's
+    size/compile-time eligibility floors for every placement, so the
+    engine's small bucketed kernels qualify). An unset knob is a
+    no-op, not a reset. Counted as
     `compile.persistent_cache.configured`."""
-    global _persistent_dir
-    try:
-        path = conf.compile_cache_dir if conf is not None else None
-    except Exception:
-        path = None
-    if not path:
-        return _persistent_dir is not None
-    with _persistent_lock:
-        if _persistent_dir == path:
-            return True
-        import logging
+    import os
 
-        import jax
-        try:
-            jax.config.update("jax_compilation_cache_dir", str(path))
-        except Exception:
-            logging.getLogger(__name__).warning(
-                "persistent compilation cache unsupported by this jax "
-                "build; compile.cache.dir ignored", exc_info=True)
-            return False
-        # Eligibility floors: jax defaults skip small/fast executables,
-        # which is exactly what this engine's per-bucket kernels are.
-        # Best-effort — older builds lack the knobs.
-        for opt, val in (
-                ("jax_persistent_cache_min_entry_size_bytes", -1),
-                ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-            try:
-                jax.config.update(opt, val)
-            except Exception:
-                pass
-        _persistent_dir = str(path)
-        _registry.get_registry().counter(
-            "compile.persistent_cache.configured").inc()
-        return True
+    path = conf.compile_cache_dir if conf is not None else None
+    if not path:
+        return
+    path = str(path)
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        import logging
+        logging.getLogger(__name__).info(
+            "compile.cache.dir=%s ignored: JAX_COMPILATION_CACHE_DIR=%s "
+            "places the cache", path, env_dir)
+        return
+    import jax
+    with _persistent_lock:
+        if jax.config.jax_compilation_cache_dir != path:
+            jax.config.update("jax_compilation_cache_dir", path)
+            _registry.get_registry().counter(
+                "compile.persistent_cache.configured").inc()
 
 
 # Warm-start AOT executables: keys already primed this process (e.g.

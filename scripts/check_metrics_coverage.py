@@ -34,6 +34,9 @@ import pkgutil
 import re
 import sys
 
+# A lint/diff tool over committed artifacts and source: it never needs the
+# chip, and pinning the CPU keeps it (and the tests that shell out to it)
+# from taking the one process slot a chip allows.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

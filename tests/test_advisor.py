@@ -412,29 +412,86 @@ def test_advisor_disabled_knob(workload_env):
 # ---------------------------------------------------------------------------
 
 
-def test_compile_cache_dir_wires_persistent_cache(tmp_path, monkeypatch):
+@pytest.fixture
+def restore_jax_cache_config():
+    import jax
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_persistent_cache_min_compile_time_secs")
+    before = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in before.items():
+        jax.config.update(n, v)
+
+
+def test_compile_cache_dir_wires_persistent_cache(tmp_path, monkeypatch,
+                                                  restore_jax_cache_config):
     import jax
 
     from hyperspace_tpu.telemetry import compilation
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cache_dir = tmp_path / "jitcache"
-    monkeypatch.setattr(compilation, "_persistent_dir", None)
-    before = getattr(jax.config, "jax_compilation_cache_dir", None)
-    try:
-        sess = HyperspaceSession(HyperspaceConf({
-            "hyperspace.warehouse.dir": str(tmp_path / "wh"),
-            "spark.hyperspace.compile.cache.dir": str(cache_dir)}))
-        assert compilation.persistent_cache_dir() == str(cache_dir)
-        assert jax.config.jax_compilation_cache_dir == str(cache_dir)
-        assert _counter("compile.persistent_cache.configured") >= 1
-        # Unset knob: configure is a no-op, not a reset.
-        HyperspaceSession(HyperspaceConf({
-            "hyperspace.warehouse.dir": str(tmp_path / "wh2")}))
-        assert jax.config.jax_compilation_cache_dir == str(cache_dir)
-        sess.close()
-    finally:
-        jax.config.update("jax_compilation_cache_dir", before)
-        monkeypatch.setattr(compilation, "_persistent_dir", None)
+    sess = HyperspaceSession(HyperspaceConf({
+        "hyperspace.warehouse.dir": str(tmp_path / "wh"),
+        "spark.hyperspace.compile.cache.dir": str(cache_dir)}))
+    assert compilation.persistent_cache_dir() == str(cache_dir)
+    assert jax.config.jax_compilation_cache_dir == str(cache_dir)
+    assert _counter("compile.persistent_cache.configured") >= 1
+    # Unset knob: configure is a no-op, not a reset.
+    HyperspaceSession(HyperspaceConf({
+        "hyperspace.warehouse.dir": str(tmp_path / "wh2")}))
+    assert jax.config.jax_compilation_cache_dir == str(cache_dir)
+    sess.close()
+
+
+def test_compile_cache_dir_knob_yields_to_environment(
+        tmp_path, monkeypatch, restore_jax_cache_config):
+    """Whoever launches the process places the cache: with
+    JAX_COMPILATION_CACHE_DIR set, the session knob moves nothing."""
+    import jax
+
+    env_dir = str(tmp_path / "from_env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    jax.config.update("jax_compilation_cache_dir", env_dir)  # as at import
+    configured = _counter("compile.persistent_cache.configured")
+    sess = HyperspaceSession(HyperspaceConf({
+        "hyperspace.warehouse.dir": str(tmp_path / "wh"),
+        "spark.hyperspace.compile.cache.dir": str(tmp_path / "from_knob")}))
+    assert jax.config.jax_compilation_cache_dir == env_dir
+    assert _counter("compile.persistent_cache.configured") == configured
+    sess.close()
+
+
+@pytest.mark.parametrize("placed_by", ["default", "environment"])
+def test_compile_cache_placement_at_import(placed_by, tmp_path):
+    """With the variable unset, `_jax_config` places the cache at one
+    fixed git-ignored path inside the checkout — never a temp name; with
+    it set, the environment's dir stands. Either way every program is
+    stored (jax's compile-time and entry-size floors are dropped in ONE
+    place, whoever placed the dir)."""
+    import subprocess
+    import sys
+
+    import hyperspace_tpu
+
+    repo = os.path.dirname(os.path.dirname(
+        os.path.abspath(hyperspace_tpu.__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"  # the probe never needs a chip
+    want_dir = os.path.join(repo, ".jax_cache")
+    if placed_by == "environment":
+        want_dir = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "env")
+    probe = ("import jax, hyperspace_tpu._jax_config; c = jax.config; "
+             "print(c.jax_compilation_cache_dir, "
+             "c.jax_persistent_cache_min_compile_time_secs, "
+             "c.jax_persistent_cache_min_entry_size_bytes)")
+    out = subprocess.run([sys.executable, "-c", probe], cwd=repo, env=env,
+                         check=True, capture_output=True, text=True,
+                         timeout=120).stdout.split()
+    assert out == [want_dir, "0.0", "-1"]
 
 
 # ---------------------------------------------------------------------------

@@ -220,3 +220,105 @@ def test_read_cache_serves_and_invalidates(tmp_path):
     t4 = P.read_table([f], columns=["x"])
     assert t4.num_rows == 9
     P.clear_read_cache()
+
+
+# ---------------------------------------------------------------------------
+# float64 is CARRIED on the device as its bit pattern (a TPU's own f64 is
+# an f32 pair and would round and clamp it); only computing decodes it.
+# ---------------------------------------------------------------------------
+
+F64_EDGE = np.array([1e300, -1e300, np.finfo(np.float64).max, 1e-300,
+                     5e-324, 1e-40, -0.0, 0.1 + 0.2, np.inf, -np.inf,
+                     np.nan, 0.25])
+
+
+def _bits(values) -> np.ndarray:
+    return np.ascontiguousarray(values, dtype=np.float64).view(np.int64)
+
+
+@pytest.fixture
+def no_float64_decode(monkeypatch):
+    """On the CPU a decode is an exact bitcast and so invisible; make it
+    an error, as it would be a rounding on the chip."""
+    def refuse(_bits):
+        raise AssertionError("float64 decoded on a path that moves rows")
+    monkeypatch.setattr(columnar, "f64_from_bits", refuse)
+
+
+def test_float64_is_carried_as_bits_and_moves_exactly(no_float64_decode):
+    import jax.numpy as jnp
+
+    table = pa.table({"k": np.arange(len(F64_EDGE), dtype=np.int64),
+                      "x": F64_EDGE,
+                      "n": pa.array([None, *F64_EDGE[1:]], type=pa.float64())})
+    batch = columnar.from_arrow(table)
+    col = batch.column("x")
+    assert col.carries_bits and not col.is_host
+    assert np.array_equal(np.asarray(col.raw), _bits(F64_EDGE))
+
+    def exact(got, rows):
+        for name in ("x", "n"):
+            want = table.column(name).take(pa.array(rows))
+            assert got.column(name).null_count == want.null_count
+            assert np.array_equal(
+                _bits(got.column(name).fill_null(7.0).to_numpy()),
+                _bits(want.fill_null(7.0).to_numpy())), name
+
+    everything = list(range(len(F64_EDGE)))
+    exact(columnar.to_arrow(batch), everything)
+    rows = [10, 0, 6, 6, 4]
+    exact(columnar.to_arrow(batch.take(jnp.asarray(rows, dtype=jnp.int32))),
+          rows)
+    # demotion / re-promotion (the segment cache's tiers): host columns
+    # hold the values themselves, the device copy carries bits again
+    host = columnar.batch_to_host(batch)
+    assert host.column("x").raw.dtype == np.float64
+    assert np.array_equal(_bits(host.column("x").raw), _bits(F64_EDGE))
+    back = columnar.host_batch_to_device(host)
+    assert back.column("x").carries_bits
+    exact(columnar.to_arrow(back), everything)
+    # a device part and a host part concatenate without a float64 H2D
+    both = columnar.concat_batches([batch, host])
+    assert both.column("x").carries_bits
+    exact(columnar.to_arrow(both), everything + everything)
+    # trees: moved columns ride in carried form, and come back as the
+    # same logical column
+    tree, aux = columnar.batch_to_tree(host, computes_on=("k",))
+    assert tree["x"]["data"].dtype == np.int64
+    rebuilt = columnar.tree_to_batch(tree, host.schema, aux)
+    assert rebuilt.column("x").raw.dtype == np.float64
+
+
+def test_float64_values_decode_where_an_expression_computes():
+    batch = columnar.from_arrow(pa.table({"x": F64_EDGE}))
+    col = batch.column("x")
+    assert np.array_equal(_bits(np.asarray(col.data)), _bits(F64_EDGE))
+    tree, _ = columnar.batch_to_tree(batch)  # computes on every column
+    assert np.asarray(tree["x"]["data"]).dtype == np.float64
+
+
+def test_arithmetic_float64_decode_matches_the_bitcast():
+    """The TPU's decode (no 64-bit bitcast there) is exact wherever the
+    device's f64 holds the value: on the CPU that is every finite
+    double in f32's exponent range, subnormals of f32 included."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(0)
+    values = np.concatenate([
+        rng.random(4096), rng.normal(size=4096) * 1e30,
+        rng.normal(size=4096) * 1e-30,
+        [0.0, -0.0, 1.0, -1.0, 3.4e38, 1.2e-38, 1e-40, 0.1 + 0.2,
+         np.inf, -np.inf, np.nan]])
+    got = np.asarray(columnar._f64_from_bits_arithmetic(
+        jnp.asarray(_bits(values))))
+    assert np.array_equal(_bits(got), _bits(values))
+
+
+def test_renaming_projection_moves_a_float64_column(no_float64_decode):
+    from hyperspace_tpu.engine.compiler import ExpressionCompiler
+    from hyperspace_tpu.plan import expr as E
+
+    batch = columnar.from_arrow(pa.table({"x": F64_EDGE}))
+    out = ExpressionCompiler(batch).value_column(
+        E.Alias(E.Column("x"), "y"), "float64")
+    assert out.carries_bits

@@ -127,3 +127,22 @@ def test_builder_host_permutation_uses_native_layout():
               % np.uint32(16)).astype(np.int32)
     ref = _ref_perm(bucket, host_column_sort_lanes(batch.column("key")))
     np.testing.assert_array_equal(perm, ref)
+
+
+def test_library_older_than_its_source_counts_as_stale(tmp_path,
+                                                       monkeypatch):
+    """`get_lib` must not prefer a leftover git-ignored .so over the
+    committed source: older than `hyperspace_host.cpp` means rebuild."""
+    import os
+
+    so, src = tmp_path / "lib.so", tmp_path / "host.cpp"
+    monkeypatch.setattr(native, "_SO_PATH", str(so))
+    monkeypatch.setattr(native, "_SRC_PATH", str(src))
+    src.write_text("// source")
+    assert native._stale()  # no library yet
+    so.write_bytes(b"")
+    os.utime(so, (1_000, 1_000))
+    os.utime(src, (2_000, 2_000))
+    assert native._stale()
+    os.utime(so, (3_000, 3_000))
+    assert not native._stale()

@@ -1,0 +1,169 @@
+"""Compiles for a described (not attached) v5e chip, at real widths.
+
+Interpret-mode tests cannot see what the chip's compiler refuses — a
+slice off the tiling, too much VMEM, a 64-bit op with no lowering. These
+compile the main path's Pallas kernels, one fused filter stage and the
+float64 decode for a described `v5e:2x2` chip; nothing runs. The sort programs (`_perm_core`,
+`_counting_match_lanes`) take minutes to compile at any size, so they are
+guarded by `chip_smoke.py` on the chip, not here.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and every xdist worker imports this
+file. Keep all such tests in THIS file (a second file could land on
+another worker, whose fixture would then skip).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROWS = 4_194_304  # MIN_DEVICE_ROWS_DEFAULT: the smallest device-lane scan
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A described-chip executable can be written to the persistent cache
+    but not read back without a chip; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("num_buckets", [64, 1024])
+@pytest.mark.parametrize("n_lanes", [1, 2])
+@pytest.mark.parametrize("kernel", ["hash", "partition"])
+def test_pallas_kernel_compiles_for_v5e(one_chip, no_compile_cache, kernel,
+                                        n_lanes, num_buckets):
+    from hyperspace_tpu.ops.pallas.hash_kernel import hash_lanes_to_buckets
+    from hyperspace_tpu.ops.pallas.partition_kernel import (
+        partition_ids_and_histogram)
+
+    fn = (hash_lanes_to_buckets if kernel == "hash"
+          else partition_ids_and_histogram)
+    lane = jax.ShapeDtypeStruct((ROWS,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(lambda *lanes: fn(list(lanes), num_buckets)).lower(
+        *[lane] * n_lanes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_float64_decode_compiles_for_v5e(one_chip, no_compile_cache):
+    """float64 columns are carried on the device as int64 bit patterns;
+    an expression that computes on one decodes it by arithmetic (the
+    TPU's f64 emulation cannot reinterpret 64 bits). Here: the decode
+    feeding a compare and a sum, as a filter or an aggregate would."""
+    from hyperspace_tpu.io.columnar import _f64_from_bits_arithmetic
+
+    bits = jax.ShapeDtypeStruct((ROWS,), jnp.int64, sharding=one_chip)
+
+    def stage(b):
+        x = _f64_from_bits_arithmetic(b)
+        return jnp.sum(x < 0.5), jnp.sum(x)
+
+    compiled = jax.jit(stage).lower(bits).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes == ROWS * 8
+
+
+def test_build_program_cache_key_names_no_caller_file(one_chip):
+    """The Pallas kernel rides in `_perm_core`'s custom call as an opaque
+    payload that jax cannot strip of locations before hashing it for the
+    compile cache. It must name kernel source only — not this file, not
+    an entry script — or any edited line up the call stack recompiles the
+    build program; and by its path INSIDE the checkout, or the same commit
+    checked out elsewhere recompiles it too (`_jax_config.py`)."""
+    import base64
+    import re
+
+    import hyperspace_tpu.ops.pallas as kernels
+    from hyperspace_tpu.ops.build import _perm_core
+
+    tree = {"key": {"data": jax.ShapeDtypeStruct((ROWS,), jnp.int64,
+                                                 sharding=one_chip)}}
+    text = _perm_core.lower(tree, ("key",), 64, 8, use_pallas=True).as_text()
+    body = base64.b64decode(
+        re.search(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text).group(1))
+    files = {s.decode() for s in re.findall(rb"[\x20-\x7e]+\.py", body)}
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    kernel_dir = os.path.relpath(
+        os.path.dirname(os.path.abspath(kernels.__file__)), repo)
+    assert files and all(os.path.dirname(f) == kernel_dir for f in files), \
+        files
+
+
+def test_fused_filter_stage_compiles_for_v5e(one_chip, no_compile_cache,
+                                             tmp_path, monkeypatch):
+    """The smoke's range filter — int64 key compare over an
+    (int64, int64, float64, int64) index scan — as the real
+    `fusion.run_stage` program, re-lowered at device-lane width."""
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from hyperspace_tpu import (Hyperspace, HyperspaceConf,
+                                HyperspaceSession, IndexConfig, col, lit)
+    from hyperspace_tpu.engine import fusion
+
+    n = 4096
+    rng = np.random.default_rng(0)
+    src = tmp_path / "fact"
+    src.mkdir()
+    pq.write_table(pa.table({
+        "key": rng.integers(0, n, n).astype(np.int64) * 1_000_003,
+        "id": np.arange(n, dtype=np.int64),
+        "measure": rng.random(n),
+        "k2": rng.integers(0, 100, n).astype(np.int64),
+    }), str(src / "part-0.parquet"))
+    sess = HyperspaceSession(HyperspaceConf({
+        "hyperspace.warehouse.dir": str(tmp_path / "wh"),
+        # one device, device lane: the conftest's 8 virtual CPU devices
+        # would otherwise hand the filter to the mesh scan
+        "spark.hyperspace.distribution.enabled": "false",
+        "spark.hyperspace.execution.min.device.rows": "0"}))
+    df = sess.read_parquet(str(src))
+    Hyperspace(sess).create_index(
+        df, IndexConfig("f", ["key"], ["id", "measure", "k2"]))
+    sess.enable_hyperspace()
+
+    captured = []
+    run_stage = fusion._run_stage
+    monkeypatch.setattr(
+        fusion, "_run_stage",
+        lambda prog, trees, tables: (captured.append((prog, trees, tables))
+                                     or run_stage(prog, trees, tables)))
+    lo, hi = 400 * 1_000_003, 410 * 1_000_003
+    (df.filter((col("key") >= lit(lo)) & (col("key") < lit(hi)))
+     .select("key", "id", "measure", "k2").collect())
+    sess.close()
+    (prog, trees, tables), = captured
+
+    wide = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((ROWS,) + a.shape[1:], a.dtype,
+                                       sharding=one_chip), trees)
+    wide_prog = fusion._StageProgram(
+        prog.key + "#wide", prog.region,
+        [(schema, aux, ROWS) for schema, aux, _ in prog.source_meta],
+        prog.tables_meta)
+    compiled = fusion._run_stage_jit.lower(wide_prog, wide, tables).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes == ROWS * 32

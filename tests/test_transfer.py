@@ -235,7 +235,7 @@ def test_slow_link_overlap_beats_serial(engine):
     n_jobs = 6
 
     def slow_put(arr, device):
-        time.sleep(put_s)  # a dispatch-blocking (tunneled) link
+        time.sleep(put_s)  # a dispatch-blocking link
         return FakeDev(arr)
 
     eng = engine(chunk_bytes=1 << 20, inflight_bytes=1 << 22, threads=2,

@@ -1,0 +1,10 @@
+"""The device's idle time inside the traced window's median query that fell
+under the planner's spans (`hs.plan.*`). Every idle
+moment between device ops goes to the innermost program span over it;
+the five `idle_*_ms` sum to `host_gap_ms` (same trace, same gaps)."""
+
+from lib import program_spans
+
+
+def compute(run):
+    return program_spans.idle_ms(run, "plan")

@@ -61,7 +61,7 @@ def _instrument_run(fn):
         }
         t0 = time.perf_counter()
         try:
-            with telemetry.span(f"action:{type(self).__name__}",
+            with telemetry.span(f"hs.action.{type(self).__name__}",
                                 "action"):
                 out = fn(self)
             report["ok"] = True
@@ -175,7 +175,8 @@ class Action(ABC):
             fn()
             return
         t0 = time.perf_counter()
-        with telemetry.span(f"{type(self).__name__}.{name}", "action"):
+        with telemetry.span(f"hs.action.{type(self).__name__}.{name}",
+                            "action"):
             fn()
         self._report["phases"][name] = round(time.perf_counter() - t0, 6)
 

@@ -453,7 +453,14 @@ class ExpressionCompiler:
 
 
 def compile_predicate(expression: E.Expression, batch: ColumnBatch):
-    return ExpressionCompiler(batch).predicate(expression)
+    if batch.is_host:
+        return ExpressionCompiler(batch).predicate(expression)
+    import jax
+
+    # The mask's ops carry the device scope where a jitted program
+    # traces them (the fused stage); an eager dispatch shows none.
+    with jax.named_scope("hs.predicate"):
+        return ExpressionCompiler(batch).predicate(expression)
 
 
 def apply_filter(batch: ColumnBatch, expression: E.Expression) -> ColumnBatch:
@@ -473,9 +480,12 @@ def apply_filter(batch: ColumnBatch, expression: E.Expression) -> ColumnBatch:
         return batch.take(np.nonzero(mask)[0].astype(np.int32))
     import jax.numpy as jnp
 
-    count = int(jnp.sum(mask))  # host sync — sizes the output
+    from hyperspace_tpu.ops.compact import compact_indices
+
+    with telemetry.span("hs.stage.sync", "operator"):
+        count = int(jnp.sum(mask))  # host sync — sizes the output
     # The sync is a true span boundary: input + mask + output are all
     # device-resident here — fold an HBM sample into the watermark.
     telemetry.memory.maybe_sample()
-    (indices,) = jnp.nonzero(mask, size=count, fill_value=0)
-    return batch.take(indices)
+    with telemetry.span("hs.stage.compact", "operator", rows=count):
+        return batch.take(compact_indices(mask, count))

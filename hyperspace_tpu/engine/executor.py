@@ -124,7 +124,8 @@ def execute_plan(plan: LogicalPlan,
 
     _resolve_scalar_subqueries(plan, conf)
     t0 = _time.perf_counter()
-    physical = compile_plan(plan, projection, conf)
+    with telemetry.span("hs.plan.compile", "plan"):
+        physical = compile_plan(plan, projection, conf)
     # Physical planning + fusion grouping time, per query (device-side
     # XLA compiles happen lazily inside operators, not here).
     telemetry.add_seconds("plan_s", _time.perf_counter() - t0)

@@ -54,6 +54,7 @@ from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.io.columnar import (ColumnBatch, DeviceColumn,
                                         batch_to_tree, carried,
                                         tree_to_batch)
+from hyperspace_tpu.ops.compact import compact_indices
 from hyperspace_tpu.plan.schema import Field, Schema
 
 
@@ -805,7 +806,7 @@ class FusedStageExec(PhysicalNode):
         telemetry.check_deadline("stage")
         t0 = _time.perf_counter()
         try:
-            with telemetry.span("fusion:dispatch", "fusion",
+            with telemetry.span("hs.stage.dispatch", "fusion",
                                 ops=len(_region_nodes(self.root)),
                                 cache_hit=cache_hit):
                 out_tree, lazy_pairs, sel, cnt = _run_stage(prog, trees,
@@ -833,12 +834,12 @@ class FusedStageExec(PhysicalNode):
         idx = None
         if sel is not None:
             t0 = _time.perf_counter()
-            with telemetry.span("fusion:sync", "fusion"):
+            with telemetry.span("hs.stage.sync", "fusion"):
                 count = int(cnt)  # THE stage sync
             _stat("sync_s", _time.perf_counter() - t0)
-            (idx,) = jnp.nonzero(sel, size=count, fill_value=0)
-            idx = idx.astype(jnp.int32)
-            base = base.take(idx)
+            with telemetry.span("hs.stage.compact", "fusion", rows=count):
+                idx = compact_indices(sel, count).astype(jnp.int32)
+                base = base.take(idx)
         if not lazy_specs:
             return base
         # Deferred build-side gathers, AT SELECTION SIZE: compose each
@@ -854,8 +855,10 @@ class FusedStageExec(PhysicalNode):
             spec.append((slot, src.validity is not None))
             srcs.append((src.raw, src.validity))
             src_cols.append((out_name, dtype, src))
-        gathered = _finalize_lazy(idx, lazy_pairs, tuple(srcs),
-                                  tuple(spec))
+        with telemetry.span("hs.stage.gather", "fusion",
+                            columns=len(spec)):
+            gathered = _finalize_lazy(idx, lazy_pairs, tuple(srcs),
+                                      tuple(spec))
         columns = dict(base.columns)
         for (out_name, dtype, src), (data, validity) in zip(src_cols,
                                                             gathered):

@@ -39,9 +39,10 @@ def _batch_rows(out) -> Optional[int]:
 
 def _instrument(fn, bucketed: bool):
     """Wrap an execute/execute_bucketed implementation with the telemetry
-    operator hook: a per-query operator record (active recorder) and a
-    trace span on the executing thread (active tracer). With neither,
-    the cost is one ContextVar read + one global read + None checks.
+    operator hook: a per-query operator record (active recorder) and an
+    `hs.op.<Name>` span on the executing thread (the span seam's two
+    sinks). With neither, the cost is one ContextVar read + two flag
+    reads.
     Applied automatically to every PhysicalNode subclass by
     `PhysicalNode.__init_subclass__`, so a new operator can never
     silently execute unmetered (`scripts/check_metrics_coverage.py`
@@ -61,34 +62,29 @@ def _instrument(fn, bucketed: bool):
         phase = "scan" if self.name == "Scan" else "operator"
         telemetry.check_deadline(phase)
         rec = telemetry.current()
-        tr = telemetry.tracer()
-        if rec is None and tr is None:
+        if rec is None and not telemetry.spans_active():
             out = fn(self, arg)
             telemetry.check_deadline(phase)
             return out
-        op = None
-        if rec is not None:
-            op = rec.start_operator(self.name, self, bucketed=bucketed)
-            if bucketed:
-                op.detail["num_buckets"] = arg
-            elif arg is not None:
-                op.detail["bucket"] = arg
-        ts = tr.now_us() if tr is not None else 0.0
-        try:
-            out = fn(self, arg)
-        except BaseException as exc:
-            if tr is not None:
-                tr.complete(self.name, "operator", ts, tr.now_us() - ts,
-                            args={"error": repr(exc)})
-            if op is not None:
-                rec.finish_operator(op, error=repr(exc))
-            raise
-        if tr is not None:
+        with telemetry.span("hs.op." + self.name, "operator") as sp:
+            op = None
+            if rec is not None:
+                op = rec.start_operator(self.name, self, bucketed=bucketed)
+                if bucketed:
+                    op.detail["num_buckets"] = arg
+                elif arg is not None:
+                    op.detail["bucket"] = arg
+            try:
+                out = fn(self, arg)
+            except BaseException as exc:
+                if op is not None:
+                    rec.finish_operator(op, error=repr(exc))
+                raise
             rows = _batch_rows(out)
-            tr.complete(self.name, "operator", ts, tr.now_us() - ts,
-                        args=(None if rows is None else {"rows": rows}))
-        if op is not None:
-            rec.finish_operator(op, rows_out=_batch_rows(out))
+            sp.set(rows=rows,
+                   lane=op.detail.get("lane") if op is not None else None)
+            if op is not None:
+                rec.finish_operator(op, rows_out=rows)
         # Operator-span boundary: fold a device-memory sample into the
         # per-query HBM watermark (throttled; after the span close so
         # the accounting walk never inflates the operator's wall).
@@ -561,7 +557,6 @@ class FilterExec(PhysicalNode):
         """Filter preserves bucket grouping: the compaction gather is
         stable-ascending, so surviving rows stay in bucket order; new
         per-bucket lengths are segment sums of the mask."""
-        import jax.numpy as jnp
         import numpy as np
         from hyperspace_tpu.engine.compiler import compile_predicate
 
@@ -580,16 +575,14 @@ class FilterExec(PhysicalNode):
         # Per-bucket survivor counts as ONE device segment-sum (row ->
         # bucket via searchsorted over the running lengths), then a single
         # [num_buckets] transfer sizes both the new lengths and the gather.
-        import jax
-        csum = jnp.cumsum(jnp.asarray(lengths, dtype=jnp.int64))
-        row_bucket = jnp.searchsorted(
-            csum, jnp.arange(batch.num_rows, dtype=jnp.int64), side="right")
-        new_lengths = np.asarray(jax.ops.segment_sum(
-            mask.astype(jnp.int32), row_bucket.astype(jnp.int32),
-            num_segments=num_buckets)).astype(np.int64)
+        from hyperspace_tpu.ops.compact import (bucket_survivors,
+                                                compact_indices)
+        with telemetry.span("hs.stage.sync", "operator"):
+            new_lengths = np.asarray(bucket_survivors(
+                mask, lengths, num_buckets)).astype(np.int64)
         count = int(new_lengths.sum())
-        (indices,) = jnp.nonzero(mask, size=count, fill_value=0)
-        return batch.take(indices), new_lengths
+        with telemetry.span("hs.stage.compact", "operator", rows=count):
+            return batch.take(compact_indices(mask, count)), new_lengths
 
     def execute_sharded(self, num_buckets: int, mesh, align_plan=None):
         """Filter preserves the sharded layout: rows never move, the

@@ -1033,182 +1033,11 @@ class QueryScheduler:
             self._breakers.record_success(key)
         return batch
 
-    # -- the collect pipeline ---------------------------------------------
-
-    def collect(self, df, timeout: Optional[float] = None,
-                tenant: Optional[str] = None):
-        """Execute a DataFrame end to end under serving control.
-        Returns `(arrow_table, QueryMetrics)` — `DataFrame.collect`
-        owns the user-facing return shape. `tenant` (else the
-        session's sticky `session.tenant(...)` default, else the
-        DEFAULT tenant) is the billing identity the query charges:
-        admission quotas, DRR dequeue weight, SLO window, and every
-        chargeback counter key on it."""
-        from hyperspace_tpu.io.columnar import to_arrow
-        from hyperspace_tpu.plan import footprint as _footprint
-        from hyperspace_tpu.utils import faults
-
-        session = df.session
-        conf = session.conf if session is not None else None
-        if session is not None and getattr(session, "_closed", False):
-            raise HyperspaceException(
-                "Session is closed; create a new HyperspaceSession.")
-        if tenant is None and session is not None:
-            tenant = getattr(session, "_default_tenant", None)
-        eff_tenant = str(tenant) if tenant else telemetry.DEFAULT_TENANT
-        query_id = f"q-{next(self._ids)}"
-        if timeout is None and conf is not None:
-            timeout = conf.serve_deadline_seconds or None
-        deadline = Deadline(query_id, timeout)
-        ent = _QueryEntry(query_id, deadline,
-                          _footprint.projected_bytes(df.plan),
-                          id(session) if session is not None else None)
-        ent.tenant = eff_tenant
-        # Replica routing (`parallel/replica.py`): on a multi-slice
-        # topology with replication on, pin this query's fills +
-        # execution to the least-loaded replica slice (cold-range
-        # queries pin to their home slice). Routed BEFORE admission so
-        # the per-replica budget charges the right slice; routing must
-        # never fail a query.
-        try:
-            from hyperspace_tpu.parallel import replica as _replica
-            from hyperspace_tpu.parallel.context import topology
-            rep = _replica.get_router().route(df.plan, conf, self)
-            if rep is not None:
-                topo = topology(conf)
-                ent.replica = rep
-                ent.n_replicas = topo[0] if topo is not None else 0
-        except Exception:
-            logger.debug("replica routing skipped", exc_info=True)
-        description = ", ".join(df.schema.names[:6])
-        metrics = telemetry.QueryMetrics(description=description)
-        metrics.query_id = query_id  # cancel/log correlation handle
-        # Routed-replica dimension: flight-ring consumers (slow-decile
-        # attribution, /healthz's by-replica grouping) can now group
-        # entries by the slice that served them; None = unrouted.
-        metrics.replica = ent.replica
-        # Tenant dimension: stamped on the recorder (flight-ring
-        # `tenant=` filter, /healthz by-tenant grouping) — always the
-        # EFFECTIVE tenant, "default" included, so post-hoc grouping
-        # never needs a null branch.
-        metrics.tenant = eff_tenant
-        # The SOURCE (pre-optimization) logical plan rides the recorder
-        # into the flight ring: the index advisor's what-if scorer
-        # replays exactly this plan against hypothetical indexes
-        # (logical plans are immutable once built; holding the reference
-        # costs nothing per query — no serialization on the hot path).
-        metrics.logical_plan = df.plan
-        with self._cv:
-            self._active[query_id] = ent
+    def _finish(self, metrics, conf, session, eff_tenant) -> None:
+        """Everything after execution, before the answer leaves as
+        Arrow: the recorder's wall, its anatomy, the process aggregates,
+        the SLO windows, index-usage mining, the flight ring."""
         reg = telemetry.get_registry()
-        try:
-            try:
-                t_admit0 = time.perf_counter()
-                wait_s = self._admit(ent, conf)
-                # Critical-path sources: the recorder's wall started at
-                # construction (before admission), so queue wait and the
-                # admission bookkeeping around it are genuine wall
-                # segments — stamp both as per-query counters for
-                # `telemetry/critical_path.py` to classify.
-                metrics.add_seconds("serve.queue_wait_s", wait_s)
-                metrics.add_seconds(
-                    "serve.admission_s",
-                    max(time.perf_counter() - t_admit0 - wait_s, 0.0))
-            except QueryServingError as exc:
-                self._record_serving_error(exc, None, conf)
-                raise
-            try:
-                with telemetry.recording(metrics), \
-                        telemetry.deadline_scope(deadline), \
-                        telemetry.tenant_scope(eff_tenant), \
-                        telemetry.span("query", "query",
-                                       description=description):
-                    metrics.event("serve", "admitted",
-                                  query_id=query_id,
-                                  footprint_bytes=ent.footprint,
-                                  queue_wait_s=round(wait_s, 6))
-                    faults.fire("scheduler.run")
-                    deadline.check("plan")
-                    plan = (session.optimize(df.plan)
-                            if session is not None else df.plan)
-                    if plan is not df.plan:
-                        # Admission charged the UNOPTIMIZED plan. The
-                        # rewritten plan may read strictly fewer bytes —
-                        # a covering index's narrower data, or a
-                        # sketch-pruned scan's surviving files — so
-                        # re-project and credit the difference:
-                        # admission control charges only what the plan
-                        # will actually stage.
-                        opt_fp = _footprint.projected_bytes(plan)
-                        if opt_fp < ent.footprint:
-                            reproj = self._credit(ent,
-                                                  ent.footprint - opt_fp)
-                            if reproj:
-                                metrics.event("serve",
-                                              "footprint_reprojected",
-                                              query_id=query_id,
-                                              credited_bytes=reproj)
-                        # Already-resident index segments are bytes this
-                        # query will never stage: credit them back so
-                        # queued queries coalesce onto the warm cache.
-                        try:
-                            from hyperspace_tpu.io import segcache
-                            resident = (segcache.get_cache()
-                                        .resident_bytes_for_plan(plan))
-                        except Exception:
-                            resident = 0
-                        credited = self._credit(ent, resident)
-                        if credited:
-                            metrics.event("serve", "footprint_credit",
-                                          query_id=query_id,
-                                          credited_bytes=credited)
-                    # Inter-query batched execution (`engine/batcher.py`):
-                    # concurrent same-signature point/filter queries
-                    # coalesce into one jitted predicate invocation over
-                    # the shared scan. None = ineligible shape, nothing
-                    # to coalesce with, or batch-lane fallback — the
-                    # per-query resilient path below stays the general
-                    # executor (and the fallback target).
-                    batch = None
-                    if conf is not None and conf.serve_batch_enabled:
-                        from hyperspace_tpu.engine import batcher
-                        batch = batcher.get_batcher().try_collect(
-                            df, plan, metrics, conf, deadline, self)
-                    if batch is None:
-                        # Replica-pinned execution: under the scope,
-                        # every distribution decision (fills, SPMD
-                        # programs) sees the routed slice's flat
-                        # submesh. The batched lane above is exempt by
-                        # design — its one invocation already serves
-                        # the whole cohort.
-                        from hyperspace_tpu.parallel.context import \
-                            replica_scope
-                        if ent.replica is not None:
-                            metrics.event("serve", "replica",
-                                          query_id=query_id,
-                                          replica=ent.replica)
-                        with replica_scope(ent.replica):
-                            batch = self._execute_resilient(df, plan,
-                                                            metrics,
-                                                            conf)
-                    if not batch.is_host:
-                        # Query-end HBM watermark, FORCED (throttling
-                        # may have swallowed every span-boundary sample
-                        # of a fast query) and inside the recording so
-                        # it attributes here.
-                        telemetry.memory.sample()
-                    else:
-                        import sys as _sys
-                        if "jax" in _sys.modules:
-                            # Host result, but intermediates may have
-                            # ridden the device; throttled sample — and
-                            # never an import of jax to find zero bytes.
-                            telemetry.memory.maybe_sample()
-            except QueryServingError as exc:
-                self._record_serving_error(exc, metrics, conf)
-                raise
-        finally:
-            self._release(ent)
         metrics.finish()
         # Latency anatomy: decompose the finished wall into the closed
         # segment set and stamp it on the recorder BEFORE the flight
@@ -1263,7 +1092,195 @@ class QueryScheduler:
         telemetry.flight.record(metrics, conf=conf)
         if session is not None:
             session._last_query_metrics = metrics
-        table = to_arrow(batch)
+
+    def _credit_rewritten(self, ent, plan, metrics) -> None:
+        """Admission charged the UNOPTIMIZED plan. The rewritten plan
+        may read strictly fewer bytes — a covering index's narrower
+        data, or a sketch-pruned scan's surviving files — so re-project
+        and credit the difference: admission control charges only what
+        the plan will actually stage. Already-resident index segments
+        are bytes this query will never stage either: credit them back
+        so queued queries coalesce onto the warm cache."""
+        from hyperspace_tpu.plan import footprint as _footprint
+        with telemetry.span("hs.serve.credit", "serve"):
+            opt_fp = _footprint.projected_bytes(plan)
+            if opt_fp < ent.footprint:
+                reproj = self._credit(ent, ent.footprint - opt_fp)
+                if reproj:
+                    metrics.event("serve", "footprint_reprojected",
+                                  query_id=ent.query_id,
+                                  credited_bytes=reproj)
+            try:
+                from hyperspace_tpu.io import segcache
+                resident = (segcache.get_cache()
+                            .resident_bytes_for_plan(plan))
+            except Exception:
+                resident = 0
+            credited = self._credit(ent, resident)
+            if credited:
+                metrics.event("serve", "footprint_credit",
+                              query_id=ent.query_id,
+                              credited_bytes=credited)
+
+    # -- the collect pipeline ---------------------------------------------
+
+    def collect(self, df, timeout: Optional[float] = None,
+                tenant: Optional[str] = None):
+        """Execute a DataFrame end to end under serving control.
+        Returns `(arrow_table, QueryMetrics)` — `DataFrame.collect`
+        owns the user-facing return shape. `tenant` (else the
+        session's sticky `session.tenant(...)` default, else the
+        DEFAULT tenant) is the billing identity the query charges:
+        admission quotas, DRR dequeue weight, SLO window, and every
+        chargeback counter key on it."""
+        from hyperspace_tpu.io.columnar import to_arrow
+        from hyperspace_tpu.plan import footprint as _footprint
+        from hyperspace_tpu.utils import faults
+
+        session = df.session
+        conf = session.conf if session is not None else None
+        if session is not None and getattr(session, "_closed", False):
+            raise HyperspaceException(
+                "Session is closed; create a new HyperspaceSession.")
+        if tenant is None and session is not None:
+            tenant = getattr(session, "_default_tenant", None)
+        eff_tenant = str(tenant) if tenant else telemetry.DEFAULT_TENANT
+        query_id = f"q-{next(self._ids)}"
+        # Everything up to a granted admission is one span: footprint
+        # projection, routing, the recorder, the queue.
+        with telemetry.span("hs.serve.admit", "serve",
+                            qid=query_id) as admit_span:
+            if timeout is None and conf is not None:
+                timeout = conf.serve_deadline_seconds or None
+            deadline = Deadline(query_id, timeout)
+            ent = _QueryEntry(query_id, deadline,
+                              _footprint.projected_bytes(df.plan),
+                              id(session) if session is not None else None)
+            ent.tenant = eff_tenant
+            # Replica routing (`parallel/replica.py`): on a multi-slice
+            # topology with replication on, pin this query's fills +
+            # execution to the least-loaded replica slice (cold-range
+            # queries pin to their home slice). Routed BEFORE admission so
+            # the per-replica budget charges the right slice; routing must
+            # never fail a query.
+            try:
+                from hyperspace_tpu.parallel import replica as _replica
+                from hyperspace_tpu.parallel.context import topology
+                rep = _replica.get_router().route(df.plan, conf, self)
+                if rep is not None:
+                    topo = topology(conf)
+                    ent.replica = rep
+                    ent.n_replicas = topo[0] if topo is not None else 0
+            except Exception:
+                logger.debug("replica routing skipped", exc_info=True)
+            description = ", ".join(df.schema.names[:6])
+            metrics = telemetry.QueryMetrics(description=description)
+            metrics.query_id = query_id  # cancel/log correlation handle
+            # Routed-replica dimension: flight-ring consumers (slow-decile
+            # attribution, /healthz's by-replica grouping) can now group
+            # entries by the slice that served them; None = unrouted.
+            metrics.replica = ent.replica
+            # Tenant dimension: stamped on the recorder (flight-ring
+            # `tenant=` filter, /healthz by-tenant grouping) — always the
+            # EFFECTIVE tenant, "default" included, so post-hoc grouping
+            # never needs a null branch.
+            metrics.tenant = eff_tenant
+            # The SOURCE (pre-optimization) logical plan rides the recorder
+            # into the flight ring: the index advisor's what-if scorer
+            # replays exactly this plan against hypothetical indexes
+            # (logical plans are immutable once built; holding the reference
+            # costs nothing per query — no serialization on the hot path).
+            metrics.logical_plan = df.plan
+            with self._cv:
+                self._active[query_id] = ent
+            try:
+                t_admit0 = time.perf_counter()
+                wait_s = self._admit(ent, conf)
+                # Critical-path sources: the recorder's wall started at
+                # construction (before admission), so queue wait and the
+                # admission bookkeeping around it are genuine wall
+                # segments — stamp both as per-query counters for
+                # `telemetry/critical_path.py` to classify.
+                metrics.add_seconds("serve.queue_wait_s", wait_s)
+                metrics.add_seconds(
+                    "serve.admission_s",
+                    max(time.perf_counter() - t_admit0 - wait_s, 0.0))
+                admit_span.set(queue_wait_s=round(wait_s, 6))
+            except BaseException as exc:
+                if isinstance(exc, QueryServingError):
+                    self._record_serving_error(exc, None, conf)
+                self._release(ent)
+                raise
+        try:
+            try:
+                with telemetry.recording(metrics), \
+                        telemetry.deadline_scope(deadline), \
+                        telemetry.tenant_scope(eff_tenant), \
+                        telemetry.span("hs.query", "query",
+                                       description=description):
+                    metrics.event("serve", "admitted",
+                                  query_id=query_id,
+                                  footprint_bytes=ent.footprint,
+                                  queue_wait_s=round(wait_s, 6))
+                    faults.fire("scheduler.run")
+                    deadline.check("plan")
+                    with telemetry.span("hs.plan.optimize", "plan"):
+                        plan = (session.optimize(df.plan)
+                                if session is not None else df.plan)
+                    if plan is not df.plan:
+                        self._credit_rewritten(ent, plan, metrics)
+                    # Inter-query batched execution (`engine/batcher.py`):
+                    # concurrent same-signature point/filter queries
+                    # coalesce into one jitted predicate invocation over
+                    # the shared scan. None = ineligible shape, nothing
+                    # to coalesce with, or batch-lane fallback — the
+                    # per-query resilient path below stays the general
+                    # executor (and the fallback target).
+                    batch = None
+                    if conf is not None and conf.serve_batch_enabled:
+                        from hyperspace_tpu.engine import batcher
+                        batch = batcher.get_batcher().try_collect(
+                            df, plan, metrics, conf, deadline, self)
+                    if batch is None:
+                        # Replica-pinned execution: under the scope,
+                        # every distribution decision (fills, SPMD
+                        # programs) sees the routed slice's flat
+                        # submesh. The batched lane above is exempt by
+                        # design — its one invocation already serves
+                        # the whole cohort.
+                        from hyperspace_tpu.parallel.context import \
+                            replica_scope
+                        if ent.replica is not None:
+                            metrics.event("serve", "replica",
+                                          query_id=query_id,
+                                          replica=ent.replica)
+                        with replica_scope(ent.replica):
+                            batch = self._execute_resilient(df, plan,
+                                                            metrics,
+                                                            conf)
+                    if not batch.is_host:
+                        # Query-end HBM watermark, FORCED (throttling
+                        # may have swallowed every span-boundary sample
+                        # of a fast query) and inside the recording so
+                        # it attributes here.
+                        telemetry.memory.sample()
+                    else:
+                        import sys as _sys
+                        if "jax" in _sys.modules:
+                            # Host result, but intermediates may have
+                            # ridden the device; throttled sample — and
+                            # never an import of jax to find zero bytes.
+                            telemetry.memory.maybe_sample()
+            except QueryServingError as exc:
+                self._record_serving_error(exc, metrics, conf)
+                raise
+        finally:
+            self._release(ent)
+        with telemetry.span("hs.serve.finish", "serve", qid=query_id):
+            self._finish(metrics, conf, session, eff_tenant)
+        with telemetry.span("hs.to_arrow", "api", qid=query_id,
+                            rows=batch.num_rows):
+            table = to_arrow(batch)
         return table, metrics
 
 

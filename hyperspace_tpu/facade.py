@@ -208,7 +208,7 @@ class Hyperspace:
         from hyperspace_tpu import telemetry
 
         usage = telemetry.tenant_digest()
-        counters = telemetry.get_registry().counters_dict()
+        counters = telemetry.get_registry().series_snapshot()["counters"]
         totals = {name: sum(u.get(name, 0) for u in usage.values())
                   for name in telemetry.TENANT_CHARGE_COUNTERS}
         global_ = {name: counters.get(name, 0)

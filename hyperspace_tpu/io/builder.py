@@ -27,9 +27,17 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 import hyperspace_tpu.engine  # noqa: F401  (x64 config)
+from hyperspace_tpu import telemetry
 from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.io import columnar, parquet
 from hyperspace_tpu.plan.nodes import BucketSpec
+
+
+def _write_file(table, out: str) -> None:
+    """One bucket file's encode + write, on the writer thread."""
+    with telemetry.span("hs.build.write.file", "build",
+                        rows=table.num_rows):
+        parquet.write_table(table, out)
 
 
 def _write_sorted_runs(table, perm_chunks, starts, ends, path: str,
@@ -51,6 +59,16 @@ def _write_sorted_runs(table, perm_chunks, starts, ends, path: str,
     concatenation stays fully sorted — the same multi-run layout the
     incremental-refresh deltas already use.
     """
+    with telemetry.span("hs.build.write", "build",
+                        rows=table.num_rows) as sp:
+        written = _write_runs(table, perm_chunks, starts, ends, path,
+                              file_suffix)
+        sp.set(files=len(written))
+    return written
+
+
+def _write_runs(table, perm_chunks, starts, ends, path: str,
+                file_suffix: Optional[str]) -> List[str]:
     import pyarrow as pa
 
     from hyperspace_tpu.io import transfer
@@ -73,7 +91,8 @@ def _write_sorted_runs(table, perm_chunks, starts, ends, path: str,
             fut.result()
         pending.clear()
 
-    from hyperspace_tpu import telemetry
+    # The writer thread's spans carry the caller's query identifier.
+    write_file = telemetry.propagating(_write_file)
     try:
         for ci, chunk in enumerate(perm_chunks):
             # Chunk-boundary cancellation checkpoint: a cancelled query
@@ -111,8 +130,7 @@ def _write_sorted_runs(table, perm_chunks, starts, ends, path: str,
                     suffix = f"{file_suffix or ''}c{ci:02d}"
                 out = os.path.join(path, parquet.bucket_file_name(b, suffix))
                 pending.append(_writer_pool().submit(
-                    parquet.write_table,
-                    chunk_table.slice(s - offset, e - s), out))
+                    write_file, chunk_table.slice(s - offset, e - s), out))
                 written.append(out)
             offset += m
     finally:
@@ -284,21 +302,25 @@ def write_bucketed_table(table, indexed_columns: Sequence[str],
             raise HyperspaceException(
                 f"Column not found in table: {', '.join(missing)}")
         names = [by_lower[c.lower()] for c in indexed_columns]
-        if _host_lane_preferred(table.num_rows):
-            chunks, starts, ends = _host_build_permutation(
-                table, names, num_buckets)
-        else:
-            tree = _stage_key_tree(table, names)
-            chunks, starts, ends = permutation_from_tree(
-                tree, names, table.num_rows, num_buckets)
+        with telemetry.span("hs.build.sort", "build", rows=table.num_rows,
+                            lane=build_lane(table.num_rows)):
+            if _host_lane_preferred(table.num_rows):
+                chunks, starts, ends = _host_build_permutation(
+                    table, names, num_buckets)
+            else:
+                tree = _stage_key_tree(table, names)
+                chunks, starts, ends = permutation_from_tree(
+                    tree, names, table.num_rows, num_buckets)
     else:
         if key_batch.num_rows != table.num_rows:
             raise HyperspaceException(
                 f"key_batch rows ({key_batch.num_rows}) != table rows "
                 f"({table.num_rows}); the permutation would silently drop "
                 f"rows.")
-        chunks, starts, ends = build_permutation(key_batch, indexed_columns,
-                                                 num_buckets)
+        with telemetry.span("hs.build.sort", "build",
+                            rows=table.num_rows, lane="device"):
+            chunks, starts, ends = build_permutation(
+                key_batch, indexed_columns, num_buckets)
     return _write_sorted_runs(table, chunks, starts, ends, path, file_suffix)
 
 
@@ -321,14 +343,15 @@ def write_bucketed_from_files(files: Sequence[str],
     to the single-read host path."""
     import pyarrow as pa
 
-    from hyperspace_tpu import telemetry
     from hyperspace_tpu.ops.build import permutation_from_tree
 
     n = sum(parquet.file_row_counts(files))  # footers only, no decode
     if _host_lane_preferred(n):
-        table = parquet.read_table(files, columns=list(column_names))
-        if lineage_ids is not None:
-            table = append_lineage_column(table, files, lineage_ids)
+        with telemetry.span("hs.build.read", "build", files=len(files),
+                            rows=n):
+            table = parquet.read_table(files, columns=list(column_names))
+            if lineage_ids is not None:
+                table = append_lineage_column(table, files, lineage_ids)
         return write_bucketed_table(table, list(key_names), num_buckets,
                                     path, file_suffix=file_suffix)
     payload_names = [c for c in column_names if c not in key_names]
@@ -339,8 +362,11 @@ def write_bucketed_from_files(files: Sequence[str],
         # (pyarrow releases the GIL for the column decode).
         def _decode_payload():
             try:
-                payload["table"] = parquet.read_table(
-                    files, columns=payload_names)
+                with telemetry.span("hs.build.read", "build",
+                                    files=len(files), rows=n,
+                                    part="payload"):
+                    payload["table"] = parquet.read_table(
+                        files, columns=payload_names)
             except BaseException as exc:  # surfaces at join below
                 payload["error"] = exc
 
@@ -348,10 +374,13 @@ def write_bucketed_from_files(files: Sequence[str],
             target=telemetry.propagating(_decode_payload),
             name="hs-payload-decode", daemon=True)
         payload_thread.start()
-    key_table = parquet.read_table(files, columns=list(key_names))
-    tree = _stage_key_tree(key_table, key_names)
-    chunks, starts, ends = permutation_from_tree(tree, key_names, n,
-                                                 num_buckets)
+    with telemetry.span("hs.build.read", "build", files=len(files),
+                        rows=n, part="keys"):
+        key_table = parquet.read_table(files, columns=list(key_names))
+    with telemetry.span("hs.build.sort", "build", rows=n, lane="device"):
+        tree = _stage_key_tree(key_table, key_names)
+        chunks, starts, ends = permutation_from_tree(tree, key_names, n,
+                                                     num_buckets)
     if payload_thread is not None:
         payload_thread.join()
         if "error" in payload:
@@ -385,8 +414,10 @@ def write_bucketed_batch(batch: columnar.ColumnBatch,
         from hyperspace_tpu.utils import file_utils
         file_utils.create_directory(path)
         return []
-    chunks, starts, ends = build_permutation(batch, indexed_columns,
-                                             num_buckets)
+    with telemetry.span("hs.build.sort", "build", rows=batch.num_rows,
+                        lane="device"):
+        chunks, starts, ends = build_permutation(batch, indexed_columns,
+                                                 num_buckets)
     table = columnar.to_arrow(batch)  # async copies overlap the sort
     return _write_sorted_runs(table, chunks, starts, ends, path, file_suffix)
 
@@ -656,9 +687,12 @@ def write_index(df, indexed_columns: Sequence[str],
         rows = sum(parquet.file_row_counts(files))  # footers only
         mesh = should_distribute(conf, rows)
         if mesh is not None:
-            table = parquet.read_table(files, columns=names)
-            if lineage_ids is not None:
-                table = append_lineage_column(table, files, lineage_ids)
+            with telemetry.span("hs.build.read", "build",
+                                files=len(files), rows=rows):
+                table = parquet.read_table(files, columns=names)
+                if lineage_ids is not None:
+                    table = append_lineage_column(table, files,
+                                                  lineage_ids)
             # Host batch: `distributed_build` places each device's shard
             # straight from host memory (concurrent sharded puts through
             # the transfer engine) instead of round-tripping the whole

@@ -473,12 +473,15 @@ def to_arrow(batch: ColumnBatch):
     """
     import pyarrow as pa
 
+    from hyperspace_tpu import telemetry
     from hyperspace_tpu.io import transfer
 
     engine = transfer.get_engine()
-    for col in batch.columns.values():
-        engine.prefetch(col.raw, *((col.validity,)
-                                   if col.validity is not None else ()))
+    with telemetry.span("hs.to_arrow.prefetch", "api"):
+        for col in batch.columns.values():
+            engine.prefetch(col.raw, *((col.validity,)
+                                       if col.validity is not None
+                                       else ()))
 
     import time as _time
 
@@ -492,13 +495,24 @@ def to_arrow(batch: ColumnBatch):
         # Result-side D2H: device arrays cross the link in these
         # np.asarray calls (the async prefetch above may already have
         # landed them — near-zero wall for the same bytes = overlap).
-        t0 = _time.perf_counter()
-        data = fetched(np.asarray(col.raw), f.dtype)
-        validity = np.asarray(col.validity) if col.validity is not None else None
-        if not col.is_host:
-            d2h_s += _time.perf_counter() - t0
-            d2h_bytes += data.nbytes + (validity.nbytes
+        if col.is_host:
+            data = fetched(np.asarray(col.raw), f.dtype)
+            validity = (np.asarray(col.validity)
+                        if col.validity is not None else None)
+        else:
+            # One span per column's blocking fetch; ONE accounting
+            # record for the table, below.
+            with telemetry.span("hs.link.d2h", "link", direction="d2h",
+                                column=f.name) as link:
+                t0 = _time.perf_counter()
+                data = fetched(np.asarray(col.raw), f.dtype)
+                validity = (np.asarray(col.validity)
+                            if col.validity is not None else None)
+                d2h_s += _time.perf_counter() - t0
+                nbytes = data.nbytes + (validity.nbytes
                                         if validity is not None else 0)
+                link.set(bytes=nbytes)
+            d2h_bytes += nbytes
             d2h_chunks += 1 if validity is None else 2
         if col.is_string:
             values = col.dictionary[data]
@@ -520,7 +534,6 @@ def to_arrow(batch: ColumnBatch):
         arrays.append(arr)
         names.append(f.name)
     if d2h_bytes:
-        from hyperspace_tpu import telemetry
         telemetry.record_link_transfer("d2h", d2h_bytes, d2h_s,
                                        chunks=d2h_chunks)
     return pa.table(dict(zip(names, arrays)))

@@ -520,7 +520,7 @@ class SegmentCache:
         _mem.cache_miss("segments")
         reg = telemetry.get_registry()
         try:
-            with telemetry.span("segcache.fill", "cache",
+            with telemetry.span("hs.segcache.fill", "cache",
                                 index=(ref.index_name if ref else None),
                                 files=len(paths)):
                 reg.counter("cache.segments.fills").inc()
@@ -593,7 +593,7 @@ class SegmentCache:
         _mem.cache_miss("segments")
         reg = telemetry.get_registry()
         try:
-            with telemetry.span("segcache.fill", "cache",
+            with telemetry.span("hs.segcache.fill", "cache",
                                 index=(ref.index_name if ref else None)):
                 reg.counter("cache.segments.fills").inc()
                 telemetry.charge_tenant("cache.segments.fills")

@@ -532,21 +532,17 @@ class TransferEngine:
         nbytes = int(arr.nbytes)
         timings = {"convert_s": 0.0, "put_s": 0.0, "chunks": 0}
         from hyperspace_tpu import telemetry
-        t = telemetry.tracer()
-        ts = t.now_us() if t is not None else None
-        t0 = time.perf_counter()
-        if chunked:
-            dev = self._put_entry(arr, device, timings)
-        else:
-            if isinstance(arr, HostCast):
-                arr = arr.materialize()
-            dev = self._windowed_put(arr, device)
-            timings["chunks"] = 1
-        wall = time.perf_counter() - t0
+        with telemetry.link_transfer("h2d", nbytes) as link:
+            if chunked:
+                dev = self._put_entry(arr, device, timings)
+            else:
+                if isinstance(arr, HostCast):
+                    arr = arr.materialize()
+                dev = self._windowed_put(arr, device)
+                timings["chunks"] = 1
+            link.chunks = timings["chunks"]
         with self._lock:
             self.stats["puts"] += 1
-        telemetry.record_link_transfer("h2d", nbytes, wall, ts_us=ts,
-                                       chunks=timings["chunks"])
         self._sweep()
         return dev
 
@@ -559,16 +555,12 @@ class TransferEngine:
             arr = np.asarray(arr)
         nbytes = int(arr.nbytes)
         from hyperspace_tpu import telemetry
-        t = telemetry.tracer()
-        ts = t.now_us() if t is not None else None
         timings = {"convert_s": 0.0, "put_s": 0.0, "chunks": 0}
-        t0 = time.perf_counter()
-        parts = tuple(self._put_parts(arr, device, timings))
+        with telemetry.link_transfer("h2d", nbytes) as link:
+            parts = tuple(self._put_parts(arr, device, timings))
+            link.chunks = len(parts)
         with self._lock:
             self.stats["puts"] += 1
-        telemetry.record_link_transfer("h2d", nbytes,
-                                       time.perf_counter() - t0,
-                                       ts_us=ts, chunks=len(parts))
         self._sweep()
         return parts
 
@@ -596,8 +588,6 @@ class TransferEngine:
         from hyperspace_tpu import telemetry
         pool = self._staging_pool()
         phase = f"transfer.{tag}" if tag else "transfer"
-        t = telemetry.tracer()
-        ts = t.now_us() if t is not None else None
         t0 = time.perf_counter()
 
         def timed(job):
@@ -610,23 +600,27 @@ class TransferEngine:
         decode_s = 0.0
         total_bytes = 0
         results: List[dict] = []
-        for fut in futs:
-            # Per-column checkpoint: remaining decodes still run on the
-            # pool (futures are not revoked) but their results are
-            # plain host arrays — nothing device-side leaks.
-            telemetry.check_deadline(phase)
-            produced, job_s = fut.result()
-            decode_s += job_s
-            placed = {}
-            for key, value in produced.items():
-                if isinstance(value, Host):
-                    placed[key] = value.value
-                elif isinstance(value, (np.ndarray, HostCast)):
-                    total_bytes += int(value.nbytes)
-                    placed[key] = self._put_entry(value, device, timings)
-                else:
-                    placed[key] = value
-            results.append(placed)
+        # One span (and, below, one accounting record) for the group.
+        with telemetry.span("hs.link.h2d", "link", direction="h2d",
+                            tag=tag) as link:
+            for fut in futs:
+                # Per-column checkpoint: remaining decodes still run on the
+                # pool (futures are not revoked) but their results are
+                # plain host arrays — nothing device-side leaks.
+                telemetry.check_deadline(phase)
+                produced, job_s = fut.result()
+                decode_s += job_s
+                placed = {}
+                for key, value in produced.items():
+                    if isinstance(value, Host):
+                        placed[key] = value.value
+                    elif isinstance(value, (np.ndarray, HostCast)):
+                        total_bytes += int(value.nbytes)
+                        placed[key] = self._put_entry(value, device, timings)
+                    else:
+                        placed[key] = value
+                results.append(placed)
+            link.set(bytes=total_bytes, chunks=timings["chunks"])
         wall = time.perf_counter() - t0
         serial_s = decode_s + timings["convert_s"] + timings["put_s"]
         saved = max(serial_s - wall, 0.0)
@@ -641,7 +635,6 @@ class TransferEngine:
                 reg.counter(f"transfer.{tag}.chunks").inc(
                     max(timings["chunks"], 1))
             telemetry.record_link_transfer("h2d", total_bytes, wall,
-                                           ts_us=ts,
                                            chunks=max(timings["chunks"],
                                                       1))
         self._sweep()

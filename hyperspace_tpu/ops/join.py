@@ -23,6 +23,7 @@ from typing import List, Sequence, Tuple
 
 from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.io.columnar import ColumnBatch
+from hyperspace_tpu.telemetry import device_scoped
 
 
 def encode_join_keys(left: ColumnBatch, right: ColumnBatch,
@@ -115,6 +116,7 @@ def _runs_to_counts(differs, side_s, left_outer: bool):
 
 @__import__("functools").partial(__import__("jax").jit,
                                  static_argnames=("left_outer",))
+@device_scoped("hs.join.match")
 def _counting_match_lanes(lanes_l, lanes_r, left_outer: bool):
     """The counting match directly over raw key LANES — ONE staged sort
     of (marker, *value lanes, side, orig) replaces the earlier two-sort
@@ -154,6 +156,7 @@ HASH_MATCH_MIN_LANES = 4
 
 @__import__("functools").partial(__import__("jax").jit,
                                  static_argnames=("left_outer",))
+@device_scoped("hs.join.match")
 def _counting_match_lanes_hashed(lanes_l, lanes_r, left_outer: bool):
     """Hashed counting match: sort (u64 key-hash, side, orig) — one
     3-operand sort regardless of key width — then derive runs from the
@@ -292,6 +295,7 @@ def counting_join_indices(l_ids, r_ids, how: str = "inner") -> Tuple:
 
 @__import__("functools").partial(__import__("jax").jit,
                                  static_argnames=("left_outer",))
+@device_scoped("hs.join.match")
 def _counting_match(l_ids, r_ids, left_outer: bool):
     import jax
     import jax.numpy as jnp
@@ -326,6 +330,7 @@ def _counting_match(l_ids, r_ids, left_outer: bool):
 
 @__import__("functools").partial(
     __import__("jax").jit, static_argnames=("total", "left_outer"))
+@device_scoped("hs.join.expand")
 def _counting_expand(counts, starts, rights, rstart, orig_s, total: int,
                      left_outer: bool):
     import jax.numpy as jnp

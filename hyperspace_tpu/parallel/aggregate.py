@@ -151,7 +151,7 @@ def distributed_group_aggregate(batch: ColumnBatch,
     reg.counter("mesh.aggregate.execs").inc()
     telemetry.event("mesh", "aggregate", shards=n_shards,
                     rows=batch.num_rows, groups=len(group_columns))
-    with telemetry.span("mesh:aggregate", "mesh", rows=batch.num_rows,
+    with telemetry.span("hs.mesh.aggregate", "mesh", rows=batch.num_rows,
                         shards=n_shards):
         return _distributed_group_aggregate(
             batch, group_columns, aggregates, out_schema, mesh, n_shards,

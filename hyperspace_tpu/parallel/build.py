@@ -280,7 +280,7 @@ def distributed_build(batch: ColumnBatch, key_columns: Sequence[str],
         step = make_distributed_build_step(mesh, key_names, num_buckets,
                                            factor)
         t0 = _time.perf_counter()
-        with telemetry.span("mesh:build:dispatch", "mesh",
+        with telemetry.span("hs.mesh.build.dispatch", "mesh",
                             shards=n_shards, rows=n):
             out = step(in_tree)
         reg.counter("mesh.build.dispatch_s").inc(

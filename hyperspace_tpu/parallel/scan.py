@@ -54,7 +54,7 @@ def shard_batch(batch: ColumnBatch, mesh):
 
     # The engine records each host column's link crossing; the span
     # keeps the placement visible as one mesh stage in traces.
-    with telemetry.span("mesh:place", "mesh", rows=n, shards=n_shards):
+    with telemetry.span("hs.mesh.place", "mesh", rows=n, shards=n_shards):
         columns: Dict[str, DeviceColumn] = {}
         for name, col in batch.columns.items():
             columns[name] = col.with_raw(
@@ -75,7 +75,7 @@ def distributed_filter(batch: ColumnBatch, expression, mesh) -> ColumnBatch:
 
     n_shards = total_shards(mesh)
     reg = telemetry.get_registry()
-    with telemetry.span("mesh:filter", "mesh", rows=batch.num_rows,
+    with telemetry.span("hs.mesh.filter", "mesh", rows=batch.num_rows,
                         shards=n_shards):
         sharded, row_valid = shard_batch(batch, mesh)
         mask = compile_predicate(expression, sharded) & row_valid

@@ -1580,7 +1580,7 @@ def sharded_join_indices(left: ShardedBatch, right: ShardedBatch,
                 # exchange, split by the link that carries each hop.
                 _record_repartition_bytes(
                     mesh, route_capacity, 8 * len(right_keys) + 10)
-            with telemetry.span("mesh:join:spmd", "mesh", how=how,
+            with telemetry.span("hs.mesh.join.spmd", "mesh", how=how,
                                 shards=S, cap=cap):
                 (li, ri, counts_d, un_gid, un_counts_d, expand_ovf,
                  route_ovf) = program(*l_in, *r_in, l_remaps, r_remaps,
@@ -1896,7 +1896,7 @@ def sharded_filter(sh: ShardedBatch, expression) -> ColumnBatch:
         b = tree_to_batch(t, schema, aux)
         return compile_predicate(expression, b) & valid
 
-    with telemetry.span("mesh:filter", "mesh", rows=sh.num_rows,
+    with telemetry.span("hs.mesh.filter", "mesh", rows=sh.num_rows,
                         shards=sh.n_shards), _dispatch_guard(sh.mesh):
         try:
             mask = instrumented_jit("mesh.spmd_filter", step)(

@@ -65,11 +65,13 @@ from typing import Dict, List, Optional
 
 from hyperspace_tpu.telemetry.registry import (MetricsRegistry,
                                                get_registry)
-from hyperspace_tpu.telemetry.trace import (Tracer, disable_tracing,
+from hyperspace_tpu.telemetry.trace import (DEVICE_SCOPES, SPAN_NAMES,
+                                            Tracer, disable_tracing,
                                             enable_tracing, export_trace,
                                             link_transfer,
                                             record_link_transfer, span,
-                                            tracer, tracing_enabled)
+                                            spans_active, tracer,
+                                            tracing_enabled)
 from hyperspace_tpu.telemetry import memory  # noqa: F401
 from hyperspace_tpu.telemetry import compilation  # noqa: F401
 from hyperspace_tpu.telemetry import artifact  # noqa: F401
@@ -79,7 +81,8 @@ from hyperspace_tpu.telemetry import timeseries  # noqa: F401
 from hyperspace_tpu.telemetry import ops_server  # noqa: F401
 from hyperspace_tpu.telemetry import critical_path  # noqa: F401
 from hyperspace_tpu.telemetry import profiler  # noqa: F401
-from hyperspace_tpu.telemetry.compilation import instrumented_jit
+from hyperspace_tpu.telemetry.compilation import (device_scoped,
+                                                  instrumented_jit)
 from hyperspace_tpu.telemetry.flight import (FlightRecorder,
                                              get_recorder)
 from hyperspace_tpu.telemetry.memory import (DeviceMemoryAccountant,
@@ -93,8 +96,9 @@ __all__ = [
     "known_tenants", "tenant_digest", "TENANT_CHARGE_COUNTERS",
     "MetricsRegistry", "get_registry", "Tracer", "enable_tracing",
     "disable_tracing", "tracing_enabled", "tracer", "span",
+    "spans_active", "SPAN_NAMES", "DEVICE_SCOPES",
     "link_transfer", "record_link_transfer", "export_trace",
-    "memory", "compilation", "instrumented_jit", "artifact", "diff",
+    "memory", "compilation", "instrumented_jit", "device_scoped", "artifact", "diff",
     "flight", "FlightRecorder", "get_recorder",
     "DeviceMemoryAccountant", "get_accountant",
     "timeseries", "ops_server", "critical_path", "profiler",
@@ -225,8 +229,10 @@ def tenant_digest() -> Dict[str, Dict[str, float]]:
     """{tenant: {charge counter: value}} for every known tenant, read
     from the registry's `tenant.<id>.*` mirrors. Tenants with zero
     usage are included (the default tenant always appears), so a
-    consumer can verify the exactness contract by summing columns."""
-    counters = get_registry().counters_dict()
+    consumer can verify the exactness contract by summing columns.
+    UNROUNDED values (`counters_dict` rounds to a microsecond, and sums
+    of rounded dispatch-seconds do not equal the rounded sum)."""
+    counters = get_registry().series_snapshot()["counters"]
     out: Dict[str, Dict[str, float]] = {}
     for t in known_tenants():
         out[t] = {name: counters.get(f"tenant.{t}.{name}", 0)

@@ -38,6 +38,7 @@ import time
 from typing import Dict, Optional
 
 from hyperspace_tpu.telemetry import registry as _registry
+from hyperspace_tpu.telemetry import trace as _trace
 
 __all__ = ["instrumented_jit", "REGISTRY", "configure_persistent_cache",
            "persistent_cache_dir", "aot_warmup", "reset_aot_memo",
@@ -251,6 +252,37 @@ def _capture_cost(name: str, jfn, args, kwargs) -> Optional[tuple]:
         _tls.in_cost_capture = False
 
 
+def device_scoped(scope: str):
+    """Decorator: the ops of `fn` carry the device scope `scope`
+    (`telemetry.DEVICE_SCOPES`) in a capture. For a function that is
+    only ever TRACED inside a jitted program (put it under the jit
+    decorator): called eagerly it would compile at every call.
+
+    A bare `jax.named_scope` is not enough here. This package asks jax
+    for one frame per MLIR location (`_jax_config.py`, for the compile
+    cache's sake), and in that form XLA's op metadata keeps the name
+    stack only for ops inside a NESTED call: a primitive traced directly
+    in the program's body comes out as `scatter-add`, one traced inside
+    a nested jit as `jit(f)/hs.compact/jit(f)/scatter-add`. So the
+    scope wraps a nested jit of the function; XLA inlines the call, the
+    program computes what it did."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            import jax
+
+            def piece():
+                return fn(*args, **kwargs)
+
+            piece.__name__ = fn.__name__
+            with jax.named_scope(scope):
+                return jax.jit(piece)()
+
+        return scoped
+
+    return decorate
+
+
 def instrumented_jit(name: str, fn=None, **jit_kwargs):
     """`jax.jit` with compile observability. Use exactly like jit:
 
@@ -294,8 +326,6 @@ def instrumented_jit(name: str, fn=None, **jit_kwargs):
         frames = _frames()
         frame = _Frame()
         frames.append(frame)
-        tracer = telemetry.tracer()
-        ts = tracer.now_us() if tracer is not None else 0.0
         t0 = time.perf_counter()
         try:
             out = jfn(*args, **kwargs)
@@ -309,6 +339,8 @@ def instrumented_jit(name: str, fn=None, **jit_kwargs):
             with _sig_lock:
                 cause = _retrace_cause(_last_sigs.get(name), sig)
                 _last_sigs[name] = sig
+            _trace.completed(f"hs.compile.{name}", "compile", elapsed,
+                             target=name, cause=cause)
             reg.counter("compile.traces").inc()
             reg.counter("compile.seconds").inc(elapsed)
             reg.counter(f"compile.{name}.traces").inc()
@@ -334,10 +366,6 @@ def instrumented_jit(name: str, fn=None, **jit_kwargs):
                             else "retrace",
                             target=name, cause=cause,
                             seconds=round(elapsed, 4))
-            if tracer is not None:
-                tracer.complete(f"compile {name}", "compile", ts,
-                                elapsed * 1e6,
-                                args={"target": name, "cause": cause})
         else:
             reg.counter("compile.cache_hits").inc()
             telemetry.memory.cache_hit("jit")

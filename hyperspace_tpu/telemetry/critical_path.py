@@ -26,7 +26,7 @@ compute can attribute more seconds than its wall, and a negative
 reported as `overlap_s`). "The decomposition couldn't explain it" is
 itself a measured number, never a silent gap.
 
-Three surfaces:
+Two surfaces:
 
 - **per query**: `stamp(metrics)` (called by the scheduler at query
   finish) attaches the decomposition as `metrics.critical_path`, so
@@ -37,13 +37,12 @@ Three surfaces:
   selects the `critpath.` family into its ring, and
   `window_shares()` derives the trailing-window share of each segment
   — what `/critpath` serves and `bench_serve.py` embeds per arrival
-  rate;
-- **timeline**: `span_timeline(metrics)` reconstructs the query's
-  span DAG from the PR-2 tracer ring (spans nest by ts/dur
-  containment per thread) and classifies each span into the same
-  closed set — the ordered blocking path a dump viewer renders next
-  to the totals. Tracing off = None, same always-off contract as
-  every tracer hook.
+  rate.
+
+The TIMELINE of a query is not rebuilt here: the span seam
+(`telemetry/trace.py`) writes the program's `hs.*` spans onto the
+profiler's clock beside the device's ops, and a capture shows the
+blocking chain itself.
 """
 
 from __future__ import annotations
@@ -53,7 +52,7 @@ from typing import Dict, List, Optional
 from hyperspace_tpu.telemetry import registry as _registry
 
 __all__ = ["SEGMENTS", "SEGMENT_SOURCES", "decompose", "stamp",
-           "window_shares", "span_timeline", "SUM_EXACT_EPSILON_S"]
+           "window_shares", "SUM_EXACT_EPSILON_S"]
 
 # The closed segment set, in blocking order (queue first, residual
 # last). Every decomposition has exactly these keys.
@@ -81,15 +80,6 @@ SEGMENT_SOURCES: Dict[str, str] = {
     "link_h2d": "link.h2d_s",
     "link_d2h": "link.d2h_s",
 }
-
-# Tracer span category/name -> segment, for the timeline view. Spans
-# in no mapped category are host work by definition.
-_SPAN_SEGMENTS = (
-    ("compile", "compile"),
-    ("link", None),            # direction decided by the span name
-    ("cache", "cache_fill_wait"),
-    ("serve.batch", "batch_window"),
-)
 
 # |sum(segments) - wall| tolerance: the residual makes the sum exact
 # by construction, so only float rounding (segments are rounded to
@@ -183,46 +173,3 @@ def window_shares(window_s: Optional[float] = None,
     if wall_rate:
         out["dominant"] = max(SEGMENTS, key=lambda s: out["shares"][s])
     return out
-
-
-def _classify_span(cat: str, name: str) -> Optional[str]:
-    for prefix, segment in _SPAN_SEGMENTS:
-        if cat == prefix or cat.startswith(prefix + "."):
-            if segment is not None:
-                return segment
-            return "link_d2h" if name.startswith("d2h") else "link_h2d"
-    return None
-
-
-def span_timeline(metrics) -> Optional[dict]:
-    """The span-DAG view of one query: tracer-ring events overlapping
-    the query's execution window, classified into the closed segment
-    set and ordered by start time — the blocking chain a dump viewer
-    renders. Spans on the query's own threads nest by containment
-    (the Chrome trace-event discipline); unclassified spans are host
-    work (`host_python`). None without an active tracer — the
-    counter-based `decompose` needs no tracer and is the sum-exact
-    source of truth; this is the visual companion."""
-    from hyperspace_tpu.telemetry import trace as _trace
-    t = _trace.tracer()
-    if t is None or metrics.wall_s is None:
-        return None
-    start_us = (metrics._t0 - t.t0_s) * 1e6
-    end_us = start_us + metrics.wall_s * 1e6
-    with t._lock:
-        events = [e for e in t.events
-                  if e.get("ph") == "X"
-                  and e.get("ts", 0) + e.get("dur", 0) >= start_us
-                  and e.get("ts", 0) <= end_us]
-    spans: List[dict] = []
-    for e in sorted(events, key=lambda e: e.get("ts", 0)):
-        segment = _classify_span(e.get("cat", ""), e.get("name", ""))
-        spans.append({
-            "t_rel_s": round((e["ts"] - start_us) / 1e6, 6),
-            "dur_s": round(e.get("dur", 0) / 1e6, 6),
-            "name": e.get("name"),
-            "cat": e.get("cat"),
-            "tid": e.get("tid"),
-            "segment": segment or "host_python",
-        })
-    return {"wall_s": round(metrics.wall_s, 6), "spans": spans}

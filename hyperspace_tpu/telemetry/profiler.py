@@ -18,7 +18,9 @@ seam in the tree (`scripts/check_metrics_coverage.py` bans the import
 anywhere else, like the ops-HTTP and link-transfer seams).
 `device_trace(path)` wraps `jax.profiler.trace` under a process lock
 (jax allows one active trace session); the executor's `trace.dir`
-per-query capture routes through it. `request_capture()` fires a
+per-query capture routes through it. `annotation(name, **args)` is the
+span seam's second sink (`telemetry/trace.py`): the program's `hs.*`
+spans as host events in whatever session is running, on its clock. `request_capture()` fires a
 BACKGROUND capture — used by the scheduler when SLO burn crosses 1.0
 and by the flight recorder when a slowlog dump lands — writing a
 `profile-*` directory next to the slow-query dumps with the same
@@ -298,6 +300,40 @@ def device_trace(path: str):
     with _trace_lock:
         with jax.profiler.trace(path):
             yield
+
+
+# The annotation class, looked up once: `jax.profiler` is imported only
+# when jax already is (no session can run before that).
+_annotation_cls = None
+
+
+def _annotations():
+    global _annotation_cls
+    if _annotation_cls is None and "jax" in sys.modules:
+        import jax.profiler
+        _annotation_cls = jax.profiler.TraceAnnotation
+    return _annotation_cls
+
+
+def annotations_enabled() -> bool:
+    """Whether a profiler session is recording host annotations right
+    now — ANY session: `device_trace`, a triggered capture, or one the
+    embedding process started itself. One C++ flag read."""
+    cls = _annotations()
+    return cls is not None and cls.is_enabled()
+
+
+def annotation(name: str, **args):
+    """An entered host annotation in the running session's trace, or
+    None where no session records (the span seam's profiler sink,
+    `telemetry/trace.py`; nothing else calls this). Close it with
+    `__exit__`; `set_metadata(**args)` adds arguments until then."""
+    cls = _annotations()
+    if cls is None or not cls.is_enabled():
+        return None
+    ann = cls(name, **args)
+    ann.__enter__()
+    return ann
 
 
 def recent_captures(n: int = 10) -> List[dict]:

@@ -390,13 +390,21 @@ class TimeSeriesSampler:
         the trailing window — the absolute-change primitive the alert
         rules' delta/ratio/trend predicates are built on (a rate hides
         "exactly one breaker opened"). covered == 0 means the ring had
-        nothing to diff against."""
+        nothing to diff against. A ring younger than the window diffs
+        against its OLDEST sample, not against zero: the counters are
+        the process's, and a sampler started later than the process
+        would otherwise report every count made before it as made in
+        its first window (`breaker_open` firing for a breaker that
+        opened an hour ago)."""
         latest = self._latest()
         if latest is None:
             return 0.0, 0.0
         t0 = since_t if since_t is not None \
             else latest.t - (window_s or self.window_s)
         base = self._baseline(t0)
+        if base is None:
+            with self._lock:
+                base = self._ring[0]
         now_v = latest.counters.get(name, 0.0)
         then_v = base.counters.get(name, 0.0) if base is not None else 0.0
         elapsed = latest.t - (base.t if base is not None else t0)

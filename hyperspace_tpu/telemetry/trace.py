@@ -19,6 +19,18 @@ https://ui.perfetto.dev load directly.
 Timestamps are microseconds on the tracer's own perf_counter clock —
 the Chrome format needs only internal consistency, and perf_counter is
 the engine's timing base everywhere else.
+
+ONE seam, TWO sinks: `span(...)` also opens a host annotation named as
+the span (`hs.*`, `SPAN_NAMES`) in the trace of any running
+jax profiler session, through `telemetry/profiler.annotation` — so a
+device capture shows the program's own layers on the profiler's clock
+beside the device's ops, and a reducer can give each idle gap of the
+chip to the layer the host was in. The ring is for the Chrome export;
+the annotations are for captures. No conf key, no environment
+variable: a sink records when its owner runs (`enable_tracing()`, a
+profiler session), and with neither a span costs two flag reads.
+`DEVICE_SCOPES` are the matching names ON the device
+(`jax.named_scope` inside jitted programs).
 """
 
 from __future__ import annotations
@@ -27,20 +39,88 @@ import json
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Dict, List, Optional
 
+from hyperspace_tpu.telemetry import profiler as _profiler
 from hyperspace_tpu.telemetry import registry as _registry
 
 __all__ = ["Tracer", "enable_tracing", "disable_tracing",
-           "tracing_enabled", "tracer", "span", "link_transfer",
-           "record_link_transfer", "export_trace", "PID_ENGINE",
+           "tracing_enabled", "tracer", "span", "spans_active",
+           "completed", "link_transfer", "record_link_transfer",
+           "export_trace", "SPAN_NAMES", "DEVICE_SCOPES", "PID_ENGINE",
            "PID_MESH"]
 
 # Trace "processes": real engine threads vs the synthetic per-device
 # tracks (tid = device ordinal) mesh dispatches attribute work to.
 PID_ENGINE = 1
 PID_MESH = 2
+
+# Every span name the engine opens, by layer (docs/telemetry.md and
+# PERF.md quote this table; `bench/lib/program_spans.py` groups by these
+# prefixes). `<...>` stands for a class or function name. Stable,
+# lower-case, dotted; a refactor keeps them.
+SPAN_NAMES = {
+    # serving plane — engine/scheduler.collect
+    "hs.serve.admit": "queue wait + admission bookkeeping (queue_wait_s)",
+    "hs.query": "planning + execution of one query, under its recorder",
+    "hs.serve.credit": "footprint re-projection + residency credit",
+    "hs.serve.finish": "after execution: metrics.finish, critical-path "
+                       "stamp, registry + SLO updates, index-usage "
+                       "mining, flight ring",
+    "hs.serve.batch": "one batched invocation for a cohort (members)",
+    "hs.serve.batch.member": "a member's wait on its cohort",
+    # planner — in the query's own path
+    "hs.plan.optimize": "session.optimize: the rewrite rules",
+    "hs.plan.compile": "compile_plan: physical planning + fusion "
+                       "grouping",
+    # operators — engine/physical wrapper, on the executing thread
+    "hs.op.<Name>": "one physical operator (lane, rows)",
+    # fused stage — engine/fusion.py; sync + compact also in the unfused
+    # filters (engine/physical.FilterExec, engine/compiler.apply_filter)
+    "hs.stage.dispatch": "the stage program's dispatch (ops, cache_hit)",
+    "hs.stage.sync": "host blocked on a filter's survivor count(s)",
+    "hs.stage.compact": "nonzero(size=) + take of the survivors (rows)",
+    "hs.stage.gather": "the deferred lazy gathers (columns)",
+    # link / residency
+    "hs.segcache.fill": "a segment-cache fill (index, files)",
+    "hs.link.h2d": "a host-to-device placement (bytes, chunks)",
+    "hs.link.d2h": "a device-to-host fetch (bytes)",
+    "hs.to_arrow": "ColumnBatch -> Arrow table (rows, columns)",
+    "hs.to_arrow.prefetch": "issuing the async D2H copies",
+    # index build — io/builder.py, actions/
+    "hs.build.read": "source Parquet decode (files, rows)",
+    "hs.build.sort": "bucket hash + (bucket, keys) sort permutation "
+                     "(lane, rows)",
+    "hs.build.write": "gather + encode + file writes, on the calling "
+                      "thread (files)",
+    "hs.build.write.file": "one file's encode + write, on the writer "
+                           "thread (rows)",
+    "hs.action.<Class>": "one maintenance action's run()",
+    "hs.action.<Class>.<phase>": "validate / begin / op / end",
+    # mesh (multi-chip)
+    "hs.mesh.place": "placing a batch over the mesh (rows, shards)",
+    "hs.mesh.filter": "the distributed / SPMD filter",
+    "hs.mesh.aggregate": "the distributed aggregate",
+    "hs.mesh.build.dispatch": "the mesh build step's dispatch",
+    "hs.mesh.join.spmd": "the SPMD join program",
+    # ring only (recognised in hindsight, `completed`)
+    "hs.compile.<name>": "a dispatch of jit entry point <name> that "
+                         "traced + compiled",
+}
+
+# Names on the DEVICE: scopes inside jitted programs
+# (`telemetry.device_scoped`), so an op's scope path in a capture (the
+# `tf_op` of an `XLA Ops` event's metadata:
+# `jit(hs_compact)/hs.compact/jit(hs_compact)/scatter-add:`) says which
+# piece it belongs to whatever implements it. Metadata only: no program
+# computes anything else.
+DEVICE_SCOPES = {
+    "hs.predicate": "a filter predicate's mask",
+    "hs.segsum": "per-bucket survivor counts (segment sum of the mask)",
+    "hs.compact": "mask -> survivor indices (nonzero(size=))",
+    "hs.join.match": "the counting join's match program",
+    "hs.join.expand": "the counting join's expansion to row pairs",
+}
 
 _tracer: Optional["Tracer"] = None
 
@@ -183,32 +263,141 @@ def tracer() -> Optional[Tracer]:
     return _tracer
 
 
-@contextmanager
-def span(name: str, cat: str = "engine", **args):
-    """Trace the enclosed block as a complete event on this thread.
-    No-op (one global read) without an active tracer."""
+class span:
+    """THE span seam: name the enclosed block on this thread, in both
+    sinks — the ring (when `enable_tracing()` installed one) and the
+    trace of whatever jax profiler session is running (`hs.*` host
+    events on the profiler's clock, beside the device's ops). With
+    neither, entering costs one global read and one C++ flag read and
+    appends nothing anywhere.
+
+        with telemetry.span("hs.stage.compact", "fusion", rows=n) as sp:
+            ...
+            sp.set(bytes=nbytes)   # what is known only at the end
+
+    Every span carries the active query's identifier as `qid`: the one
+    passed in (where no recorder is active: admission, the epilogue),
+    else the QueryMetrics recorder's (which `telemetry.propagating`
+    carries onto pool threads), else the enclosing span's. The span
+    that caused a span is the one that contains it on its thread;
+    across threads the `qid` is the link. An exception leaving the
+    block is recorded as `error`. Names come from `SPAN_NAMES`."""
+
+    __slots__ = ("name", "cat", "args", "_ring", "_ann", "_ts", "_outer")
+
+    def __init__(self, name: str, cat: str = "engine", **args):
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self._ring = self._ann = None
+        self._outer = _OFF
+
+    def __enter__(self):
+        ring = _tracer
+        live = _profiler.annotations_enabled()
+        if ring is None and not live:
+            return self
+        args = self.args
+        self._outer = getattr(_enclosing, "qid", None)
+        if args.get("qid") is None:
+            args["qid"] = _active_query_id() or self._outer
+        _enclosing.qid = args["qid"]
+        if live:
+            self._ann = _profiler.annotation(self.name, **_plain(args))
+        if ring is not None:
+            self._ring = ring
+            self._ts = ring.now_us()
+        return self
+
+    def set(self, **args) -> None:
+        """Add arguments to an open span (no-op when nothing records)."""
+        if self._ann is not None:
+            self._ann.set_metadata(**_plain(args))
+        if self._ring is not None:
+            self.args.update(args)
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._outer is _OFF:
+            return False
+        if exc is not None:
+            self.set(error=repr(exc))
+        ann, ring = self._ann, self._ring
+        self._ann = self._ring = None
+        _enclosing.qid, self._outer = self._outer, _OFF
+        if ann is not None:
+            ann.__exit__(exc_type, exc, tb)
+        if ring is not None:
+            args = {k: v for k, v in self.args.items() if v is not None}
+            ring.complete(self.name, self.cat, self._ts,
+                          ring.now_us() - self._ts, args=args or None)
+        return False
+
+
+def spans_active() -> bool:
+    """Whether a span opened now would be recorded by either sink —
+    for hooks that skip more than the span itself when nobody
+    listens (the operator wrapper)."""
+    return _tracer is not None or _profiler.annotations_enabled()
+
+
+def completed(name: str, cat: str, seconds: float, **args) -> None:
+    """A RING-ONLY event for work recognised in hindsight, ending now
+    (a dispatch that turned out to compile: `telemetry/compilation.py`).
+    A profiler annotation cannot be back-dated, and the profiler's
+    trace has XLA's own compile events."""
     t = _tracer
-    if t is None:
-        yield
-        return
-    ts = t.now_us()
-    try:
-        yield
-    finally:
-        t.complete(name, cat, ts, t.now_us() - ts, args=args or None)
+    if t is not None:
+        end = t.now_us()
+        t.complete(name, cat, end - seconds * 1e6, seconds * 1e6,
+                   args=args or None)
+
+
+_ANNOTATION_ARG_CHARS = 120
+_annotation_unsafe = str.maketrans({"#": ";", ",": ";", "=": ":",
+                                    "\n": " "})
+
+
+def _plain(args: dict) -> dict:
+    """Arguments as a profiler annotation can carry them (the event's
+    name is extended by `#k=v,k=v#`): numbers as they are, anything
+    else as a short string without the separators; None left out."""
+    out = {}
+    for k, v in args.items():
+        if v is None:
+            continue
+        if not isinstance(v, (int, float)):
+            v = str(v)[:_ANNOTATION_ARG_CHARS].translate(_annotation_unsafe)
+        out[k] = v
+    return out
+
+
+_current_recorder = None
+_enclosing = threading.local()  # .qid of the innermost recorded span
+_OFF = object()  # a span's `_outer` while nothing records it
+
+
+def _active_query_id() -> Optional[str]:
+    global _current_recorder
+    if _current_recorder is None:
+        from hyperspace_tpu import telemetry
+        _current_recorder = telemetry.current
+    rec = _current_recorder()
+    return getattr(rec, "query_id", None) if rec is not None else None
 
 
 def record_link_transfer(direction: str, nbytes: int, seconds: float,
-                         ts_us: Optional[float] = None,
                          chunks: int = 1) -> None:
-    """Record one device-link transfer (`direction` = "h2d" | "d2h"):
+    """Account one device-link transfer (`direction` = "h2d" | "d2h"):
     registry counters + log-bucketed byte/seconds histograms ALWAYS, a
-    per-query counter when a recorder is active, a span when tracing.
-    `chunks` is how many pipelined chunk puts the logical transfer
-    shipped as (`io/transfer.py`) — `link.<dir>.chunks` vs
-    `link.<dir>.transfers` is the chunking ratio. jax dispatch is
-    asynchronous — the measured wall is dispatch-side unless the
-    measuring code synced; the byte counts are exact either way."""
+    per-query counter when a recorder is active. The timeline side is
+    `link_transfer` (or an `hs.link.<dir>` span of the caller's own
+    where one accounting record covers several crossings, as in
+    `io/columnar.to_arrow`). `chunks` is how many pipelined chunk puts
+    the logical transfer shipped as (`io/transfer.py`) —
+    `link.<dir>.chunks` vs `link.<dir>.transfers` is the chunking
+    ratio. jax dispatch is asynchronous — the measured wall is
+    dispatch-side unless the measuring code synced; the byte counts are
+    exact either way."""
     reg = _registry.get_registry()
     reg.counter(f"link.{direction}.bytes").inc(nbytes)
     reg.counter(f"link.{direction}.seconds").inc(seconds)
@@ -222,32 +411,42 @@ def record_link_transfer(direction: str, nbytes: int, seconds: float,
     telemetry.charge_tenant(f"link.{direction}.bytes", nbytes)
     telemetry.add_seconds(f"link.{direction}_s", seconds)
     telemetry.add_count(f"link.{direction}_bytes", int(nbytes))
-    t = _tracer
-    if t is not None:
-        end = t.now_us()
-        start = end - seconds * 1e6 if ts_us is None else ts_us
-        t.complete(f"{direction} {int(nbytes):,}B", "link", start,
-                   end - start,
-                   args={"bytes": int(nbytes), "direction": direction})
     # Every instrumented transfer moves device residency: fold a memory
     # sample (throttled; no-op unless a recorder or tracer is active).
     from hyperspace_tpu.telemetry import memory as _memory
     _memory.maybe_sample()
 
 
-@contextmanager
-def link_transfer(direction: str, nbytes: int, chunks: int = 1):
-    """Context-manager form of `record_link_transfer`: times the
-    enclosed block as the transfer wall."""
-    t = _tracer
-    ts = t.now_us() if t is not None else None
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        record_link_transfer(direction, nbytes,
-                             time.perf_counter() - t0, ts_us=ts,
-                             chunks=chunks)
+_LINK_SPANS = {"h2d": "hs.link.h2d", "d2h": "hs.link.d2h"}
+
+
+class link_transfer:
+    """One link crossing as a block: an `hs.link.<direction>` span
+    around it and, on the way out, `record_link_transfer` with the
+    block's wall. `chunks` may be set on the yielded object before the
+    block ends (a chunked put learns its count as it goes)."""
+
+    __slots__ = ("direction", "nbytes", "chunks", "_span", "_t0")
+
+    def __init__(self, direction: str, nbytes: int, chunks: int = 1):
+        self.direction = direction
+        self.nbytes = nbytes
+        self.chunks = chunks
+
+    def __enter__(self):
+        self._span = span(_LINK_SPANS[self.direction], "link",
+                          direction=self.direction)
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        seconds = time.perf_counter() - self._t0
+        self._span.set(bytes=int(self.nbytes), chunks=int(self.chunks))
+        self._span.__exit__(exc_type, exc, tb)
+        record_link_transfer(self.direction, self.nbytes, seconds,
+                             chunks=self.chunks)
+        return False
 
 
 def export_trace(path: str) -> dict:

@@ -807,6 +807,72 @@ def check_profiler_seam(package_dir: str):
     return failures
 
 
+# The ONE span seam: `telemetry.span(...)` (`telemetry/trace.py`) names a
+# block in both sinks — the ring and the running profiler session's
+# trace. Every name it is given comes from `SPAN_NAMES`, which
+# docs/telemetry.md, PERF.md and the benchmark's reader quote: a span
+# opened under a name the table lacks is a layer no reducer groups, and
+# a direct `Tracer.complete` elsewhere is a span one sink never sees.
+_SPAN_CALL_RE = re.compile(
+    r"""\bspan\(\s*(f?)["']([^"']+)["'](\s*\+)?""")
+_RING_COMPLETE_RE = re.compile(r"\.complete\(")
+_SPAN_SEAM = os.path.join("telemetry", "trace.py")
+
+
+def _span_pattern(name: str) -> str:
+    return re.sub(r"<[^>]*>|\{[^}]*\}", "<>", name)
+
+
+def check_span_seam(package_dir: str, repo_root: str):
+    """Source + doc-drift lint for the span seam: every literal name a
+    `span(...)` call opens is in `telemetry.SPAN_NAMES`; no
+    `Tracer.complete` outside `telemetry/trace.py`; every name of
+    `SPAN_NAMES` and `DEVICE_SCOPES` has its row in docs/telemetry.md."""
+    from hyperspace_tpu import telemetry
+    known = {_span_pattern(n) for n in telemetry.SPAN_NAMES}
+    failures = []
+    for root, _dirs, files in os.walk(package_dir):
+        if "__pycache__" in root:
+            continue
+        for fname in files:
+            if not fname.endswith(".py"):
+                continue
+            path = os.path.join(root, fname)
+            rel = os.path.relpath(path, package_dir)
+            with open(path, encoding="utf-8") as f:
+                text = f.read()
+            for m in _SPAN_CALL_RE.finditer(text):
+                lineno = text.count("\n", 0, m.start()) + 1
+                name = m.group(2) + ("{}" if m.group(3) else "")
+                if _span_pattern(name) not in known:
+                    failures.append(
+                        f"hyperspace_tpu/{rel}:{lineno}: span name "
+                        f"{name!r} is not in telemetry.SPAN_NAMES — add "
+                        "it to the table (and docs/telemetry.md) or use "
+                        "a name that is there")
+            if rel == _SPAN_SEAM:
+                continue
+            for lineno, line in enumerate(text.splitlines(), 1):
+                if _RING_COMPLETE_RE.search(line):
+                    failures.append(
+                        f"hyperspace_tpu/{rel}:{lineno}: direct "
+                        "Tracer.complete outside the span seam — open a "
+                        "telemetry.span (both sinks) instead")
+    doc_path = os.path.join(repo_root, "docs", "telemetry.md")
+    try:
+        with open(doc_path, encoding="utf-8") as f:
+            doc = f.read()
+    except OSError:
+        return failures + [f"{doc_path}: missing — the span-name table "
+                           "lives there"]
+    for name in list(telemetry.SPAN_NAMES) + list(telemetry.DEVICE_SCOPES):
+        if f"`{name}`" not in doc:
+            failures.append(
+                f"hyperspace_tpu/telemetry/trace.py: span/scope name "
+                f"{name!r} has no row in docs/telemetry.md's span table")
+    return failures
+
+
 def check_critpath_doc_rows(repo_root: str):
     """Doc-drift lint for the critical-path family: the per-segment
     counters are emitted with an f-string
@@ -1003,6 +1069,9 @@ def main() -> int:
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
     failures.extend(check_profiler_seam(
         os.path.dirname(hyperspace_tpu.__file__)))
+    failures.extend(check_span_seam(
+        os.path.dirname(hyperspace_tpu.__file__),
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
     failures.extend(check_critpath_doc_rows(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
     failures.extend(check_alert_rule_doc_rows(

@@ -16,7 +16,8 @@ from hyperspace_tpu import telemetry
 from hyperspace_tpu.config import HyperspaceConf
 from hyperspace_tpu.engine.session import HyperspaceSession
 from hyperspace_tpu.plan.expr import col, lit
-from hyperspace_tpu.telemetry import critical_path, flight, timeseries
+from hyperspace_tpu.telemetry import (critical_path, flight, timeseries,
+                                      trace)
 from hyperspace_tpu.telemetry.critical_path import (SEGMENT_SOURCES,
                                                     SEGMENTS,
                                                     SUM_EXACT_EPSILON_S)
@@ -172,30 +173,50 @@ def test_sum_exact_under_concurrent_stamping():
 
 
 # ---------------------------------------------------------------------------
-# Span classification (the timeline view)
+# The timeline view is the span seam's now (`telemetry/trace.py`): what a
+# span records, whichever sink listens
 # ---------------------------------------------------------------------------
 
 
-def test_span_classification_closed_set():
-    cases = [
-        (("compile", "jit_lower"), "compile"),
-        (("compile.aot", "warmup"), "compile"),
-        (("link", "h2d_chunk"), "link_h2d"),
-        (("link", "d2h_fetch"), "link_d2h"),
-        (("cache", "fill"), "cache_fill_wait"),
-        (("serve.batch", "gather"), "batch_window"),
-        (("plan", "rewrite"), None),       # host work by definition
-        (("serving", "admit"), None),      # no prefix-confusion
-    ]
-    for (cat, name), want in cases:
-        assert critical_path._classify_span(cat, name) == want, (cat,
-                                                                 name)
+def test_span_records_errors_late_arguments_and_inherits_the_id():
+    tracer = telemetry.enable_tracing()
+    try:
+        with pytest.raises(ValueError):
+            with telemetry.span("hs.serve.admit", "serve", qid="q-x") as sp:
+                sp.set(queue_wait_s=0.5)
+                with telemetry.span("hs.to_arrow.prefetch", "api"):
+                    pass  # no recorder here: the enclosing span's id
+                raise ValueError("boom")
+        seen = []
+
+        def worker():
+            with telemetry.span("hs.build.write.file", "build"):
+                seen.append(threading.get_ident())
+
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join()
+        events = {e["name"]: e for e in tracer.events}
+    finally:
+        telemetry.disable_tracing()
+    admit = events["hs.serve.admit"]
+    assert admit["args"]["qid"] == "q-x"
+    assert admit["args"]["queue_wait_s"] == 0.5
+    assert "boom" in admit["args"]["error"]
+    assert events["hs.to_arrow.prefetch"]["args"]["qid"] == "q-x"
+    # another thread inherits nothing; nothing leaks past the block
+    assert "args" not in events["hs.build.write.file"]
+    assert events["hs.build.write.file"]["tid"] == seen[0]
+    assert getattr(trace._enclosing, "qid", None) is None
 
 
-def test_span_timeline_none_without_tracer():
-    from hyperspace_tpu.telemetry import trace
-    assert trace.tracer() is None  # the suite's always-off default
-    assert critical_path.span_timeline(_finished_metrics()) is None
+def test_annotation_arguments_are_plain():
+    plain = trace._plain({"rows": 5, "share": 0.25, "none": None,
+                          "lane": "device", "ok": True,
+                          "description": "a, b=c #d\ne", "obj": [1, 2]})
+    assert plain == {"rows": 5, "share": 0.25, "lane": "device", "ok": True,
+                     "description": "a; b:c ;d e", "obj": "[1; 2]"}
+    assert len(trace._plain({"e": "x" * 999})["e"]) == 120
 
 
 # ---------------------------------------------------------------------------

@@ -28,7 +28,11 @@ MIB = 1024 * 1024
 
 
 def _counter(name):
-    return telemetry.get_registry().counters_dict().get(name, 0)
+    # Unrounded: `counters_dict` rounds to 1e-6, and a delta of two
+    # rounded dispatch-seconds readings is off by up to a microsecond
+    # once any earlier test in the process has dispatched to a device.
+    return telemetry.get_registry().series_snapshot()["counters"].get(
+        name, 0)
 
 
 @pytest.fixture

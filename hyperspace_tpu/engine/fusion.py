@@ -753,8 +753,6 @@ class FusedStageExec(PhysicalNode):
         return self._execute_device(batches, preps)
 
     def _execute_device(self, batches, preps) -> Optional[ColumnBatch]:
-        import jax.numpy as jnp
-
         key = self._program_key(batches, preps)
         if key in _INELIGIBLE_KEYS:
             telemetry.event("fusion", "lane", lane="eager",
@@ -838,7 +836,7 @@ class FusedStageExec(PhysicalNode):
                 count = int(cnt)  # THE stage sync
             _stat("sync_s", _time.perf_counter() - t0)
             with telemetry.span("hs.stage.compact", "fusion", rows=count):
-                idx = compact_indices(sel, count).astype(jnp.int32)
+                idx = compact_indices(sel, count)
                 base = base.take(idx)
         if not lazy_specs:
             return base

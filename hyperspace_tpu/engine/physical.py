@@ -556,7 +556,7 @@ class FilterExec(PhysicalNode):
     def execute_bucketed(self, num_buckets: int):
         """Filter preserves bucket grouping: the compaction gather is
         stable-ascending, so surviving rows stay in bucket order; new
-        per-bucket lengths are segment sums of the mask."""
+        per-bucket lengths are the mask's true rows per bucket."""
         import numpy as np
         from hyperspace_tpu.engine.compiler import compile_predicate
 
@@ -572,14 +572,14 @@ class FilterExec(PhysicalNode):
                                       minlength=num_buckets).astype(np.int64)
             indices = np.nonzero(mask)[0].astype(np.int32)
             return batch.take(indices), new_lengths
-        # Per-bucket survivor counts as ONE device segment-sum (row ->
-        # bucket via searchsorted over the running lengths), then a single
+        # Per-bucket survivor counts as ONE device program (the mask's
+        # prefix sum read at the buckets' ends), then a single
         # [num_buckets] transfer sizes both the new lengths and the gather.
         from hyperspace_tpu.ops.compact import (bucket_survivors,
                                                 compact_indices)
         with telemetry.span("hs.stage.sync", "operator"):
             new_lengths = np.asarray(bucket_survivors(
-                mask, lengths, num_buckets)).astype(np.int64)
+                mask, lengths)).astype(np.int64)
         count = int(new_lengths.sum())
         with telemetry.span("hs.stage.compact", "operator", rows=count):
             return batch.take(compact_indices(mask, count)), new_lengths

@@ -262,8 +262,8 @@ def device_scoped(scope: str):
     for one frame per MLIR location (`_jax_config.py`, for the compile
     cache's sake), and in that form XLA's op metadata keeps the name
     stack only for ops inside a NESTED call: a primitive traced directly
-    in the program's body comes out as `scatter-add`, one traced inside
-    a nested jit as `jit(f)/hs.compact/jit(f)/scatter-add`. So the
+    in the program's body comes out as `gather`, one traced inside
+    a nested jit as `jit(f)/hs.compact/jit(f)/gather`. So the
     scope wraps a nested jit of the function; XLA inlines the call, the
     program computes what it did."""
     def decorate(fn):

@@ -79,7 +79,7 @@ SPAN_NAMES = {
     # filters (engine/physical.FilterExec, engine/compiler.apply_filter)
     "hs.stage.dispatch": "the stage program's dispatch (ops, cache_hit)",
     "hs.stage.sync": "host blocked on a filter's survivor count(s)",
-    "hs.stage.compact": "nonzero(size=) + take of the survivors (rows)",
+    "hs.stage.compact": "the survivors' indices + take of them (rows)",
     "hs.stage.gather": "the deferred lazy gathers (columns)",
     # link / residency
     "hs.segcache.fill": "a segment-cache fill (index, files)",
@@ -111,13 +111,14 @@ SPAN_NAMES = {
 # Names on the DEVICE: scopes inside jitted programs
 # (`telemetry.device_scoped`), so an op's scope path in a capture (the
 # `tf_op` of an `XLA Ops` event's metadata:
-# `jit(hs_compact)/hs.compact/jit(hs_compact)/scatter-add:`) says which
+# `jit(hs_compact)/hs.compact/jit(hs_compact)/gather:`) says which
 # piece it belongs to whatever implements it. Metadata only: no program
 # computes anything else.
 DEVICE_SCOPES = {
     "hs.predicate": "a filter predicate's mask",
-    "hs.segsum": "per-bucket survivor counts (segment sum of the mask)",
-    "hs.compact": "mask -> survivor indices (nonzero(size=))",
+    "hs.segsum": "per-bucket survivor counts (the mask's prefix sum at "
+                 "the buckets' ends)",
+    "hs.compact": "mask -> survivor indices (rank select or sort select)",
     "hs.join.match": "the counting join's match program",
     "hs.join.expand": "the counting join's expansion to row pairs",
 }

@@ -41,9 +41,20 @@ def _scan_bytes(metrics) -> int:
 @pytest.fixture(autouse=True)
 def fresh_ring_and_cache():
     """Advisor tests read the PROCESS flight ring: empty it first so
-    other suites' queries (over now-deleted tmp dirs) are not mined."""
+    other suites' queries (over now-deleted tmp dirs) are not mined.
+    The scorer also reads the PROCESS registry's measured skipping
+    prune fractions (`whatif.measured_prune_fraction`): what other
+    suites' skipping queries measured would re-rank the candidates here
+    (the skipping one first, and the lease tests see `exists` for
+    `conflict`), so those series start absent too."""
     telemetry.get_recorder().clear()
     segcache.set_cache(segcache.SegmentCache())
+    registry = telemetry.get_registry()
+    with registry._lock:
+        for name in [n for n in registry._metrics
+                     if n.startswith("skipping.")
+                     and n.endswith("measured_prune_fraction")]:
+            del registry._metrics[name]
     yield
     telemetry.get_recorder().clear()
     segcache.set_cache(segcache.SegmentCache())

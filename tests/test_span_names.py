@@ -14,10 +14,33 @@ from hyperspace_tpu import telemetry
 from span_seam_helpers import REPO_ROOT
 
 
-def test_the_filter_sizing_programs_are_named_and_exact():
-    """`hs_compact` / `hs_segsum`: one named program each, their ops
-    under the device scope, the same numbers as numpy."""
+def _scoped_ops(program, scope, args, static, needs):
+    """The compiled program is named, holds no scatter and no `while`,
+    and every op of the kinds `needs` sits under the device scope (the
+    chip's own program, its window reductions included, is read in
+    `test_tpu_compile.py`: the CPU's compiler rewrites those)."""
     import jax
+
+    fn = program.__wrapped__
+    hlo = jax.jit(fn, static_argnames=tuple(static)).lower(
+        *args, **static).compile().as_text()
+    assert f"jit_{fn.__name__}" in hlo.splitlines()[0]
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    kinds = {n.rsplit("/", 1)[-1] for n in names}
+    assert not [k for k in kinds if "scatter" in k or k == "while"], kinds
+    under = f"jit({fn.__name__})/{scope}/"
+    for kind in needs:
+        mine = [n for n in names if n.endswith("/" + kind)]
+        assert mine and all(n.startswith(under) for n in mine), (kind, names)
+        # none of the kind outside the scope ("sort" alone also names
+        # the comparator's two parameters)
+        assert kind == "sort" or kind not in names, (kind, names)
+
+
+def test_the_filter_sizing_programs_are_named_and_exact(monkeypatch):
+    """`hs_compact` / `hs_segsum`: one named program each, their ops
+    under the device scope, the same numbers as numpy, and no scatter
+    over the mask's rows on either side of the compaction's selection."""
     import jax.numpy as jnp
 
     from hyperspace_tpu.ops import compact
@@ -28,27 +51,25 @@ def test_the_filter_sizing_programs_are_named_and_exact():
     idx = np.asarray(compact.compact_indices(jnp.asarray(mask),
                                              int(mask.sum())))
     assert np.array_equal(idx, np.nonzero(mask)[0])
-    got = np.asarray(compact.bucket_survivors(jnp.asarray(mask), lengths, 4))
+    got = np.asarray(compact.bucket_survivors(jnp.asarray(mask), lengths))
     bounds = np.concatenate([[0], np.cumsum(lengths)])
     want = [int(mask[bounds[i]:bounds[i + 1]].sum()) for i in range(4)]
     assert got.tolist() == want
     # The names reach the compiled program's op metadata (what a device
     # capture shows): with this package's one-frame-per-location setting
-    # a bare `jax.named_scope` would leave `scatter-add` bare.
-    for program, scope, args, static in (
-            (compact._compact_jit, "hs.compact", (jnp.asarray(mask),),
-             {"size": 7}),
-            (compact._segsum_jit, "hs.segsum",
-             (jnp.asarray(mask), jnp.asarray(lengths)),
-             {"num_segments": 4})):
-        fn = program.__wrapped__
-        hlo = jax.jit(fn, static_argnames=tuple(static)).lower(
-            *args, **static).compile().as_text()
-        assert f"jit_{fn.__name__}" in hlo.splitlines()[0]
-        scatters = [n for n in re.findall(r'op_name="([^"]*)"', hlo)
-                    if n.endswith("/scatter-add")]
-        assert scatters and all(
-            n.startswith(f"jit({fn.__name__})/{scope}/") for n in scatters)
+    # a bare `jax.named_scope` would leave the gathers bare. Each side of
+    # the compaction's selection (rank select: the prefix sum and
+    # gathers; sort select) in a fresh trace.
+    for sparse, needs in ((True, ("gather",)), (False, ("sort",))):
+        monkeypatch.setattr(compact, "_rank_select_wins",
+                            lambda rows, size, sparse=sparse: sparse)
+        monkeypatch.setattr(compact, "_compact_jit", None)
+        assert np.array_equal(idx, np.asarray(compact.compact_indices(
+            jnp.asarray(mask), int(mask.sum()))))
+        _scoped_ops(compact._compact_jit, "hs.compact",
+                    (jnp.asarray(mask),), {"size": 7}, needs)
+    _scoped_ops(compact._segsum_jit, "hs.segsum",
+                (jnp.asarray(mask), jnp.asarray(lengths)), {}, ("gather",))
     assert set(telemetry.DEVICE_SCOPES) >= {"hs.compact", "hs.segsum",
                                             "hs.predicate"}
 

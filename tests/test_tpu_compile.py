@@ -167,3 +167,48 @@ def test_fused_filter_stage_compiles_for_v5e(one_chip, no_compile_cache,
         prog.tables_meta)
     compiled = fusion._run_stage_jit.lower(wide_prog, wide, tables).compile()
     assert compiled.memory_analysis().argument_size_in_bytes == ROWS * 32
+
+
+@pytest.mark.parametrize("program,side", [("hs_compact", "sparse"),
+                                          ("hs_compact", "dense"),
+                                          ("hs_segsum", "sparse")])
+def test_filter_sizing_programs_are_scoped_on_v5e(one_chip, no_compile_cache,
+                                                  monkeypatch, program, side):
+    """What a device capture will show of `ops/compact.py`, at the range
+    cell's shape (60,004 of 6,000,000 rows): every op of the chip's own
+    program under the device scope — the prefix sum's window reductions
+    too, which `jnp.cumsum` would leave bare — and no scatter and no
+    `while` on either side of the compaction's selection (the dense side
+    at 65,536 rows: a sort of millions takes several seconds to compile)."""
+    import re
+
+    from hyperspace_tpu.ops import compact
+
+    rows, size = (6_000_000, 60_004) if side == "sparse" else (65_536, 60_004)
+    monkeypatch.setattr(compact, "_rank_select_wins",
+                        lambda rows, size: side == "sparse")
+    monkeypatch.setattr(compact, "_compact_jit", None)  # fresh traces
+    monkeypatch.setattr(compact, "_segsum_jit", None)
+    compact.compact_indices(jnp.zeros(8, bool), 1)
+    compact.bucket_survivors(jnp.zeros(8, bool), [8])
+    mask = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+    if program == "hs_compact":
+        scope = "hs.compact"
+        lowered = jax.jit(compact._compact_jit.__wrapped__,
+                          static_argnames=("size",)).lower(mask, size=size)
+    else:
+        scope = "hs.segsum"
+        lowered = jax.jit(compact._segsum_jit.__wrapped__).lower(
+            mask, jax.ShapeDtypeStruct((64,), jnp.int64, sharding=one_chip))
+    hlo = lowered.compile().as_text()
+    assert f"jit_{program}" in hlo.splitlines()[0]
+    entry = hlo[hlo.index("\nENTRY "):]
+    timed = [line for line in entry.splitlines() if re.search(
+        r" (fusion|reduce-window|gather|sort|scatter|while)\(", line)]
+    kinds = {re.search(r" ([a-z-]+)\(", line).group(1) for line in timed}
+    assert not kinds & {"scatter", "while"}, kinds
+    assert ("sort" in kinds) == (side == "dense"), kinds
+    assert side == "dense" or {"reduce-window", "fusion"} <= kinds, kinds
+    bare = [line.split("=")[0].strip() for line in timed
+            if f'op_name="jit({program})/{scope}/' not in line]
+    assert timed and not bare, bare

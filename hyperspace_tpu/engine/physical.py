@@ -586,7 +586,8 @@ class FilterExec(PhysicalNode):
 
     def execute_sharded(self, num_buckets: int, mesh, align_plan=None):
         """Filter preserves the sharded layout: rows never move, the
-        predicate mask just narrows `row_valid` — each device evaluates
+        predicate mask just narrows `row_valid` — one SPMD program
+        (`spmd.sharded_predicate_mask`) in which each device evaluates
         its shard, nothing crosses the link, and the downstream join /
         aggregate skips masked rows exactly as it skips padding. The
         per-bucket histogram is stale after filtering, so it is dropped
@@ -597,12 +598,13 @@ class FilterExec(PhysicalNode):
                                         align_plan=align_plan)
         if sh is None:
             return None
-        from hyperspace_tpu.engine.compiler import compile_predicate
-        from hyperspace_tpu.parallel.spmd import (
-            ShardedBatch, count_string_predicate_lookups)
-        count_string_predicate_lookups(self.condition, sh.batch)
-        mask = compile_predicate(self.condition, sh.batch)
-        return ShardedBatch(sh.batch, sh.row_valid & mask, sh.mesh,
+        from hyperspace_tpu.parallel.spmd import (ShardedBatch,
+                                                  sharded_predicate_mask)
+        with telemetry.span("hs.mesh.filter", "mesh",
+                            rows=(sh.num_rows if sh.lengths is not None
+                                  else None), shards=sh.n_shards):
+            row_valid = sharded_predicate_mask(sh, self.condition)
+        return ShardedBatch(sh.batch, row_valid, sh.mesh,
                             sh.rows_per_shard, sh.num_buckets,
                             lengths=None, split_plan=sh.split_plan)
 

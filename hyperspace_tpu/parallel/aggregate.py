@@ -17,7 +17,6 @@ so no catastrophic cancellation).
 
 from __future__ import annotations
 
-from functools import partial
 from typing import List, Sequence, Tuple
 
 import numpy as np
@@ -113,21 +112,38 @@ def _shard_partials(tree, num_lanes: int, specs_meta: Tuple[Tuple[str, bool],
 
 
 def make_partial_step(mesh, num_lanes: int, specs_meta, capacity: int):
-    import jax
+    """The partial-aggregation program (`jit_aggregate_step`, its ops
+    under the device scope `hs.mesh.aggregate`), kept with the other
+    SPMD programs per (mesh, lanes, specs, capacity): a warm repeat
+    dispatches the compiled program instead of retracing a fresh jit."""
+    from hyperspace_tpu.parallel.spmd import _cached_program
 
-    from hyperspace_tpu.parallel.mesh import compat_shard_map, row_spec
-    rows_spec = row_spec(mesh)
+    def build():
+        import jax
 
-    def step(tree):
-        body = partial(_shard_partials, num_lanes=num_lanes,
-                       specs_meta=specs_meta, capacity=capacity)
-        return compat_shard_map(
-            body, mesh=mesh,
-            in_specs=(jax.tree_util.tree_map(lambda _: rows_spec, tree),),
-            out_specs=rows_spec, check_vma=False)(tree)
+        from hyperspace_tpu.parallel.mesh import (compat_shard_map,
+                                                  row_spec)
+        from hyperspace_tpu.telemetry import (device_scoped,
+                                              instrumented_jit)
+        rows_spec = row_spec(mesh)
 
-    from hyperspace_tpu.telemetry import instrumented_jit
-    return instrumented_jit("mesh.aggregate_step", step)
+        @device_scoped("hs.mesh.aggregate")
+        def shard_partials(tree):
+            return _shard_partials(tree, num_lanes=num_lanes,
+                                   specs_meta=specs_meta,
+                                   capacity=capacity)
+
+        def aggregate_step(tree):
+            return compat_shard_map(
+                shard_partials, mesh=mesh,
+                in_specs=(jax.tree_util.tree_map(lambda _: rows_spec,
+                                                 tree),),
+                out_specs=rows_spec, check_vma=False)(tree)
+
+        return instrumented_jit("mesh.aggregate_step", aggregate_step)
+
+    return _cached_program(
+        ("aggregate", mesh, num_lanes, specs_meta, capacity), build)
 
 
 def distributed_group_aggregate(batch: ColumnBatch,
@@ -222,9 +238,16 @@ def _distributed_group_aggregate(batch, group_columns, aggregates,
 def _combine_partials(batch, out, group_columns, aggregates, specs_meta,
                       out_schema, num_lanes, n_shards, capacity,
                       sharded, row_valid):
+    from hyperspace_tpu import telemetry
     from hyperspace_tpu.ops.keys import host_dense_group_ids
 
-    rows = np.asarray(out["rows"]).reshape(-1)
+    # The [n_shards, G] partial tables cross here, once: accounted as
+    # the link crossing they are (the host combine reads numpy below).
+    tables = {k: v for k, v in out.items() if k != "overflow"}
+    with telemetry.link_transfer(
+            "d2h", sum(int(v.nbytes) for v in tables.values())):
+        out = {k: np.asarray(v) for k, v in tables.items()}
+    rows = out["rows"].reshape(-1)
     used = rows > 0  # empty slots carry no group
     keys = [np.asarray(out[f"key{i}"]).reshape(-1)[used]
             for i in range(num_lanes)]

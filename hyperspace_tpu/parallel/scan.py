@@ -61,7 +61,9 @@ def shard_batch(batch: ColumnBatch, mesh):
                 place(col.carry, 0),
                 (place(col.validity, False)
                  if col.validity is not None else None))
-        row_valid = place(np.ones(n, dtype=bool), False)
+        # made on the device: the mask is no payload to send over the link
+        row_valid = engine.put(jnp.arange(padded, dtype=jnp.int32) < n,
+                               device=sharding)
     return ColumnBatch(batch.schema, columns), row_valid
 
 

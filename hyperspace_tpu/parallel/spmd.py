@@ -520,10 +520,28 @@ def read_sharded(per_shard_files: List[List[str]], lengths,
     `split_plan` is stamped onto the result so the join knows the
     layout is row-balanced, not bucket-aligned."""
     from hyperspace_tpu import telemetry
-    from hyperspace_tpu.io import segcache
 
     lengths = np.asarray(lengths, dtype=np.int64)
     n_shards = total_shards(mesh)
+    fills = telemetry.get_registry().counter("cache.segments.fills")
+    fills_before = fills.value
+    with telemetry.span("hs.mesh.read", "mesh", rows=int(lengths.sum()),
+                        shards=n_shards) as sp:
+        out = _read_sharded(per_shard_files, lengths, columns, schema,
+                            mesh, n_shards, base_ref, conf, budget,
+                            shard_specs, split_plan)
+        # no fill ran: every device's range came out of its segment cache
+        sp.set(cached=int(base_ref is not None
+                          and fills.value == fills_before))
+    return out
+
+
+def _read_sharded(per_shard_files, lengths, columns, schema, mesh,
+                  n_shards: int, base_ref, conf, budget, shard_specs,
+                  split_plan) -> ShardedBatch:
+    from hyperspace_tpu import telemetry
+    from hyperspace_tpu.io import segcache
+
     if shard_specs is None:
         segs = shard_row_segments(lengths, n_shards)
         ranges = bucket_ranges(len(lengths), n_shards)
@@ -1316,13 +1334,16 @@ def _join_program(mesh, n_keys: int, Cl: int, Cr: int, cap: int,
     import jax
     import jax.numpy as jnp
 
-    from hyperspace_tpu.telemetry import instrumented_jit
+    from hyperspace_tpu.telemetry import device_scoped, instrumented_jit
 
     S = total_shards(mesh)
 
     def build():
-        def step(l_datas, l_ok, l_valid, r_datas, r_ok, r_valid,
-                 l_remaps, r_remaps, r_hash_tables):
+        # Named for the trace (the device's program is `jit_spmd_join`);
+        # every op of it under the device scope `hs.mesh.join`.
+        @device_scoped("hs.mesh.join")
+        def spmd_join(l_datas, l_ok, l_valid, r_datas, r_ok, r_valid,
+                      l_remaps, r_remaps, r_hash_tables):
             l_d = list(l_datas)
             r_d = list(r_datas)
             r_hash_sub = {}
@@ -1387,7 +1408,7 @@ def _join_program(mesh, n_keys: int, Cl: int, Cr: int, cap: int,
             return (li, ri, counts, un_gid, un_counts, expand_ovf,
                     route_ovf)
 
-        return instrumented_jit("mesh.spmd_join", step)
+        return instrumented_jit("mesh.spmd_join", spmd_join)
 
     key = ("join", mesh, n_keys, Cl, Cr, cap, left_outer, need_right,
            repartition_to, route_capacity, membership, remap_idx)
@@ -1568,8 +1589,10 @@ def sharded_join_indices(left: ShardedBatch, right: ShardedBatch,
     reg = telemetry.get_registry()
     tracer = telemetry.tracer()
     span_ts = tracer.now_us() if tracer is not None else 0.0
+    attempt = 0
     with _dispatch_guard(mesh):
         while True:
+            attempt += 1
             program = _join_program(mesh, len(left_keys),
                                     left.rows_per_shard,
                                     right.rows_per_shard, cap, left_outer,
@@ -1590,8 +1613,10 @@ def sharded_join_indices(left: ShardedBatch, right: ShardedBatch,
                 # count vectors + overflow scalars together, after
                 # everything (match AND expansion AND compaction) has
                 # dispatched — not a sizing sync in the middle.
-                counts, un_counts, e_ovf, r_ovf = jax.device_get(
-                    (counts_d, un_counts_d, expand_ovf, route_ovf))
+                with telemetry.span("hs.mesh.join.sync", "mesh", cap=cap,
+                                    attempt=attempt):
+                    counts, un_counts, e_ovf, r_ovf = jax.device_get(
+                        (counts_d, un_counts_d, expand_ovf, route_ovf))
                 sync_s = _time.perf_counter() - t0
             reg.counter("mesh.join.sync_s").inc(sync_s)
             telemetry.add_seconds("mesh.sync_s", sync_s)
@@ -1872,43 +1897,72 @@ def repartition_sharded(batch: ColumnBatch, key_columns: Sequence[str],
                         lengths=None)
 
 
+def sharded_predicate_mask(sh: ShardedBatch, expression,
+                           reuse: bool = True):
+    """`row_valid` narrowed by the predicate, as ONE jitted SPMD program
+    (`jit_spmd_filter`, its ops under the device scope
+    `hs.mesh.filter`): the compiled predicate traces together with the
+    validity mask; each device evaluates its shard and no row moves.
+    The program is kept per (mesh, predicate, schema, dictionaries) —
+    everything its trace reads besides the arrays — so a warm repeat
+    dispatches it without retracing; `reuse=False` traces afresh (the
+    trace-time dictionary lookups run, and are counted, every call)."""
+    import json
+
+    from hyperspace_tpu import telemetry
+    from hyperspace_tpu.engine.compiler import compile_predicate
+    from hyperspace_tpu.io.columnar import batch_to_tree, tree_to_batch
+    from hyperspace_tpu.telemetry import device_scoped, instrumented_jit
+
+    count_string_predicate_lookups(expression, sh.batch)
+    tree, aux = batch_to_tree(sh.batch, computes_on=())
+    schema = sh.batch.schema
+
+    def build():
+        @device_scoped("hs.mesh.filter")
+        def spmd_filter(t, valid):
+            b = tree_to_batch(t, schema, aux)
+            return compile_predicate(expression, b) & valid
+
+        return instrumented_jit("mesh.spmd_filter", spmd_filter)
+
+    try:
+        if not reuse:
+            return build()(tree, sh.row_valid)
+        key = ("filter", sh.mesh,
+               json.dumps(expression.to_dict(), sort_keys=True,
+                          default=str),
+               schema.to_json(),
+               tuple((name, _dict_fingerprint(d))
+                     for name, d in aux.items() if d is not None))
+        return _cached_program(key, build)(tree, sh.row_valid)
+    except HyperspaceException:
+        raise
+    except Exception:
+        # A predicate shape the tracer cannot close over (host-only
+        # op in a UDF, say) degrades to the eager SPMD evaluation —
+        # same math, more dispatches.
+        telemetry.get_registry().counter(
+            "mesh.spmd.filter_eager_fallbacks").inc()
+        return compile_predicate(expression, sh.batch) & sh.row_valid
+
+
 def sharded_filter(sh: ShardedBatch, expression) -> ColumnBatch:
-    """Predicate scan over the born-sharded layout as ONE jitted SPMD
-    program: the compiled predicate traces together with the validity
-    mask; each device evaluates its shard. Only the final compaction
-    gather crosses shards. Result equals the single-chip `apply_filter`
-    bit for bit."""
+    """Predicate scan over the born-sharded layout (the SPMD mask
+    program above). Only the final compaction gather crosses shards.
+    Result equals the single-chip `apply_filter` bit for bit."""
     import time as _time
 
     import jax.numpy as jnp
 
     from hyperspace_tpu import telemetry
-    from hyperspace_tpu.engine.compiler import compile_predicate
-    from hyperspace_tpu.io.columnar import batch_to_tree, tree_to_batch
-    from hyperspace_tpu.telemetry import instrumented_jit
 
     reg = telemetry.get_registry()
-    count_string_predicate_lookups(expression, sh.batch)
-    tree, aux = batch_to_tree(sh.batch, computes_on=())
-    schema = sh.batch.schema
-
-    def step(t, valid):
-        b = tree_to_batch(t, schema, aux)
-        return compile_predicate(expression, b) & valid
-
     with telemetry.span("hs.mesh.filter", "mesh", rows=sh.num_rows,
                         shards=sh.n_shards), _dispatch_guard(sh.mesh):
-        try:
-            mask = instrumented_jit("mesh.spmd_filter", step)(
-                tree, sh.row_valid)
-        except HyperspaceException:
-            raise
-        except Exception:
-            # A predicate shape the tracer cannot close over (host-only
-            # op in a UDF, say) degrades to the eager SPMD evaluation —
-            # same math, more dispatches.
-            reg.counter("mesh.spmd.filter_eager_fallbacks").inc()
-            mask = compile_predicate(expression, sh.batch) & sh.row_valid
+        # traced per call, as ever: a warm LIKE still asks the segment
+        # cache for its mask (`spmd.strings.like_mask_cache_hits`)
+        mask = sharded_predicate_mask(sh, expression, reuse=False)
         t0 = _time.perf_counter()
         count = int(jnp.sum(mask))  # the one sizing readback
         sync_s = _time.perf_counter() - t0

@@ -99,10 +99,16 @@ SPAN_NAMES = {
     "hs.action.<Class>.<phase>": "validate / begin / op / end",
     # mesh (multi-chip)
     "hs.mesh.place": "placing a batch over the mesh (rows, shards)",
+    "hs.mesh.read": "a born-sharded read: each device's bucket range "
+                    "out of its segment cache, or filled (rows, shards, "
+                    "cached)",
     "hs.mesh.filter": "the distributed / SPMD filter",
     "hs.mesh.aggregate": "the distributed aggregate",
     "hs.mesh.build.dispatch": "the mesh build step's dispatch",
-    "hs.mesh.join.spmd": "the SPMD join program",
+    "hs.mesh.join.spmd": "the SPMD join program: one attempt's dispatch "
+                         "and readback (how, shards, cap)",
+    "hs.mesh.join.sync": "the host blocked on that attempt's one "
+                         "readback (cap, attempt)",
     # ring only (recognised in hindsight, `completed`)
     "hs.compile.<name>": "a dispatch of jit entry point <name> that "
                          "traced + compiled",
@@ -121,6 +127,12 @@ DEVICE_SCOPES = {
     "hs.compact": "mask -> survivor indices (rank select or sort select)",
     "hs.join.match": "the counting join's match program",
     "hs.join.expand": "the counting join's expansion to row pairs",
+    # mesh: the three SPMD programs, on every chip's plane
+    "hs.mesh.filter": "the SPMD predicate mask (`jit_spmd_filter`)",
+    "hs.mesh.join": "the SPMD join: match + static-capacity expansion "
+                    "(`jit_spmd_join`)",
+    "hs.mesh.aggregate": "the per-shard partial aggregation "
+                         "(`jit_aggregate_step`)",
 }
 
 _tracer: Optional["Tracer"] = None

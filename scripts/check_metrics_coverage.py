@@ -816,6 +816,7 @@ def check_profiler_seam(package_dir: str):
 _SPAN_CALL_RE = re.compile(
     r"""\bspan\(\s*(f?)["']([^"']+)["'](\s*\+)?""")
 _RING_COMPLETE_RE = re.compile(r"\.complete\(")
+_DEVICE_SCOPE_RE = re.compile(r"""\bdevice_scoped\(\s*["']([^"']+)["']""")
 _SPAN_SEAM = os.path.join("telemetry", "trace.py")
 
 
@@ -825,7 +826,8 @@ def _span_pattern(name: str) -> str:
 
 def check_span_seam(package_dir: str, repo_root: str):
     """Source + doc-drift lint for the span seam: every literal name a
-    `span(...)` call opens is in `telemetry.SPAN_NAMES`; no
+    `span(...)` call opens is in `telemetry.SPAN_NAMES` and every
+    literal `device_scoped(...)` scope in `DEVICE_SCOPES`; no
     `Tracer.complete` outside `telemetry/trace.py`; every name of
     `SPAN_NAMES` and `DEVICE_SCOPES` has its row in docs/telemetry.md."""
     from hyperspace_tpu import telemetry
@@ -850,6 +852,13 @@ def check_span_seam(package_dir: str, repo_root: str):
                         f"{name!r} is not in telemetry.SPAN_NAMES — add "
                         "it to the table (and docs/telemetry.md) or use "
                         "a name that is there")
+            for m in _DEVICE_SCOPE_RE.finditer(text):
+                if m.group(1) not in telemetry.DEVICE_SCOPES:
+                    lineno = text.count("\n", 0, m.start()) + 1
+                    failures.append(
+                        f"hyperspace_tpu/{rel}:{lineno}: device scope "
+                        f"{m.group(1)!r} is not in telemetry.DEVICE_SCOPES "
+                        "— add it to the table (and docs/telemetry.md)")
             if rel == _SPAN_SEAM:
                 continue
             for lineno, line in enumerate(text.splitlines(), 1):

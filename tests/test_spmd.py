@@ -207,9 +207,12 @@ def test_pad_blowup_guard():
 
 def test_warm_two_stage_smj_zero_d2h_between_stages():
     """Device-resident stage flow: join -> in-program repartition ->
-    second join -> SPMD aggregate, with ZERO D2H link crossings across
-    the whole pipeline (the engine-counted `link.d2h.*` series stays
-    flat until result materialization)."""
+    second join -> SPMD aggregate, with ZERO D2H link crossings between
+    the stages (the engine-counted `link.d2h.*` series stays flat until
+    result materialization). The aggregate's host combine IS the
+    result's materialization: its [n_shards, G] partial tables cross
+    once, counted as the one crossing they are (PR 27; uncounted
+    before, so a mesh query's `link.d2h.bytes` read 0)."""
     from hyperspace_tpu.ops.bucketed_join import assemble_join_output
     from hyperspace_tpu.plan.nodes import Aggregate, AggSpec, Scan
     from hyperspace_tpu.plan.schema import Schema
@@ -217,7 +220,7 @@ def test_warm_two_stage_smj_zero_d2h_between_stages():
     mesh, lsh, rsh, lb, rb, ll, rl = sharded_pair(n=1500, m=700,
                                                   seed=21)
 
-    def pipeline():
+    def pipeline(aggregate=True):
         li, ri = spmd.sharded_join_indices(lsh, rsh, ["k"], ["k"])
         joined = assemble_join_output(lsh.batch, rsh.batch, li, ri,
                                       how="inner")
@@ -227,6 +230,8 @@ def test_warm_two_stage_smj_zero_d2h_between_stages():
                                   how="inner",
                                   columns=["k", "v", "v_r"])
         stage3 = spmd.repartition_sharded(j2, ["k"], 16, mesh)
+        if not aggregate:
+            return stage3
         schema = Schema.from_arrow(pa.table(
             {"k": np.zeros(1, np.int64), "v": np.zeros(1),
              "v_r": np.zeros(1)}).schema)
@@ -240,12 +245,16 @@ def test_warm_two_stage_smj_zero_d2h_between_stages():
     cold = columnar.to_arrow(pipeline()).to_pandas()
     reg = telemetry.get_registry()
     before = dict(reg.counters_dict())
-    warm_out = pipeline()  # stop BEFORE materialization
+    pipeline(aggregate=False)  # every stage, BEFORE materialization
     after = dict(reg.counters_dict())
     assert after.get("link.d2h.chunks", 0) == \
         before.get("link.d2h.chunks", 0), "a stage crossed D2H"
     assert after.get("link.d2h.bytes", 0) == \
         before.get("link.d2h.bytes", 0)
+    warm_out = pipeline()
+    final = dict(reg.counters_dict())
+    assert final.get("link.d2h.transfers", 0) == \
+        after.get("link.d2h.transfers", 0) + 1, "the partial tables, once"
     warm = columnar.to_arrow(warm_out).to_pandas()
     pd.testing.assert_frame_equal(
         cold.sort_values("k").reset_index(drop=True),

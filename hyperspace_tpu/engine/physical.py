@@ -200,41 +200,32 @@ class ScanExec(PhysicalNode):
         return (self.conf.segment_cache_bytes if device
                 else self.conf.read_cache_bytes)
 
-    def _read_device(self, files: List[str], bucket=None,
-                     bucketed: bool = False) -> columnar.ColumnBatch:
+    def _read_device(self, files: List[str],
+                     ref) -> columnar.ColumnBatch:
         """Device-lane read THROUGH the HBM segment cache: a warm hit
         is link-free (no parquet decode, no H2D). Rule-selected index
-        scans key by (index root, committed version, bucket selector);
-        unversioned scans fall back to stamp validation inside the
-        cache."""
-        ref = segcache.segment_ref_for_scan(
-            self.scan, bucket=bucket,
-            allowed_buckets=self.allowed_buckets, bucketed=bucketed)
+        scans key by `ref` (index root, committed version, bucket
+        selector); unversioned scans (`ref` None) fall back to stamp
+        validation inside the cache."""
         return segcache.read_segment(files, self.columns,
                                      self.out_schema, ref=ref,
                                      conf=self.conf,
                                      budget=self._budget(device=True),
                                      shared_members=self.shared_members)
 
-    def _annotate_read(self, files: List[str], host: bool,
-                       files_total: Optional[int] = None) -> None:
+    def _annotate_read(self, facts, host: bool) -> None:
         """Index-usage detail on this scan's operator record: lane, files
-        scanned vs total, buckets scanned vs total. `files_total` is
-        passed by the caller FROM THE LISTING IT ALREADY MADE — this
-        hook performs no IO of its own (telemetry must not add a listing
-        to the scan hot path)."""
+        scanned vs total, buckets scanned vs total — all from the facts
+        `_resolve` already holds; this hook performs no IO of its own
+        (telemetry must not add a listing to the scan hot path)."""
         if telemetry.current() is None:
             return
-        from hyperspace_tpu.plan import footprint as _footprint
         detail = {"lane": "host" if host else "device",
-                  "files_scanned": len(files),
-                  # Raw on-disk bytes behind this read, via the stamp-
-                  # validated size cache admission control already
-                  # populated this collect (warm: no extra listing, one
-                  # cached stat per file). Feeds the regression differ
-                  # and the index advisor's per-relation scan-bytes
-                  # signal.
-                  "bytes_scanned": _footprint.file_sizes_total(files),
+                  "files_scanned": len(facts.files),
+                  # Raw on-disk bytes behind this read. Feeds the
+                  # regression differ and the index advisor's
+                  # per-relation scan-bytes signal.
+                  "bytes_scanned": facts.bytes_scanned,
                   "roots": list(self.scan.root_paths)}
         if self.shared_members:
             detail["shared_members"] = self.shared_members
@@ -251,8 +242,8 @@ class ScanExec(PhysicalNode):
                 # pruning narrowed the read (full-range scans carry no
                 # hotness signal) and small enough to ride the ring.
                 detail["bucket_ids"] = sorted(self.allowed_buckets)
-        if files_total is not None:
-            detail["files_total"] = files_total
+        if facts.files_total is not None:
+            detail["files_total"] = facts.files_total
         telemetry.annotate(**detail)
 
     def simple_string(self) -> str:
@@ -327,12 +318,67 @@ class ScanExec(PhysicalNode):
                 out.setdefault(b, []).extend(fs)
         return out
 
-    def _execute(self, bucket: Optional[int] = None) -> columnar.ColumnBatch:
-        files_total: Optional[int] = None
-        if bucket is not None:
-            if self.scan.bucket_spec is None:
-                raise HyperspaceException("Bucket read on unbucketed scan.")
-            files: List[str] = self._per_bucket_files().get(bucket, [])
+    def _resolve(self, bucket: Optional[int] = None,
+                 num_buckets: Optional[int] = None):
+        """(facts, ref) of this read — the ONE place a scan learns about
+        its files: their names in read order, `files_total`, the rows
+        per bucket and in all (the lane choice), the bytes on disk
+        (`segcache.ScanFacts`). `num_buckets` asks for the bucket-ordered
+        layout of `execute_bucketed` / `execute_sharded`, `bucket` for
+        one bucket's files. Where the scan names a committed index
+        version (`segcache.segment_ref_for_scan` gives a ref) the facts
+        are that version's and come from the memo beside its segments:
+        a warm scan stats, opens and lists nothing and gives the
+        `hs-io` pool no task. Any other scan (source data, several
+        roots, hybrid scan's appended files) resolves from the files
+        every time: that pass is how a rewritten file is noticed."""
+        ref = segcache.segment_ref_for_scan(
+            self.scan, bucket=bucket,
+            allowed_buckets=self.allowed_buckets,
+            bucketed=num_buckets is not None)
+        from_files = functools.partial(self._resolve_from_files, bucket,
+                                       num_buckets)
+        with telemetry.span("hs.scan.resolve", "cache") as sp:
+            if ref is None:
+                # bytes only where an operator record will report them
+                facts = from_files(want_bytes=telemetry.current() is not None)
+                cached = False
+            else:
+                facts, cached = segcache.get_cache().scan_facts(
+                    ref, num_buckets,
+                    functools.partial(from_files, want_bytes=True))
+            sp.set(files=len(facts.files), cached=int(cached))
+        telemetry.get_registry().counter(
+            "scan.resolve.hits" if cached else "scan.resolve.misses").inc()
+        return facts, ref
+
+    def _resolve_from_files(self, bucket, num_buckets,
+                            want_bytes: bool) -> "segcache.ScanFacts":
+        """`_resolve`'s answer from the listing, the Parquet footers
+        (`parquet.file_row_counts`: a stamp and a cache probe per file,
+        on the `hs-io` pool) and the size cache."""
+        import numpy as np
+
+        buckets = files_total = lengths = None
+        if num_buckets is not None:
+            per_bucket: dict = {}
+            files_total = 0
+            for b, fs in self._per_bucket_files().items():
+                files_total += len(fs)
+                if (self.allowed_buckets is not None
+                        and b not in self.allowed_buckets):
+                    # Pruned by the filter above: no row in this bucket
+                    # can survive it, so an empty bucket is equivalent.
+                    continue
+                per_bucket.setdefault(b, []).extend(fs)
+            # ONE bucket-ordered file list; per-bucket lengths come from
+            # parquet footers (no data read).
+            ordered = [(b, f) for b in range(num_buckets)
+                       for f in per_bucket.get(b, [])]
+            buckets = tuple(b for b, _ in ordered)
+            files = [f for _, f in ordered]
+        elif bucket is not None:
+            files = self._per_bucket_files().get(bucket, [])
         elif self.allowed_buckets is not None and self.scan.bucket_spec:
             files = []
             per_bucket = self._per_bucket_files()
@@ -342,6 +388,33 @@ class ScanExec(PhysicalNode):
         else:
             files = self.scan.files()
             files_total = len(files)
+        # Footer row counts only gate the lane choice, which per-bucket
+        # reads don't make — keep the metadata pass off that hot path.
+        counts = (None if bucket is not None
+                  else tuple(parquet.file_row_counts(files)))
+        if num_buckets is not None:
+            lengths = np.zeros(num_buckets, dtype=np.int64)
+            for b, c in zip(buckets, counts):
+                lengths[b] += c
+            lengths.setflags(write=False)
+        bytes_scanned = None
+        if want_bytes:
+            from hyperspace_tpu.plan import footprint as _footprint
+            bytes_scanned = _footprint.file_sizes_total(files)
+        return segcache.ScanFacts(
+            files=tuple(files), buckets=buckets, files_total=files_total,
+            counts=counts, lengths=lengths, bytes_scanned=bytes_scanned)
+
+    def _min_device_rows(self) -> int:
+        from hyperspace_tpu.constants import MIN_DEVICE_ROWS_DEFAULT
+        return (self.conf.min_device_rows if self.conf is not None
+                else MIN_DEVICE_ROWS_DEFAULT)
+
+    def _execute(self, bucket: Optional[int] = None) -> columnar.ColumnBatch:
+        if bucket is not None and self.scan.bucket_spec is None:
+            raise HyperspaceException("Bucket read on unbucketed scan.")
+        facts, ref = self._resolve(bucket=bucket)
+        files = list(facts.files)
         if not files:
             return _empty_batch(self.out_schema)
         # Adaptive lane: small reads (e.g. a pruned point-filter bucket)
@@ -349,20 +422,14 @@ class ScanExec(PhysicalNode):
         # an attached chip) is assumed to dwarf the work. Downstream jnp operators promote host
         # batches to the device transparently when they need it. Host
         # batches come through the stamped decoded-batch cache.
-        from hyperspace_tpu.constants import MIN_DEVICE_ROWS_DEFAULT
-        min_dev = (self.conf.min_device_rows if self.conf is not None
-                   else MIN_DEVICE_ROWS_DEFAULT)
-        # Footer row counts only gate the lane choice, which per-bucket
-        # reads don't make — keep the metadata pass off that hot path.
-        host = (bucket is None
-                and sum(parquet.file_row_counts(files)) < min_dev)
-        self._annotate_read(files, host, files_total)
+        host = bucket is None and facts.rows < self._min_device_rows()
+        self._annotate_read(facts, host)
         if host:
             batch = parquet.read_host_batch(files, self.columns,
                                             self.out_schema,
                                             budget=self._budget(device=False))
         else:
-            batch = self._read_device(files, bucket=bucket)
+            batch = self._read_device(files, ref)
         if bucket is not None and len(files) > 1:
             # Multiple sorted runs in one bucket (incremental deltas): the
             # concat is not globally sorted — restore order on device.
@@ -396,8 +463,6 @@ class ScanExec(PhysicalNode):
         aligned. `align_plan` IS that other side's read: each shard
         holds every row of the buckets intersecting the plan's segment
         (split buckets replicated per covering shard)."""
-        import numpy as np
-
         from hyperspace_tpu.parallel import spmd
         from hyperspace_tpu.parallel.mesh import (bucket_ranges,
                                                   total_shards)
@@ -406,41 +471,26 @@ class ScanExec(PhysicalNode):
             return None
         if not spmd.supports_sharded(self.out_schema):
             return None  # a dtype outside the host-lane map (defensive)
-        per_bucket: dict = {}
-        files_total = 0
-        for b, files in self._per_bucket_files().items():
-            files_total += len(files)
-            if (self.allowed_buckets is not None
-                    and b not in self.allowed_buckets):
-                continue
-            per_bucket.setdefault(b, []).extend(files)
-        ordered = [(b, f) for b in range(num_buckets)
-                   for f in per_bucket.get(b, [])]
-        lengths = np.zeros(num_buckets, dtype=np.int64)
-        counts = parquet.file_row_counts([f for _, f in ordered])
-        for (b, _), c in zip(ordered, counts):
-            lengths[b] += c
-        total = int(lengths.sum())
+        facts, ref = self._resolve(num_buckets=num_buckets)
+        total = facts.rows
         if total == 0:
             return None
         mode = self.conf.distribution if self.conf is not None else "auto"
         if mode == "auto":
-            from hyperspace_tpu.constants import (
-                DISTRIBUTION_MIN_ROWS_DEFAULT, MIN_DEVICE_ROWS_DEFAULT)
-            min_dev = (self.conf.min_device_rows if self.conf is not None
-                       else MIN_DEVICE_ROWS_DEFAULT)
+            from hyperspace_tpu.constants import \
+                DISTRIBUTION_MIN_ROWS_DEFAULT
             min_dist = (self.conf.distribution_min_rows
                         if self.conf is not None
                         else DISTRIBUTION_MIN_ROWS_DEFAULT)
-            if total < max(min_dev, min_dist):
+            if total < max(self._min_device_rows(), min_dist):
                 return None  # host / single-chip lane territory
         n_shards = total_shards(mesh)
-        ref = segcache.segment_ref_for_scan(
-            self.scan, allowed_buckets=self.allowed_buckets,
-            bucketed=True)
+        lengths = facts.lengths.copy()
+        per_bucket: dict = {}
+        for b, f in zip(facts.buckets, facts.files):
+            per_bucket.setdefault(b, []).append(f)
         budget = self._budget(device=True)
-        self._annotate_read([f for _, f in ordered], host=False,
-                            files_total=files_total)
+        self._annotate_read(facts, host=False)
         if align_plan is not None:
             # The other side of a sub-shard join: intersected buckets
             # replicated per covering shard. Decline when replication
@@ -467,7 +517,7 @@ class ScanExec(PhysicalNode):
             # into row-balanced virtual sub-shards and stay on the
             # SPMD lane (the join reads its other side aligned).
             split_plan, shard_specs = spmd.plan_skew_read(
-                per_bucket, lengths, n_shards)
+                per_bucket, lengths, n_shards, counts=facts.counts)
             telemetry.get_registry().counter(
                 "mesh.spmd.subshard_reads").inc()
             telemetry.annotate(subsharded=True)
@@ -487,41 +537,20 @@ class ScanExec(PhysicalNode):
         """Read all bucket files in bucket order; lengths come from parquet
         metadata — no device work. (The batched join sorts per-bucket ids
         itself, so multi-run buckets need no pre-sort here.)"""
-        import numpy as np
-
         if self.scan.bucket_spec is None:
             raise HyperspaceException("Bucketed read on unbucketed scan.")
-        per_bucket = {}
-        files_total = 0
-        for b, files in self._per_bucket_files().items():
-            files_total += len(files)
-            if (self.allowed_buckets is not None
-                    and b not in self.allowed_buckets):
-                # Pruned by the filter above: no row in this bucket can
-                # survive it, so an empty bucket is equivalent.
-                continue
-            per_bucket.setdefault(b, []).extend(files)
-        # ONE ordered concurrent read of all bucket files; per-bucket
-        # lengths come from parquet footers (no data read).
-        ordered = [(b, f) for b in range(num_buckets)
-                   for f in per_bucket.get(b, [])]
-        lengths = np.zeros(num_buckets, dtype=np.int64)
-        if not ordered:
+        facts, ref = self._resolve(num_buckets=num_buckets)
+        lengths = facts.lengths.copy()
+        files = list(facts.files)
+        if not files:
             return _empty_batch(self.out_schema), lengths
-        counts = parquet.file_row_counts([f for _, f in ordered])
-        for (b, _), c in zip(ordered, counts):
-            lengths[b] += c
-        files = [f for _, f in ordered]
-        from hyperspace_tpu.constants import MIN_DEVICE_ROWS_DEFAULT
-        min_dev = (self.conf.min_device_rows if self.conf is not None
-                   else MIN_DEVICE_ROWS_DEFAULT)
-        host = int(lengths.sum()) < min_dev
-        self._annotate_read(files, host, files_total)
+        host = facts.rows < self._min_device_rows()
+        self._annotate_read(facts, host)
         if host:
             return parquet.read_host_batch(
                 files, self.columns, self.out_schema,
                 budget=self._budget(device=False)), lengths
-        return self._read_device(files, bucketed=True), lengths
+        return self._read_device(files, ref), lengths
 
 
 class FilterExec(PhysicalNode):

@@ -67,6 +67,17 @@ the old `_device_cache`/`read_device_batch` access anywhere else):
   buckets. Selectors whose bucket coverage is unknowable ("all",
   explicit file lists, SPMD range keys) drop conservatively.
 
+- **scan facts**: what a scan has to know about a version's files
+  before it can ask for the segment — their names in read order, the
+  per-bucket row counts, the row total its lane choice reads, the bytes
+  on disk — is as immutable as the segment, so it is resolved once per
+  (index root, committed version, bucket selector) and kept HERE
+  (`ScanFacts`, `SegmentCache.scan_facts`), dropped by every hook that
+  drops the segment. A warm scan of a committed version then touches no
+  file: no `stat`, no footer, no listing, no pool task. Reads with no
+  `SegmentRef` are never memoised — their metadata pass is how a
+  rewritten source file is noticed.
+
 Telemetry: `cache.segments.{hits,misses,fills,evictions,bytes_held,
 entries,pins}` and `cache.segments.host.{hits,demotions,evictions,
 bytes_held,entries}` plus `cache.segments.rekeyed` through the PR-3
@@ -91,8 +102,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from hyperspace_tpu import constants
 
-__all__ = ["SegmentCache", "SegmentRef", "get_cache", "set_cache",
-           "reset_cache", "clear", "segment_ref_for_scan",
+__all__ = ["SegmentCache", "SegmentRef", "ScanFacts", "get_cache",
+           "set_cache", "reset_cache", "clear", "segment_ref_for_scan",
            "on_version_committed", "on_version_deleted",
            "on_index_dropped", "invalidate_source_paths", "read_segment",
            "stats_snapshot"]
@@ -113,6 +124,10 @@ SEGMENT_CACHE_HOST_BYTES = int(os.environ.get(
 # cancelled waiter notices its deadline promptly, long enough not to
 # spin (same discipline as the scheduler's queue wait).
 _FILL_WAIT_QUANTUM_S = 0.05
+
+# Kept `ScanFacts` (one per version x bucket selector x layout): a plain
+# bound, LRU past it. An entry is a few tuples of the read's file names.
+_SCAN_FACTS_MAX = 1024
 
 _VERSION_DIR_RE = re.compile(
     re.escape(constants.INDEX_VERSION_DIRECTORY_PREFIX) + r"=(\d+)$")
@@ -173,6 +188,27 @@ def segment_ref_for_scan(scan, bucket=None, allowed_buckets=None,
                       index_root=os.path.dirname(root),
                       version=int(m.group(1)),
                       bucket=selector)
+
+
+@dataclass(frozen=True)
+class ScanFacts:
+    """What `engine/physical.ScanExec` resolves about one read's files
+    before it reads them. For a read with a `SegmentRef` these are facts
+    of a committed, immutable version and are kept with its segments
+    (`SegmentCache.scan_facts`); any other read resolves them anew."""
+
+    files: tuple                 # in read order
+    buckets: Optional[tuple]     # each file's bucket: bucket-ordered reads
+    files_total: Optional[int]   # the listing's size before bucket pruning
+    counts: Optional[tuple]      # rows of each file (footers); None for a
+                                 # per-bucket read, which makes no lane choice
+    lengths: object              # int64[num_buckets] rows per bucket
+                                 # (read-only; copy before handing on), or None
+    bytes_scanned: Optional[int]  # on-disk bytes; None = not asked for
+
+    @property
+    def rows(self) -> Optional[int]:
+        return None if self.counts is None else sum(self.counts)
 
 
 class _Entry:
@@ -285,6 +321,11 @@ class SegmentCache:
         self._default_host_budget = (
             SEGMENT_CACHE_HOST_BYTES if host_budget_bytes is None
             else int(host_budget_bytes))
+        # ScanFacts by (ref.key, num_buckets), beside the entries they
+        # describe and under the same lock and the same invalidation.
+        self._facts: "OrderedDict[tuple, Tuple[SegmentRef, ScanFacts]]" \
+            = OrderedDict()
+        self._facts_epoch = 0  # bumped by every drop: see scan_facts
 
     # -- budget math ------------------------------------------------------
 
@@ -444,6 +485,39 @@ class SegmentCache:
         with self._cv:
             return sum(e.nbytes for e in self._entries.values()
                        if e.ref is not None and e.ref.index_root in roots)
+
+    # -- scan facts -------------------------------------------------------
+
+    def scan_facts(self, ref: SegmentRef, num_buckets: Optional[int],
+                   resolve) -> Tuple[ScanFacts, bool]:
+        """(facts, cached) of the read `ref` names, in the bucket-ordered
+        layout over `num_buckets` or the plain one (None): from the memo,
+        or from `resolve()` (the listing, footers and sizes, run outside
+        the lock) and kept. Two cold readers may both resolve; the facts
+        are the version's, so they agree. A resolve that raced an
+        invalidation (of any index: one epoch serves them all) is
+        served to its caller, not kept."""
+        key = ref.key + (num_buckets,)
+        with self._cv:
+            hit = self._facts.get(key)
+            if hit is not None:
+                self._facts.move_to_end(key)
+                return hit[1], True
+            epoch = self._facts_epoch
+        facts = resolve()
+        with self._cv:
+            if self._facts_epoch == epoch:
+                self._facts[key] = (ref, facts)
+                while len(self._facts) > _SCAN_FACTS_MAX:
+                    self._facts.popitem(last=False)
+        return facts, False
+
+    def _drop_facts(self, predicate) -> None:
+        # Caller holds the cv lock.
+        self._facts_epoch += 1
+        for k in [k for k, (ref, _) in self._facts.items()
+                  if predicate(ref)]:
+            del self._facts[k]
 
     # -- the read path ----------------------------------------------------
 
@@ -760,6 +834,7 @@ class SegmentCache:
                             if e.ref is not None and predicate(e.ref)]
             for k in host_victims:
                 self._host_bytes -= self._host.pop(k).nbytes
+            self._drop_facts(predicate)
             for f in self._fills.values():
                 if f.index_root is not None and predicate(
                         SegmentRef("", f.index_root, -1, "all")):
@@ -824,6 +899,11 @@ class SegmentCache:
                     else:
                         self._host_bytes -= victim.nbytes
                         host_dropped += 1
+            # The facts of every older version go (the carried files live
+            # under new names): the next read of the new version
+            # resolves once.
+            self._drop_facts(lambda ref: ref.index_root == root
+                             and ref.version != new_version)
             for f in self._fills.values():
                 if f.index_root == root:
                     # Conservative: an in-flight fill may cover touched
@@ -892,6 +972,7 @@ class SegmentCache:
             nh = len(self._host)
             self._host.clear()
             self._host_bytes = 0
+            self._drop_facts(lambda ref: True)
             for f in self._fills.values():
                 f.doomed = True
             self._publish_stats()
@@ -913,6 +994,7 @@ class SegmentCache:
                                       if e.pinned),
                 "host_entries": len(self._host),
                 "host_bytes_held": self._host_bytes,
+                "scan_facts": len(self._facts),
             }
 
 

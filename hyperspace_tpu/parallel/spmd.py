@@ -253,28 +253,32 @@ def subshard_plan(lengths, n_shards: int) -> SubshardPlan:
                         tuple(spans))
 
 
-def _file_cuts(per_bucket: dict, num_buckets: int):
+def _file_cuts(per_bucket: dict, num_buckets: int, counts=None):
     """Ordered (bucket, file, rows) over the bucket-ordered file list
     plus the cumulative row offsets — the geometry both sub-shard read
-    planners slice against. Row counts come from parquet footers."""
+    planners slice against. Row counts are the caller's (`counts`, in
+    that order: the scan's resolved facts) or come from parquet
+    footers."""
     from hyperspace_tpu.io import parquet
 
     ordered = [(b, f) for b in range(num_buckets)
                for f in per_bucket.get(b, [])]
-    counts = parquet.file_row_counts([f for _, f in ordered])
+    if counts is None:
+        counts = parquet.file_row_counts([f for _, f in ordered])
     cum = np.concatenate([[0], np.cumsum(np.asarray(counts,
                                                     dtype=np.int64))])
     return ordered, counts, cum
 
 
-def plan_skew_read(per_bucket: dict, lengths, n_shards: int):
+def plan_skew_read(per_bucket: dict, lengths, n_shards: int,
+                   counts=None):
     """(plan, shard_specs) for the SKEWED side: each shard s reads rows
     [lo, hi) of the bucket-ordered file list — the covering files plus
     a (skip, take) window so a file holding a cut boundary decodes once
     per touching shard but ships only its slice."""
     lengths = np.asarray(lengths, dtype=np.int64)
     plan = subshard_plan(lengths, n_shards)
-    ordered, counts, cum = _file_cuts(per_bucket, len(lengths))
+    ordered, counts, cum = _file_cuts(per_bucket, len(lengths), counts)
     specs = []
     for lo, hi in plan.segments:
         if hi <= lo:

@@ -82,6 +82,10 @@ SPAN_NAMES = {
     "hs.stage.compact": "the survivors' indices + take of them (rows)",
     "hs.stage.gather": "the deferred lazy gathers (columns)",
     # link / residency
+    "hs.scan.resolve": "a scan learning its files, per-bucket rows, row "
+                       "total and bytes: from the memo kept with a "
+                       "committed version's segments, or from the "
+                       "listing and footers (files, cached)",
     "hs.segcache.fill": "a segment-cache fill (index, files)",
     "hs.link.h2d": "a host-to-device placement (bytes, chunks)",
     "hs.link.d2h": "a device-to-host fetch (bytes)",

@@ -98,10 +98,39 @@ class SortedRows:
         return bad
 
 
-def same_columns(a: dict, b: dict) -> bool:
-    """Row for row the same, in the same order."""
-    return sorted(a) == sorted(b) and all(
-        len(a[n]) == len(b[n]) and np.array_equal(a[n], b[n]) for n in a)
+def _same_array(x, y) -> bool:
+    import pyarrow as pa
+
+    t = x.type
+    if t != y.type or len(x) != len(y) or x.offset != y.offset \
+            or x.null_count or y.null_count:
+        return False
+    if pa.types.is_dictionary(t):
+        if not _same_array(x.dictionary, y.dictionary):
+            return False
+    elif pa.types.is_boolean(t) or not (
+            pa.types.is_primitive(t) or pa.types.is_string(t)
+            or pa.types.is_binary(t)):
+        return False  # bit-packed or nested: not read here
+    # buffer 0 is the validity bitmap: no nulls on either side
+    return all(p is not None and q is not None and p.equals(q)
+               for p, q in zip(x.buffers()[1:], y.buffers()[1:]))
+
+
+def same_buffers(a, b) -> bool:
+    """Whether two Arrow tables are the same bytes: schema, chunking,
+    no nulls, and every data buffer of every chunk equal (a memcmp, so
+    nan equals itself and -0.0 is not 0.0, which `Table.equals` has the
+    other way round). False is no verdict: the same rows may sit in
+    other chunks, in another order or in a type not read here, and the
+    multiset comparison decides."""
+    if a.schema != b.schema or a.num_rows != b.num_rows:
+        return False
+    for ca, cb in zip(a.columns, b.columns):
+        if ca.num_chunks != cb.num_chunks or not all(
+                _same_array(x, y) for x, y in zip(ca.chunks, cb.chunks)):
+            return False
+    return True
 
 
 def mismatched_rows(got: dict, want) -> int:

@@ -81,15 +81,6 @@ class Deployment:
                  for _, r in self.hs.indexes().iterrows()}
         return found[name]
 
-    # -- queries ----------------------------------------------------------
-
-    def plan(self, df):
-        """What `collect` does before it executes, for the planner's
-        span."""
-        from hyperspace_tpu.engine.executor import compile_plan
-
-        return compile_plan(self.sess.optimize(df.plan), conf=self.sess.conf)
-
     def close(self) -> None:
         self.sess.close()
 

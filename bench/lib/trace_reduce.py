@@ -126,9 +126,9 @@ def busy_all_chips(reduced: dict):
 
 def idle_gaps(reduced: dict, top: int = 10):
     """The window's idle time by what the host was doing in it: each gap
-    between device ops goes to the innermost bench span that covers its
-    middle (`outside` where none does). [[name, seconds], ...], most
-    first."""
+    between device ops is cut where a bench span starts or ends inside
+    it, and each piece goes to the innermost span that covers it
+    (`outside` where none does). [[name, seconds], ...], most first."""
     lo, hi = reduced["window"]
     busy = busy_all_chips(reduced)
     edges = [lo] + [t for iv in busy for t in iv] + [hi]
@@ -138,10 +138,15 @@ def idle_gaps(reduced: dict, top: int = 10):
              if n != WINDOW_SPAN]
     by_name = {}
     for s, e in gaps:
-        mid = (s + e) / 2
-        covering = [(ee - ss, n) for n, ss, ee in named if ss <= mid < ee]
-        name = min(covering)[1][len(SPAN_PREFIX):] if covering else "outside"
-        by_name[name] = by_name.get(name, 0.0) + (e - s)
+        over = [(n, ss, ee) for n, ss, ee in named if ss < e and ee > s]
+        cuts = sorted({s, e} | {t for _, ss, ee in over for t in (ss, ee)
+                                if s < t < e})
+        for a, b in zip(cuts, cuts[1:]):
+            covering = [(ee - ss, n) for n, ss, ee in over
+                        if ss <= a and b <= ee]
+            name = min(covering)[1][len(SPAN_PREFIX):] if covering \
+                else "outside"
+            by_name[name] = by_name.get(name, 0.0) + (b - a)
     return [[n, s] for n, s in sorted(by_name.items(),
                                       key=lambda kv: -kv[1])[:top]]
 

@@ -9,13 +9,17 @@ could do it in 1/n of the time, and each ran 1/n of the sum.
 
 The survivors are the answer's own two counts summed (Q12 counts every
 line that passed the filter and found its order, and every line has
-one); the pairs are as many."""
+one); the pairs are as many. The first traced record as a rule holds no
+table of its own: its answer repeated the bytes of the first answer to
+the same parameters (`ops/select.settle`), whose record `same_as`
+points at, and that one's table is read."""
 
 from lib import layers, roofline
 
 
 def survivors(run):
-    answer = run["records"][0].get("answer")
+    rec = run["records"][0]
+    answer = rec.get("same_as", rec).get("answer")
     if answer is None:
         return None
     return sum(sum(answer.column(c).to_pylist())

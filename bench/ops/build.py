@@ -48,7 +48,9 @@ class Op:
         self._last_build = name
         built = time.perf_counter()
         rec = self.then.run(i, traced, warming)
-        rec.update(start=t0, built=built, end=time.perf_counter(), name=name,
+        # `end` stays the query's own: what follows it there is the
+        # harness settling the answer
+        rec.update(start=t0, built=built, name=name,
                    rows_indexed=self.dep.rows[
                        self.dep.config["indexes"][index]["table"]])
         return rec

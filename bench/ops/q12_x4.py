@@ -27,10 +27,9 @@ from __future__ import annotations
 
 import json
 import os
-import time
 
 from lib import plugins
-from lib.lake import counters, lanes_of, note
+from lib.lake import counters, note
 
 _BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ONE_CHIP_MIX = "closed_loop_q12"
@@ -51,24 +50,16 @@ class Op(plugins.load(_BENCH, "ops", "q12").Op):
         super().__init__(spec, deployment, seed, spans)
         self.fallbacks_before = counters().get("spmd.fallbacks", 0)
 
+    def of_metrics(self, metrics) -> dict:
+        """What the query's metrics say of the mesh: the join's rows per
+        shard and every fallback's reason."""
+        return {"shard_rows": [list(e["shard_rows"])
+                               for e in metrics.events_of("mesh", "join")],
+                "fallbacks": [e.get("reason")
+                              for e in metrics.events_of("spmd", "fallback")]}
+
     def run(self, i: int, traced: bool = False, warming: bool = False) -> dict:
-        """`select`'s `run`, keeping what the query's metrics say of the
-        mesh: the join's rows per shard and every fallback's reason."""
-        params = self.params(i, warming)
-        df = self.dataframe(params)
-        if traced:
-            with self.spans.span("plan", i):
-                self.dep.plan(df)
-        t0 = time.perf_counter()
-        with self.spans.span("collect", i):
-            table, metrics = df.collect(with_metrics=True)
-        t1 = time.perf_counter()
-        rec = {"params": params, "answer": table, "start": t0, "end": t1,
-               "rows": table.num_rows, "lanes": lanes_of(metrics),
-               "shard_rows": [list(e["shard_rows"])
-                              for e in metrics.events_of("mesh", "join")],
-               "fallbacks": [e.get("reason")
-                             for e in metrics.events_of("spmd", "fallback")]}
+        rec = super().run(i, traced, warming)
         if warming:
             note(f"op {i}: join rows per shard {rec['shard_rows']}, "
                  f"fallbacks {rec['fallbacks']}")

@@ -1,7 +1,17 @@
 """The operation `select`: one `DataFrame.collect` of the query a traffic
 file describes (`"query"`: `table`, an optional key `range`, an optional
-`join` to a second table, `select`), the answer kept and compared with
-the plain reference after the window.
+`join` to a second table, `select`), every answer compared with the
+plain reference after the window.
+
+What is held until then does not grow with the rate: the first answer
+for each distinct `params` is kept whole; a later one is compared with
+that first on arrival, after its `end` is stamped, buffer by buffer
+(`lib/compare.same_buffers`), and where the two are the same bytes the
+record keeps a pointer to the first's record (whose verdict is then
+its own) and the table is dropped. An answer that differs from its
+first is kept whole and judged after the window like a first: it may
+hold the same rows in another order. So a mix whose parameters never
+repeat keeps every answer, and one that repeats them keeps one each.
 
 An operation is a module of `ops/` with a class `Op(spec, deployment,
 seed, spans)` that has `warm_ops`, `run(i, traced, warming) -> record`
@@ -13,12 +23,17 @@ plain reference (`reference/<name>.py`).
 
 from __future__ import annotations
 
+import json
 import time
 
 import numpy as np
 
 from lib import compare, plugins
 from lib.lake import lanes_of, note
+
+
+def _key(params: dict) -> str:
+    return json.dumps(params, sort_keys=True)
 
 
 class Op:
@@ -30,6 +45,7 @@ class Op:
         self.dep = deployment
         self.spans = spans
         self.rng = np.random.default_rng([int(seed), 0x7AF])
+        self.firsts = {}  # params -> (record, table) of their first answer
         self.width, self.starts = None, []
         if "range" in self.query:
             r, ds = self.query["range"], deployment.dataset
@@ -97,15 +113,32 @@ class Op:
     def run(self, i: int, traced: bool = False, warming: bool = False) -> dict:
         params = self.params(i, warming)
         df = self.dataframe(params)
-        if traced:
-            with self.spans.span("plan", i):
-                self.dep.plan(df)
         t0 = time.perf_counter()
         with self.spans.span("collect", i):
             table, metrics = df.collect(with_metrics=True)
         t1 = time.perf_counter()
-        return {"params": params, "answer": table, "start": t0, "end": t1,
-                "rows": table.num_rows, "lanes": lanes_of(metrics)}
+        rec = {"params": params, "start": t0, "end": t1,
+               "rows": table.num_rows, "lanes": lanes_of(metrics)}
+        rec.update(self.of_metrics(metrics))
+        with self.spans.span("settle", i):
+            self.settle(rec, table)
+        return rec
+
+    def of_metrics(self, metrics) -> dict:
+        """What else of the query's QueryMetrics an operation's records
+        keep (nothing here)."""
+        return {}
+
+    def settle(self, rec: dict, table) -> None:
+        """Between two operations, so in the rate and in no latency: the
+        record holds either its `answer`, or `same_as`, the record of
+        the first answer to the same `params`, whose bytes this one
+        repeated."""
+        first = self.firsts.setdefault(_key(rec["params"]), (rec, table))
+        if first[0] is not rec and compare.same_buffers(table, first[1]):
+            rec["same_as"] = first[0]
+        else:
+            rec["answer"] = table
 
     # -- the comparison ---------------------------------------------------
 
@@ -126,8 +159,11 @@ class Op:
                 or got["join"][0] in want["join_not"])))
 
     def check(self, records: list) -> dict:
-        """{name: [number, limit]} over every answer handed in. Frees
-        each answer as it goes."""
+        """{name: [number, limit]} over every answer handed in: those
+        the records still hold against the reference, one reference
+        answer per distinct `params`; the others by the verdict on the
+        first answer whose bytes they repeated. Frees each answer as it
+        goes."""
         from concurrent.futures import ThreadPoolExecutor
 
         reference = plugins.load(
@@ -136,48 +172,37 @@ class Op:
         ).Reference(self.dep.tables)
         vocabulary = self.dep.dataset.VOCABULARY
 
-        def want_of(params):
-            return compare.SortedRows(compare.reference_columns(
-                reference.answer(self.query, params)))
-
-        # without a drawn range one reference answer serves every query
-        t0 = time.perf_counter()
-        shared = None if self.width is not None \
-            else want_of(records[0]["params"])
-        t_ref = time.perf_counter() - t0
-
-        verified = []  # one answer already found equal to the reference
-        hits = [0]
-
-        def judge(rec) -> int:
-            table = rec.pop("answer")
-            want = shared or want_of(rec["params"])
+        def judge(rec, want) -> int:
             try:
-                got = compare.arrow_columns(table, vocabulary)
+                got = compare.arrow_columns(rec.pop("answer"), vocabulary)
             except (ValueError, KeyError) as e:
                 note(f"op {rec['op']}: unreadable answer: {e}")
                 return max(want.n, 1)
-            # a query asked again answers, as a rule, with the same rows
-            # in the same order: equal to a verified answer is verified
-            if shared and verified and compare.same_columns(got, verified[0]):
-                hits[0] += 1
-                return 0
-            bad = compare.mismatched_rows(got, want)
-            if shared and not bad and not verified:
-                verified.append(got)
-            return bad
+            return compare.mismatched_rows(got, want)
 
+        def judge_all(held: list) -> None:
+            want = compare.SortedRows(compare.reference_columns(
+                reference.answer(self.query, held[0]["params"])))
+            for rec in held:
+                rec["bad"] = judge(rec, want)
+
+        by_params = {}
+        for rec in records:
+            if "answer" in rec:
+                by_params.setdefault(_key(rec["params"]), []).append(rec)
         t0 = time.perf_counter()
-        first = [judge(records[0])] if shared else []
-        t_first = time.perf_counter() - t0
         with ThreadPoolExecutor(max_workers=6) as pool:
-            bad = first + list(pool.map(judge, records[len(first):]))
-        note(f"check: shared reference {t_ref:.2f}s, first answer "
-             f"{t_first:.2f}s, the other {len(records) - len(first)} "
-             f"{time.perf_counter() - t0 - t_first:.2f}s"
-             f"{'' if not shared else ', of them equal row for row to the first: ' + str(hits[0])}")
+            list(pool.map(judge_all, by_params.values()))
+        self.firsts.clear()
+        held = sum(len(h) for h in by_params.values())
+        note(f"check: answers held: {held}, for {len(by_params)} distinct "
+             f"params, judged against the reference in "
+             f"{time.perf_counter() - t0:.2f}s; the other "
+             f"{len(records) - held} were the bytes of their first")
+        bad = [r["bad"] if "bad" in r else r["same_as"]["bad"]
+               for r in records]
         return {
-            "answers_compared": [len(records), len(records)],
+            "answers_compared": [len(bad), len(records)],
             "mismatched_rows": [int(sum(bad)), 0],
             "wrong_answers": [sum(1 for b in bad if b), 0],
             "off_lane_queries": [sum(self.off_lane(r) for r in records), 0],

@@ -6,10 +6,11 @@ One process: refuses to start unless jax sees exactly the cell's chips as
 TPU devices; makes the data from the seed; builds what the cell's traffic
 needs through the program's public API; warms the cell's shapes; measures
 for `--seconds`; then compares every answer of the window with the plain
-reference. Notes go to earlier lines; the last line of stdout is the one
-JSON object the driver reads. With `--trace 0` its metrics are the cell's
-end-to-end metrics, with `--trace 1` its per-layer metrics, read from a
-profiler trace of the window's first seconds.
+reference. Notes go to earlier lines, one of them (`[bench] where: {...}`,
+lib/where.py) saying where the window's time went; the last line of stdout
+is the one JSON object the driver reads. With `--trace 0` its metrics are
+the cell's end-to-end metrics, with `--trace 1` its per-layer metrics, read
+from a profiler trace of the window's first seconds.
 
 Everything that belongs to one configuration, dataset, traffic mix, kind
 of traffic, kind of operation or metric is a file found by the name that
@@ -149,7 +150,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
     devices = require_chips(cell["chips"]) if need_chip else jax.devices()
 
-    from lib import compiles, plugins, spans as spans_mod, trace_reduce
+    from lib import compiles, plugins, spans as spans_mod, trace_reduce, where
     from lib.lake import Deployment, counters
 
     bench_dir = found["bench_dir"]
@@ -193,7 +194,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              f"{compiled_before['counts']['backend']} programs, cache loads "
              f"{compiled_before['seconds']['cache_load']:.2f}s)")
 
+        watch = where.Watch().start()
         window = traffic.run_window(seconds, tracer)
+        spent = watch.stop()
         failed = traffic.failed
         compiled = compiles.delta(listener.snapshot(), compiled_before)
         counters_after = counters()
@@ -250,6 +253,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         else:
             compared["answers_compared"] = [0, 1]
         note(f"check: {time.perf_counter() - t_check:.2f}s")
+        result["where"] = dict(
+            where.window_notes(traffic.records, window, spans), **spent,
+            fs=where.fs_type(work),
+            stat_us=where.stat_us(os.path.join(work, "lake")))
+        note("where: " + json.dumps(result["where"]))
         result["correct"] = bool(
             traffic.records
             and all(_within(name, got, limit)

@@ -138,6 +138,20 @@ def test_the_roofline_divides_by_summed_chip_seconds(root):
     assert reader.compute(run) is None
 
 
+def test_the_roofline_reads_a_repeats_answer_from_its_first(root):
+    """The window's records as `ops/select.settle` leaves them: the
+    answer repeated a warm-up's bytes, so the record holds `same_as`,
+    the warm-up's record, and no table of its own."""
+    reader = plug("metrics", "join_x4_roofline")
+    want = reader.compute(make_run(root))
+    run = make_run(root)
+    warm = run["records"][0]
+    run["records"] = [{"same_as": warm}, {"same_as": warm}]
+    assert reader.compute(run) == pytest.approx(want)
+    run["records"] = [{"same_as": {}}]
+    assert reader.compute(run) is None
+
+
 def test_skew_is_the_busiest_chip_over_the_mean(root):
     reader = plug("metrics", "shard_busy_skew_pct")
     # inside a whole query chips 0, 1, 3 are busy 1.2 s, chip 2 1.68 s

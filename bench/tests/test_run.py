@@ -92,16 +92,16 @@ def test_the_stress_join_mix_is_ready_for_a_later_cell():
         "name": "stress_join", "config": "tpch_sf3",
         "traffic": "closed_loop_join", "chips": 1, "why": "stress"})
     for m in manifest["end_to_end"] + manifest["per_layer"]:
-        if m["name"] in ("queries_per_s", "join_p95_ms", "plan_ms"):
+        if m["name"] in ("queries_per_s", "join_p95_ms", "optimize_ms"):
             m["workloads"].append("stress_join")
     result = bench_run.run_cell("stress_join", 11, 1.0, True,
                                 manifest=manifest, **TINY)
     assert result["correct"] is True, result["compared"]
     assert result["compared"]["answers_compared"][0] > 2
-    assert "plan_ms" in result["metrics"]
+    assert "optimize_ms" in result["metrics"]
 
 
-# -- the timed path broken underneath ---------------------------------------
+# -- answers altered on their way to the client -------------------------------
 
 
 def _alter_one_value(table):
@@ -123,10 +123,9 @@ def _alter_one_value(table):
     raise AssertionError("no float64 or int64 column to alter")
 
 
-@pytest.fixture
-def altered_answers(monkeypatch):
-    """Every 3rd answer the scheduler hands back carries one altered
-    value."""
+def _answers_through(monkeypatch, alter):
+    """Every answer the scheduler hands back goes through
+    `alter(n, table)`, n counting from 1; returns the counter."""
     from hyperspace_tpu.engine import scheduler
 
     sched = scheduler.get_scheduler()
@@ -135,11 +134,103 @@ def altered_answers(monkeypatch):
     def collect(df, **kw):
         table, metrics = real(df, **kw)
         calls[0] += 1
-        if calls[0] % 3 == 0:
-            table = _alter_one_value(table)
-        return table, metrics
+        return alter(calls[0], table), metrics
 
     monkeypatch.setattr(sched, "collect", collect)
+    return calls
+
+
+# -- what the harness holds through a window --------------------------------
+
+
+RANGE = next(c for c in CELLS if "range" in c)
+
+
+def _records_of(monkeypatch):
+    """What the driver's records of the next `run_cell` reach when the
+    window has closed (before the check frees the answers): how many
+    records, distinct `params` and live Arrow tables."""
+    from conftest import plug
+
+    driver = plug("drivers", "closed_loop").Driver
+    seen = {}
+    real = driver.check
+
+    def check(self):
+        import pyarrow as pa
+
+        records = self.warm_records + self.records
+        seen["records"] = len(records)
+        seen["tables"] = len({
+            id(t) for r in records
+            for t in (r.get("answer"), r.get("same_as", {}).get("answer"))
+            if isinstance(t, pa.Table)})
+        seen["params"] = len({json.dumps(r["params"], sort_keys=True)
+                              for r in records})
+        return real(self)
+
+    monkeypatch.setattr(driver, "check", check)
+    return seen
+
+
+def test_a_window_keeps_one_table_per_distinct_params(monkeypatch, capsys):
+    seen = _records_of(monkeypatch)
+    result = bench_run.run_cell(RANGE, 21, 1.0, False, **TINY)
+    assert result["correct"] is True, result["compared"]
+    assert seen["records"] > seen["params"] == 8
+    assert seen["tables"] <= seen["params"]
+    assert result["compared"]["answers_compared"] == [seen["records"]] * 2
+    # the `where` note parses, and its quarters hold the window's operations
+    lines = [l for l in capsys.readouterr().out.splitlines()
+             if l.startswith("[bench] where: ")]
+    assert len(lines) == 1
+    where = json.loads(lines[0][len("[bench] where: "):])
+    assert where == result["where"]
+    assert sum(where["quarter_ops"]) == where["ops"] == result["attempted"]
+    assert where["op_ms"]["p50"] <= where["op_ms"]["p95"] <= where["op_ms"]["max"]
+    assert where["stalls"]["n"] >= 0 and where["settle_ms_p50"] > 0
+    assert len(where["rss_bytes"]) == 2 and min(where["rss_bytes"]) > 0
+    assert sum(where["cpu_s"]) > 0 and where["gc"]["collections"] >= 0
+    assert where["fs"] != "unknown" and where["stat_us"] > 0
+
+
+def test_one_wrong_repeat_is_one_wrong_answer(monkeypatch):
+    """One changed bit in one column of one repeat (the 11th answer: the
+    8 starts are warmed first) is kept whole, judged and counted once."""
+    calls = _answers_through(
+        monkeypatch, lambda n, t: _alter_one_value(t) if n == 11 else t)
+    result = bench_run.run_cell(RANGE, 22, 1.0, False, **TINY)
+    assert calls[0] > 11
+    assert result["correct"] is False
+    assert result["compared"]["wrong_answers"] == [1, 0]
+    assert result["compared"]["mismatched_rows"][0] >= 1
+    assert result["compared"]["answers_compared"][0] == calls[0]
+
+
+def test_a_repeat_in_another_order_is_correct(monkeypatch):
+    """Every 3rd answer comes back with its rows reversed: not the bytes
+    of its first, so kept and judged as a multiset, and right."""
+    import pyarrow as pa
+
+    seen = _records_of(monkeypatch)
+    calls = _answers_through(
+        monkeypatch, lambda n, t: t.take(pa.array(
+            np.arange(t.num_rows)[::-1])) if n % 3 == 0 else t)
+    result = bench_run.run_cell(RANGE, 23, 1.0, False, **TINY)
+    assert result["correct"] is True, result["compared"]
+    assert seen["params"] < seen["tables"] < seen["records"]
+    assert result["compared"]["answers_compared"][0] == calls[0]
+
+
+# -- the timed path broken underneath ---------------------------------------
+
+
+@pytest.fixture
+def altered_answers(monkeypatch):
+    """Every 3rd answer the scheduler hands back carries one altered
+    value."""
+    _answers_through(
+        monkeypatch, lambda n, t: _alter_one_value(t) if n % 3 == 0 else t)
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -42,13 +42,14 @@ def synthetic():
 
 def test_idle_gaps_go_to_the_innermost_span():
     gaps = dict(tr.idle_gaps(synthetic()))
-    # 0-1: plan (0.45 is in plan); 2-4 and 6-8 midpoint 7 -> collect#0
-    # ends at 7.0, op#1 starts at 7.0 -> op; 9-10: collect#1 ends at 9.5,
-    # midpoint 9.5 -> op#1 ends 9.5 -> outside
-    assert gaps["plan"] == pytest.approx(1.0)
-    assert gaps["collect"] == pytest.approx(2.0)
-    assert gaps["op"] == pytest.approx(2.0)
-    assert gaps["outside"] == pytest.approx(1.0)
+    # a gap is cut where a span starts or ends inside it. 0-1: plan to
+    # 0.9, then collect#0; 2-4: collect#0; 6-8: collect#0 to 7.0, op#1
+    # to 7.5, collect#1 from there; 9-10: collect#1 to 9.5 (where op#1
+    # ends too), then outside
+    assert gaps["plan"] == pytest.approx(0.9)
+    assert gaps["collect"] == pytest.approx(0.1 + 2.0 + 1.0 + 0.5 + 0.5)
+    assert gaps["op"] == pytest.approx(0.5)
+    assert gaps["outside"] == pytest.approx(0.5)
     assert sum(gaps.values()) == pytest.approx(10.0 - 4.0)
 
 

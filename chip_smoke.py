@@ -248,12 +248,24 @@ def timed(label: str, fn):
 
 
 def print_process_notes() -> None:
+    """The process's totals from the registry (the link first: what the
+    phases moved, and what the pipelined transfer saved), then the
+    allocator's peak, which only a chip's accountant reads."""
     import jax
 
     from hyperspace_tpu import telemetry
-    from hyperspace_tpu.telemetry import artifact, memory
+    from hyperspace_tpu.telemetry import memory
 
-    note(f"transfer digest: {json.dumps(artifact.transfer_digest())}")
+    c = telemetry.get_registry().counters_dict()
+    note("link totals: " + json.dumps({
+        name: c.get(name, 0) for name in (
+            "link.h2d.bytes", "link.h2d.seconds", "link.h2d.chunks",
+            "link.h2d.transfers", "link.d2h.bytes", "link.d2h.seconds",
+            "link.d2h.chunks", "link.d2h.prefetch_errors",
+            "transfer.overlap_saved_seconds")}))
+    note(f"registry totals: compile.seconds "
+         f"{c.get('compile.seconds', 0.0):.2f} compile.traces "
+         f"{int(c.get('compile.traces', 0))}")
     memory.sample()
     backend = memory.get_accountant().backend
     assert backend == "memory_stats", \
@@ -263,10 +275,6 @@ def print_process_notes() -> None:
         note(f"{d.platform}:{d.id} peak_bytes_in_use "
              f"{st['peak_bytes_in_use']} of bytes_limit "
              f"{st.get('bytes_limit')}")
-    c = telemetry.get_registry().counters_dict()
-    note(f"registry totals: compile.seconds "
-         f"{c.get('compile.seconds', 0.0):.2f} compile.traces "
-         f"{int(c.get('compile.traces', 0))}")
 
 
 # ---------------------------------------------------------------------------

@@ -454,8 +454,8 @@ TELEMETRY_CRITPATH_ENABLED_DEFAULT = "true"
 # Sampling profiler (`telemetry/profiler.py`): when enabled, a daemon
 # thread samples every live thread's stack at `profiler.hz` and
 # aggregates host time by collapsed stack (served at `/profile`).
-# Off by default; the overhead when on is gated (<2% closed-loop QPS)
-# by `bench_regress.py --serve`.
+# Off by default; `tests/test_profiler.py` bounds the sampler's own
+# loop cost.
 TELEMETRY_PROFILER_ENABLED = "spark.hyperspace.telemetry.profiler.enabled"
 TELEMETRY_PROFILER_ENABLED_DEFAULT = "false"
 TELEMETRY_PROFILER_HZ = "spark.hyperspace.telemetry.profiler.hz"

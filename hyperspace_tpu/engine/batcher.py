@@ -43,8 +43,8 @@ Mechanics:
   aot_warmup` — keyed like the segment cache by (index root, version,
   shape, rows, bucket) — riding the PR-11 persistent compile cache so
   a fresh replica's first batched query loads executables instead of
-  tracing (`compile.traces == 0` on the warmed shapes, gated by
-  `bench_regress.py --serve`).
+  tracing (`compile.traces == 0` on the warmed shapes:
+  `tests/test_batcher.py::test_aot_warmup_makes_first_cohorts_trace_free`).
 
 Series: `serve.batch.{invocations,members,window_wait_s,fallbacks,
 solo}`, plus `compile.aot.*` and the segment cache's

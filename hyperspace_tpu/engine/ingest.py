@@ -32,7 +32,7 @@ Design rules, in order of importance:
    jittered backoff — no sleep-in-except) and, still losing, concedes
    with `ingest.conflicts` + a "conceded" decision. Exactly one writer
    ever wins; the appends are picked up next tick.
-4. **Caller-threaded.** `run_once()` is synchronous; the owner (bench
+4. **Caller-threaded.** `run_once()` is synchronous; the owner (a
    harness, a cron, a test) drives it on `ingest.interval.seconds`.
    The engine's thread seam keeps background threads in the scheduler;
    an injected crash (BaseException) propagates to the caller like a

@@ -1254,8 +1254,7 @@ class SortMergeJoinExec(PhysicalNode):
         first-class (per-range dictionaries + in-program rank remaps).
         Covers every equi-join type of the sharded counting match;
         right_outer swaps sides. A decline WITH a mesh available is a
-        real lane miss — counted as `spmd.fallbacks` (the TPC-DS bench
-        asserts the flagship set runs fallback-free)."""
+        real lane miss — counted as `spmd.fallbacks`."""
         from hyperspace_tpu.parallel import spmd
         from hyperspace_tpu.parallel.context import (distribution_mesh,
                                                      mesh_size)

@@ -508,7 +508,7 @@ class QueryScheduler:
         return self._slo
 
     def slo_snapshot(self, conf=None) -> dict:
-        """SLO window state for `/healthz` and the bench drivers."""
+        """SLO window state for `/healthz`, alerts and history."""
         return self._slo.snapshot(conf)
 
     def _tenant_slo_for(self, tenant: str) -> SloTracker:

@@ -203,8 +203,9 @@ class Hyperspace:
         site mirrors its global counter inc onto the active tenant's
         `tenant.<id>.*` series at the same line, so `totals` (the
         per-tenant sums) equals `global` (the process counters) to the
-        bit — the contract `bench_regress.py --serve` gates. Unscoped
-        work bills the "default" tenant; nothing is ever dropped."""
+        bit (`tests/test_tenancy.py::test_tenant_report_exactness`
+        holds it). Unscoped work bills the "default" tenant; nothing is
+        ever dropped."""
         from hyperspace_tpu import telemetry
 
         usage = telemetry.tenant_digest()

@@ -188,13 +188,12 @@ def build_lane(rows: int) -> str:
     takes: "native-host" (C++ radix — no device link traffic,
     link-independent cost), "host-lexsort" (small build; an XLA compile
     could never amortize), or "device" (no native library and the size
-    justifies the on-chip sort). THE routing predicate — the bench
-    reports this same value, so artifact labels can't drift from the
-    product's actual path. Device/mesh-resident batches are routed by
-    residency before this is consulted (`write_bucketed_batch`,
-    `parallel/build.py`). Above 2^31 rows the native lane's int32
-    permutation would wrap (`native.bucket_key_sort_perm` declines), so
-    sizing routes to the int64-permutation lanes instead."""
+    justifies the on-chip sort). THE routing predicate.
+    Device/mesh-resident batches are routed by residency before this is
+    consulted (`write_bucketed_batch`, `parallel/build.py`). Above 2^31
+    rows the native lane's int32 permutation would wrap
+    (`native.bucket_key_sort_perm` declines), so sizing routes to the
+    int64-permutation lanes instead."""
     from hyperspace_tpu import native
     if rows < BUILD_MIN_DEVICE_ROWS:
         return "host-lexsort"

@@ -373,7 +373,7 @@ def read_host_batch(paths: Sequence[str],
 def clear_device_cache() -> None:
     """Empty the HBM segment cache (`io/segcache.py` owns the device
     lane now; this name survives for the cold-cache callers —
-    `clear_read_cache`, bench drivers, tests)."""
+    `clear_read_cache`, tests)."""
     from hyperspace_tpu.io import segcache
     segcache.clear()
 

@@ -1,15 +1,14 @@
 """HBM-resident index segment cache — THE device-residency seam.
 
-BENCH_r05 put the device path's problem in one line: ~0.11s of device
-compute against ~1.95s of H2D/D2H. Under serving traffic every query
-re-paid parquet decode + H2D for the same hot index shards; the paper's
-premise is that a covering index is a *reusable* derived dataset
-(PAPER.md §3 — read many times per build), and on a TPU the analog of
-Spark's distributed page cache is HBM residency. This module promotes
-the stamped device-batch LRU that used to live inside `io/parquet.py`
-into a first-class, process-wide, byte-budgeted segment cache that owns
-device residency end to end (`scripts/check_metrics_coverage.py` bans
-the old `_device_cache`/`read_device_batch` access anywhere else):
+Under serving traffic every query re-paid parquet decode + H2D for the
+same hot index shards; the paper's premise is that a covering index is
+a *reusable* derived dataset (PAPER.md §3 — read many times per
+build), and on a TPU the analog of Spark's distributed page cache is
+HBM residency. This module promotes the stamped device-batch LRU that
+used to live inside `io/parquet.py` into a first-class, process-wide,
+byte-budgeted segment cache that owns device residency end to end
+(`scripts/check_metrics_coverage.py` bans the old
+`_device_cache`/`read_device_batch` access anywhere else):
 
 - **keying**: a committed index segment is keyed by
   `(index root, v__=N, bucket selector, columns, schema)` — content
@@ -1028,7 +1027,7 @@ def reset_cache() -> None:
 
 
 def clear() -> None:
-    """Empty the process cache (bench cold phases, test isolation)."""
+    """Empty the process cache (cold phases, test isolation)."""
     cache = _cache
     if cache is not None:
         cache.clear()

@@ -1,11 +1,10 @@
 """Pipelined host<->device transfer engine — THE link seam.
 
-The index build is link-bound on this port (BENCH_r05: 0.114s of rung1
-device compute vs ~1.95s of H2D key staging + D2H permutation fetch),
-and the paper's data-plane lesson — keep it a streaming recipe, not a
-blocking copy — maps on TPU to classic input-pipeline software
-pipelining: chunk the batch and keep the decoder, the link, the device,
-and the writer busy at once.
+The index build stages every key column over the link and fetches the
+permutation back, and the paper's data-plane lesson — keep it a
+streaming recipe, not a blocking copy — maps on TPU to classic
+input-pipeline software pipelining: chunk the batch and keep the
+decoder, the link, the device, and the writer busy at once.
 
 Every host->device crossing in the package routes through this module
 (`scripts/check_metrics_coverage.py` bans raw `jax.device_put` anywhere

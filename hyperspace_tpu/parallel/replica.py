@@ -122,7 +122,7 @@ class ReplicaRouter:
         """Pick the replica slice for one query, or None when replica
         routing does not apply (flat mesh, replication off, too few
         slices). `buckets` overrides the plan-derived bucket hints:
-        {root: (bucket_ids, num_buckets)} — the bench drives the
+        {root: (bucket_ids, num_buckets)} — a caller drives the
         hot/cold policy through it deterministically."""
         from hyperspace_tpu.parallel.context import topology
 

@@ -43,14 +43,12 @@ cache in the system reports through), and `compilation`
 (`instrumented_jit`: compile spans, trace/cache-hit counters, and
 retrace-cause decision events for every jit entry point).
 
-Regression attribution (PR 6) closes the loop: `artifact` (the ONE
-canonical, versioned bench-artifact schema both bench drivers emit),
-`diff` (align two artifacts or two QueryMetrics trees and decompose
-each wall delta into compute / link / compile / cache / fallback /
-residual buckets — the ranked attribution tree `scripts/bench_diff.py`
-prints and `scripts/bench_regress.py` auto-runs on gate failure), and
-`flight` (the always-on ring of the last-K completed QueryMetrics plus
-the slow-query dump, `spark.hyperspace.telemetry.slowlog.*`).
+Regression attribution: `flight` (the always-on ring of the last-K
+completed QueryMetrics plus the slow-query dump,
+`spark.hyperspace.telemetry.slowlog.*`) and `diff` (align two
+QueryMetrics trees — a slow-query dump against a live re-run — and
+decompose the wall delta into compute / link / compile / cache /
+fallback / residual buckets).
 """
 
 from __future__ import annotations
@@ -74,7 +72,6 @@ from hyperspace_tpu.telemetry.trace import (DEVICE_SCOPES, SPAN_NAMES,
                                             tracing_enabled)
 from hyperspace_tpu.telemetry import memory  # noqa: F401
 from hyperspace_tpu.telemetry import compilation  # noqa: F401
-from hyperspace_tpu.telemetry import artifact  # noqa: F401
 from hyperspace_tpu.telemetry import diff  # noqa: F401
 from hyperspace_tpu.telemetry import flight  # noqa: F401
 from hyperspace_tpu.telemetry import timeseries  # noqa: F401
@@ -98,7 +95,7 @@ __all__ = [
     "disable_tracing", "tracing_enabled", "tracer", "span",
     "spans_active", "SPAN_NAMES", "DEVICE_SCOPES",
     "link_transfer", "record_link_transfer", "export_trace",
-    "memory", "compilation", "instrumented_jit", "device_scoped", "artifact", "diff",
+    "memory", "compilation", "instrumented_jit", "device_scoped", "diff",
     "flight", "FlightRecorder", "get_recorder",
     "DeviceMemoryAccountant", "get_accountant",
     "timeseries", "ops_server", "critical_path", "profiler",
@@ -645,9 +642,8 @@ class QueryMetrics:
                           default=str)
 
     def summary(self) -> dict:
-        """Compact per-query digest — what the bench artifacts embed so
-        future rounds carry operator-level trajectories, not just
-        totals. Operator seconds are summed per operator type over
+        """Compact per-query digest (operator-level trajectories, not
+        just totals). Operator seconds are summed per operator type over
         SELF time (child time subtracted), so the digest adds up instead
         of double-counting nested walls."""
         child_s: Dict[Optional[int], float] = {}

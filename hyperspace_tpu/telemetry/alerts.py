@@ -543,9 +543,9 @@ class AlertManager:
             return True
 
     def digest(self) -> dict:
-        """The compact block bench artifacts embed (and
-        `bench_regress.py --serve` gates `fired == 0` on a clean lap):
-        the four exact counters plus a compact incident list."""
+        """The four exact counters plus a compact incident list
+        (`fired == 0` on a clean lap:
+        `tests/test_alerts.py::test_clean_closed_loop_lap_fires_zero_incidents`)."""
         counters = _registry.get_registry().counters_dict()
         return {
             "evaluations": int(counters.get("alerts.evaluations", 0)),

@@ -36,8 +36,7 @@ Two surfaces:
   registry counters (plus `critpath.wall.seconds`); the PR-15 sampler
   selects the `critpath.` family into its ring, and
   `window_shares()` derives the trailing-window share of each segment
-  — what `/critpath` serves and `bench_serve.py` embeds per arrival
-  rate.
+  — what `/critpath` serves.
 
 The TIMELINE of a query is not rebuilt here: the span seam
 (`telemetry/trace.py`) writes the program's `hs.*` spans onto the

@@ -1,15 +1,10 @@
-"""Regression attribution: diff two bench artifacts (or two
-`QueryMetrics` trees) and decompose each wall-clock delta into
-attributed buckets.
+"""Regression attribution: diff two `QueryMetrics` trees and decompose
+the wall-clock delta into attributed buckets.
 
-PRs 1-5 built the telemetry that can EXPLAIN a regression — operator
-trees, link spans, retrace-cause events, cache series, degradation
-events — but nothing consumed two rounds and said *why* one is slower;
-BENCH_TPCDS_r04 regressed 3.9x against r03 and sat unexplained for two
-PRs. This module is that consumer. Given an old and a new artifact
-(canonical schema, `telemetry/artifact.py`), it aligns queries by name
-and operator nodes by tree path, and splits every query's wall delta
-into:
+The operator's use is a flight-recorder slow-query dump against a live
+re-run of the same query (`telemetry/flight.py`: the dump carries the
+full tree): `diff_trees(doc["metrics"], live.to_dict())` aligns
+operator nodes by tree path and splits the wall delta into:
 
 - `compute`   — per-operator self-time movement net of link/compile/
                 device-dispatch (node-level deltas ride in the bucket
@@ -37,30 +32,19 @@ into:
                 (`serve.interrupted.<phase>` counters), so timeout
                 clusters name their phase instead of landing in
                 residual;
-- `framework_common` — LEGACY-artifact coarse attribution: the part of
-                the rules-on slowdown matching the rules-OFF lane's
-                relative slowdown. Both lanes share everything except
-                the index rewrite, so a shift both paid is environment
-                / framework-wide (a shared host's time-of-day
-                wobble lands here), not index-path work;
 - `residual`  — whatever the telemetry cannot attribute.
 
-Buckets are ranked by attributed magnitude; `dominant` names the
-biggest. `ArtifactDiff.format_tree()` renders the ranked attribution
-tree `scripts/bench_diff.py` prints, and `scripts/bench_regress.py`
-auto-runs on any gate failure so a failed gate arrives with its own
-diagnosis. `diff_trees()` diffs two raw `QueryMetrics` trees directly
-— a flight-recorder dump against a live re-run, say — without any
-artifact around them.
+Buckets are ranked by attributed magnitude; `QueryDiff.dominant` names
+the biggest. `tests/test_tree_diff.py` and
+`tests/test_flight_recorder.py::test_slow_dump_round_trip_and_diff`
+hold the attribution.
 """
 
 from __future__ import annotations
 
-import json
 from typing import Dict, List, Optional
 
-__all__ = ["Bucket", "QueryDiff", "ArtifactDiff", "diff_artifacts",
-           "diff_trees"]
+__all__ = ["Bucket", "QueryDiff", "diff_trees"]
 
 # Evidence-only buckets attribute counts, never seconds (their cost is
 # already inside compute/link); they rank below any timed bucket.
@@ -324,45 +308,6 @@ def _attribute_from_rollups(qd: QueryDiff, old: Optional[dict],
     qd.buckets.append(Bucket("cancellation", 0.0, serve_detail))
 
 
-def _attribute_legacy(qd: QueryDiff, old_entry: dict,
-                      new_entry: dict) -> None:
-    """Coarse per-lane attribution when per-query telemetry is absent
-    (legacy rounds): the rules-OFF lane runs the same engine minus the
-    index rewrite, so the slowdown BOTH lanes paid is framework/
-    environment-common; only the remainder is index-path-specific."""
-    old_off = old_entry.get("rules_off_s")
-    new_off = new_entry.get("rules_off_s")
-    delta = qd.delta or 0.0
-    common = 0.0
-    detail: dict = {}
-    if old_off and new_off and qd.old_wall:
-        off_ratio = new_off / old_off
-        common = qd.old_wall * (off_ratio - 1.0)
-        detail = {"rules_off_s": [old_off, new_off],
-                  "rules_off_ratio": round(off_ratio, 3)}
-        qd.notes.append(
-            f"rules-off lane moved x{off_ratio:.2f} "
-            f"({old_off:.1f}s -> {new_off:.1f}s): shared framework/"
-            "environment cost, not index-path work")
-    qd.buckets.append(Bucket("framework_common", common, detail))
-    qd.buckets.append(Bucket("residual", delta - common))
-    old_cpu = old_entry.get("pandas_s")
-    new_cpu = new_entry.get("pandas_s")
-    if old_cpu and new_cpu:
-        qd.notes.append(
-            f"pandas baseline moved x{new_cpu / old_cpu:.2f} "
-            f"({old_cpu:.1f}s -> {new_cpu:.1f}s) — vs_baseline shifts "
-            "independently of the framework's own wall")
-    qd.notes.append("no per-query telemetry in at least one artifact "
-                    "(legacy round): attribution is per-lane only")
-
-
-def _entry_block(entry: dict):
-    """Best telemetry block in a per-query artifact entry: the full
-    tree when the round committed one, else the summary digest."""
-    return entry.get("tree") or entry.get("metrics")
-
-
 def _tree_critpath(tree) -> Optional[dict]:
     if isinstance(tree, dict):
         return tree.get("critical_path")
@@ -396,164 +341,3 @@ def diff_trees(old_tree, new_tree, name: str = "query") -> QueryDiff:
                     f"{seg} {d:+.4f}s" for seg, d in movers
                     if abs(d) > 1e-9))
     return qd
-
-
-def _diff_query_entry(name: str, old_entry: dict,
-                      new_entry: dict) -> QueryDiff:
-    old_roll = _rollup(_entry_block(old_entry))
-    new_roll = _rollup(_entry_block(new_entry))
-    old_wall = old_entry.get("rules_on_s",
-                             (old_roll or {}).get("wall"))
-    new_wall = new_entry.get("rules_on_s",
-                             (new_roll or {}).get("wall"))
-    qd = QueryDiff(name, old_wall, new_wall)
-    if old_roll and new_roll:
-        _attribute_from_rollups(qd, old_roll, new_roll)
-    else:
-        _attribute_legacy(qd, old_entry, new_entry)
-    return qd
-
-
-class ArtifactDiff:
-    """Attribution of a whole round-over-round artifact pair."""
-
-    def __init__(self, old_doc: dict, new_doc: dict,
-                 old_name: str = "old", new_name: str = "new"):
-        self.old_name = old_name
-        self.new_name = new_name
-        self.old_vs_baseline = old_doc.get("vs_baseline")
-        self.new_vs_baseline = new_doc.get("vs_baseline")
-        self.old_value = old_doc.get("value")
-        self.new_value = new_doc.get("value")
-        self.metric = new_doc.get("metric") or old_doc.get("metric")
-        self.queries: List[QueryDiff] = []
-        self.only_old: List[str] = []
-        self.only_new: List[str] = []
-        self.notes: List[str] = []
-
-        old_q = old_doc.get("queries") or {}
-        new_q = new_doc.get("queries") or {}
-        # bench.py artifacts carry rungs instead of queries; their
-        # device_s walls and metrics digests diff the same way.
-        if not old_q and not new_q:
-            old_q = {k: self._rung_entry(v)
-                     for k, v in (old_doc.get("rungs") or {}).items()}
-            new_q = {k: self._rung_entry(v)
-                     for k, v in (new_doc.get("rungs") or {}).items()}
-        for name in sorted(set(old_q) | set(new_q)):
-            if name not in old_q:
-                self.only_new.append(name)
-                continue
-            if name not in new_q:
-                self.only_old.append(name)
-                continue
-            self.queries.append(
-                _diff_query_entry(name, old_q[name], new_q[name]))
-
-        self._environment_notes(old_doc, new_doc)
-
-    @staticmethod
-    def _rung_entry(rung: dict) -> dict:
-        entry = dict(rung)
-        if "rules_on_s" not in entry and "device_s" in entry:
-            entry["rules_on_s"] = entry["device_s"]
-        if "pandas_s" not in entry and "cpu_s" in entry:
-            entry["pandas_s"] = entry["cpu_s"]
-        return entry
-
-    def _environment_notes(self, old_doc: dict, new_doc: dict) -> None:
-        op = (old_doc.get("link_probe") or {})
-        np_ = (new_doc.get("link_probe") or {})
-        if op.get("h2d_mb_s") and np_.get("h2d_mb_s"):
-            self.notes.append(
-                f"link probe: h2d {op['h2d_mb_s']} -> "
-                f"{np_['h2d_mb_s']} MB/s, sync floor "
-                f"{op.get('sync_latency_s')} -> "
-                f"{np_.get('sync_latency_s')}s")
-        for doc, label in ((old_doc, self.old_name),
-                           (new_doc, self.new_name)):
-            if doc.get("legacy"):
-                self.notes.append(
-                    f"{label} is a migrated legacy round: no telemetry "
-                    "sections; attribution is per-lane only")
-        ot, nt = old_doc.get("platform"), new_doc.get("platform")
-        if ot and nt and ot != nt:
-            self.notes.append(
-                f"PLATFORM CHANGED {ot} -> {nt}: walls are not "
-                "hardware-comparable; read ratios, not seconds")
-        os_, ns = old_doc.get("scale"), new_doc.get("scale")
-        if os_ is not None and ns is not None and os_ != ns:
-            self.notes.append(
-                f"SCALE CHANGED {os_} -> {ns}: walls are not "
-                "workload-comparable; read ratios, not seconds")
-
-    def ranked_queries(self) -> List[QueryDiff]:
-        return sorted(self.queries,
-                      key=lambda q: -abs(q.delta or 0.0))
-
-    def to_dict(self) -> dict:
-        return {
-            "old": self.old_name,
-            "new": self.new_name,
-            "metric": self.metric,
-            "vs_baseline": [self.old_vs_baseline, self.new_vs_baseline],
-            "value": [self.old_value, self.new_value],
-            "queries": [q.to_dict() for q in self.ranked_queries()],
-            "only_in_old": self.only_old,
-            "only_in_new": self.only_new,
-            "notes": list(self.notes),
-        }
-
-    def to_json(self, indent: Optional[int] = 2) -> str:
-        return json.dumps(self.to_dict(), indent=indent, default=str)
-
-    def format_tree(self) -> str:
-        lines = [f"Attribution: {self.old_name} -> {self.new_name}"]
-        if self.old_vs_baseline is not None \
-                and self.new_vs_baseline is not None:
-            ch = (self.new_vs_baseline / self.old_vs_baseline - 1.0
-                  if self.old_vs_baseline else 0.0)
-            lines.append(
-                f"  headline vs_baseline {self.old_vs_baseline:.3f} -> "
-                f"{self.new_vs_baseline:.3f} ({ch:+.1%})")
-        if isinstance(self.old_value, (int, float)) \
-                and isinstance(self.new_value, (int, float)):
-            lines.append(f"  {self.metric or 'value'} "
-                         f"{self.old_value:.3f} -> {self.new_value:.3f}")
-        for note in self.notes:
-            lines.append(f"  note: {note}")
-        for qd in self.ranked_queries():
-            head = f"+- {qd.name}"
-            if qd.old_wall is not None and qd.new_wall is not None:
-                head += (f"  {qd.old_wall:.3f}s -> {qd.new_wall:.3f}s"
-                         f"  ({qd.delta:+.3f}s"
-                         + (f", x{qd.ratio:.2f}" if qd.ratio else "")
-                         + ")")
-            if qd.dominant:
-                head += f"  dominant: {qd.dominant}"
-            lines.append(head)
-            for b in qd.ranked():
-                detail = ""
-                if b.detail:
-                    detail = "  " + json.dumps(b.detail, default=str,
-                                               sort_keys=True)
-                    if len(detail) > 140:
-                        detail = detail[:137] + "..."
-                lines.append(f"   +- {b.name:16s} {b.seconds:+9.3f}s"
-                             f"{detail}")
-            for note in qd.notes:
-                lines.append(f"   |  note: {note}")
-        for name in self.only_old:
-            lines.append(f"+- {name}  (only in {self.old_name})")
-        for name in self.only_new:
-            lines.append(f"+- {name}  (only in {self.new_name})")
-        return "\n".join(lines)
-
-
-def diff_artifacts(old_doc: dict, new_doc: dict, old_name: str = "old",
-                   new_name: str = "new") -> ArtifactDiff:
-    """Diff two canonical (or migrated) artifact documents. Callers
-    loading from disk should go through `telemetry.artifact.load` so
-    driver envelopes are unwrapped and legacy rounds are explicit."""
-    return ArtifactDiff(old_doc, new_doc, old_name=old_name,
-                        new_name=new_name)

@@ -4,10 +4,10 @@ process.
 Every observability surface before this module — registry, sampler
 ring, SLO burn, flight recorder — is in-process and evaporates on
 exit, so trend questions ("is warm p99 creeping week over week?",
-"when did the cache hit rate collapse?") were only answerable through
-hand-committed bench artifacts. The source paper's core discipline is
-that ALL index data and metadata live on the lake with no side
-services; telemetry history is metadata and gets the same treatment:
+"when did the cache hit rate collapse?") were not answerable at all.
+The source paper's core discipline is that ALL index data and metadata
+live on the lake with no side services; telemetry history is metadata
+and gets the same treatment:
 
 - **Writer** — `TelemetryHistory.flush()` assembles one append-only,
   schema-versioned SEGMENT document (registry snapshot, the sampler
@@ -32,11 +32,8 @@ services; telemetry history is metadata and gets the same treatment:
   time-ordered view (samples ordered by wall time, incidents
   deduplicated by id, per-process provenance retained).
 - **CLI** — `python -m hyperspace_tpu.telemetry.history report
-  [--dir D] [--window S] [--series NAME] [--baseline ARTIFACT]`
-  renders per-series windows and rate deltas from the merged history,
-  and regression vs a named baseline round (a committed canonical
-  bench artifact: its `process_metrics` counters against the history's
-  latest cumulative values).
+  [--dir D] [--window S] [--series NAME]`
+  renders per-series windows and rate deltas from the merged history.
 
 This module is the ONE place history segments are written —
 `scripts/check_metrics_coverage.py` bans the directory literal
@@ -343,14 +340,11 @@ def merge(directory: str) -> dict:
 
 
 def trend_report(merged: dict, window_s: float = 300.0,
-                 series: Optional[List[str]] = None,
-                 baseline: Optional[dict] = None) -> dict:
+                 series: Optional[List[str]] = None) -> dict:
     """Per-series trends over the merged history: for each counter,
     the rate over the trailing `window_s` next to the all-history
     rate (the delta is the trend); for each histogram, windowed
-    p50/p90/p99. `baseline` (a canonical bench artifact dict) adds a
-    regression section: the history's latest cumulative counters vs
-    the round's committed `process_metrics`."""
+    p50/p90/p99."""
     from hyperspace_tpu.telemetry.timeseries import (delta_buckets,
                                                      quantile_from_buckets)
     samples = merged.get("samples") or []
@@ -408,24 +402,6 @@ def trend_report(merged: dict, window_s: float = 300.0,
             "p50": quantile_from_buckets(buckets, 0.50),
             "p90": quantile_from_buckets(buckets, 0.90),
             "p99": quantile_from_buckets(buckets, 0.99),
-        }
-    if baseline is not None:
-        base_counters = baseline.get("process_metrics") or {}
-        reg = {}
-        for name in sorted(set(base_counters)
-                           & set((latest.get("counters") or {}))):
-            old_v = float(base_counters.get(name) or 0.0)
-            new_v = float((latest.get("counters") or {}).get(name, 0.0))
-            if old_v == 0.0 and new_v == 0.0:
-                continue
-            reg[name] = {"baseline": round(old_v, 6),
-                         "history": round(new_v, 6),
-                         "change": (round(new_v / old_v, 4)
-                                    if old_v else None)}
-        out["vs_baseline"] = {
-            "metric": baseline.get("metric"),
-            "driver": baseline.get("driver"),
-            "counters": reg,
         }
     return out
 
@@ -528,8 +504,6 @@ def _main(argv: List[str]) -> int:
                      help="trailing window seconds (default 300)")
     rep.add_argument("--series", action="append", default=None,
                      help="series name or prefix filter (repeatable)")
-    rep.add_argument("--baseline", default=None,
-                     help="canonical bench artifact to regress against")
     args = parser.parse_args(argv)
     if args.cmd != "report":
         parser.print_help()
@@ -538,13 +512,9 @@ def _main(argv: List[str]) -> int:
     if directory is None:
         from hyperspace_tpu.config import HyperspaceConf
         directory = HyperspaceConf().telemetry_history_dir
-    baseline = None
-    if args.baseline:
-        from hyperspace_tpu.telemetry import artifact
-        baseline = artifact.load(args.baseline, migrate_legacy=True)
     merged = merge(directory)
     report = trend_report(merged, window_s=args.window,
-                          series=args.series, baseline=baseline)
+                          series=args.series)
     report["directory"] = directory
     report["segments"] = merged["segments"]
     report["skipped_segments"] = merged["skipped"]

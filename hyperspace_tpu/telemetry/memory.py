@@ -40,7 +40,7 @@ from typing import Dict, Optional, Tuple
 from hyperspace_tpu.telemetry import registry as _registry
 
 __all__ = ["DeviceMemoryAccountant", "get_accountant", "maybe_sample",
-           "sample", "snapshot", "artifact_section", "cache_hit",
+           "sample", "snapshot", "cache_hit",
            "cache_miss", "cache_eviction", "cache_stats"]
 
 # Minimum seconds between throttled samples. The live-arrays fallback
@@ -235,33 +235,3 @@ def cache_stats(name: str, bytes_held: Optional[int],
         reg.gauge(f"cache.{name}.bytes_held").set(bytes_held)
     if entries is not None:
         reg.gauge(f"cache.{name}.entries").set(entries)
-
-
-def artifact_section() -> dict:
-    """The memory/compile block bench artifacts embed next to
-    `process_metrics`: per-device peak HBM, per-cache
-    hit/miss/eviction/bytes-held series, compile trace/cache-hit
-    counts. Everything a regression gate (`scripts/bench_regress.py`)
-    or a committed round needs to carry the resource story."""
-    snap = _ACCOUNTANT.snapshot()
-    reg = _registry.get_registry().to_dict()
-    caches: Dict[str, dict] = {}
-    for kind, metrics in (("counters", reg["counters"]),
-                          ("gauges", reg["gauges"])):
-        for name, value in metrics.items():
-            if not name.startswith("cache."):
-                continue
-            _, cache_name, series = name.split(".", 2)
-            caches.setdefault(cache_name, {})[series] = value
-    # Complete each cache's standard series with explicit zeros — a
-    # cache that never evicted (or never hit) still reports the full
-    # shape, so artifact consumers diff like-for-like across rounds.
-    for series in ("hits", "misses", "evictions"):
-        for stats in caches.values():
-            stats.setdefault(series, 0)
-    compile_stats = {k.split(".", 1)[1]: v
-                     for k, v in reg["counters"].items()
-                     if k.startswith("compile.")}
-    snap["caches"] = caches
-    snap["compile"] = compile_stats
-    return snap

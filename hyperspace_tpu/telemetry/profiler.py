@@ -4,10 +4,12 @@ Two instruments, one discipline (in-process, pull-based, opt-in):
 
 **Sampling profiler** — a daemon thread walks every live thread's
 stack (`sys._current_frames()`) at `telemetry.profiler.hz` and
-aggregates host time by collapsed stack. Cheap enough to leave on in
-production (the overhead gate in `bench_regress.py --serve` holds it
-under 2% of closed-loop QPS): sampling costs one frame walk per
-thread per tick, no tracing hooks, no interpreter callbacks. Exports
+aggregates host time by collapsed stack. Meant to be cheap enough to
+leave on in production
+(`tests/test_profiler.py::test_sampling_cost_is_bounded` bounds the
+sampler's own loop cost; its effect on served throughput is not
+measured by any benchmark cell yet): sampling costs one frame walk
+per thread per tick, no tracing hooks, no interpreter callbacks. Exports
 the two standard shapes — collapsed stacks (`module:function;... N`,
 the flamegraph.pl / speedscope input) and nested flamegraph JSON
 (d3-flame-graph) — plus by-module/by-function host-time tables,
@@ -82,7 +84,7 @@ class SamplingProfiler:
     """The always-on host profiler: one daemon thread, one dict of
     collapsed stacks -> sample counts. `start()`/`stop()` are
     idempotent; `drain()` waits for the loop to exit; `reset()` clears
-    the aggregate without stopping (the bench's A/B phases use it)."""
+    the aggregate without stopping."""
 
     def __init__(self, hz: float = DEFAULT_HZ):
         self.hz = max(float(hz), 0.1)

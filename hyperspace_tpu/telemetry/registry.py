@@ -255,7 +255,7 @@ class MetricsRegistry:
                 "histograms": histograms}
 
     def counters_dict(self) -> Dict[str, float]:
-        """Counters only — the compact form bench artifacts embed."""
+        """Counters only."""
         return self.to_dict()["counters"]
 
     def series_snapshot(self) -> dict:
@@ -281,7 +281,7 @@ class MetricsRegistry:
     def to_text(self) -> str:
         """Prometheus text exposition format (the `/metrics` payload a
         service deployment would scrape). Conformance contract (pinned
-        by `tests/test_artifact_diff.py::test_prometheus_conformance`):
+        by `tests/test_tree_diff.py::test_prometheus_conformance`):
         every family gets `# HELP` then `# TYPE` before its samples,
         names obey the Prometheus grammar (dotted names sanitized via
         `_prom_name`; the HELP text carries the original dotted name

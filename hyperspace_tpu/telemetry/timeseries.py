@@ -2,7 +2,7 @@
 
 Every telemetry surface before this module is snapshot-shaped — the
 registry accumulates since process start, `QueryMetrics` covers one
-query, bench artifacts cover one round — so "what is p99 over the last
+query, a benchmark run covers one window — so "what is p99 over the last
 60 seconds, and is it getting worse?" was unanswerable. This module is
 the flight-recorder discipline applied to the registry itself: a
 background sampler (one daemon thread, `drain()`-able, atexit-stopped —
@@ -28,8 +28,7 @@ cannot express:
   `tests/test_timeseries.py` pins against a brute-force oracle.
 
 The ring itself is the `/timeseries` payload of the ops server
-(`telemetry/ops_server.py`) and the source of `bench_serve.py`'s
-per-second QPS/latency timeline. Everything is in-process and
+(`telemetry/ops_server.py`). Everything is in-process and
 pull-based — the source paper keeps all index state on the lake with
 no side services, and the operations plane keeps that discipline: no
 agent, no push gateway, nothing to deploy next to the engine.
@@ -339,7 +338,7 @@ class TimeSeriesSampler:
         the state at the window start (merge = subtract cumulative
         states; summing per-interval deltas gives the identical answer,
         which is the mergeability the gauges rely on). `since_t` pins
-        the window start to an absolute time instead (bench drivers
+        the window start to an absolute time instead (a caller
         isolating one phase)."""
         latest = self._latest()
         if latest is None:
@@ -456,9 +455,9 @@ class TimeSeriesSampler:
     def samples(self, since_t: Optional[float] = None,
                 since_seq: Optional[int] = None) -> List[dict]:
         """The ring as JSON-able dicts, oldest first. `since_t` keeps
-        only samples strictly after that time (the bench drivers'
-        phase isolation); `since_seq` keeps only ticks with a strictly
-        greater sequence (the incremental-scraper cursor)."""
+        only samples strictly after that time (phase isolation);
+        `since_seq` keeps only ticks with a strictly greater sequence
+        (the incremental-scraper cursor)."""
         with self._lock:
             entries = list(self._ring)
         return [s.to_dict() for s in entries

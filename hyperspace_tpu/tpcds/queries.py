@@ -12,10 +12,9 @@ key columns are projected away immediately after each join so repeatedly
 joined dimensions never collide on output names.
 
 The pandas oracle for each query doubles as the CPU baseline and the
-correctness check: `bench_tpcds.py` and `tests/test_tpcds.py` assert
-sorted-result equality between rules-on, rules-off, and the oracle —
-the reference's own E2E guarantee
-(`E2EHyperspaceRulesTests.scala:330-346`).
+correctness check: `tests/test_tpcds.py` asserts sorted-result
+equality between rules-on, rules-off, and the oracle — the reference's
+own E2E guarantee (`E2EHyperspaceRulesTests.scala:330-346`).
 
 The round-3 queries run in UN-REDUCED shape: full official column
 lists, SUM/AVG over expression inputs, ORDER BY aggregate aliases

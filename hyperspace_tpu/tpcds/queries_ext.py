@@ -3,7 +3,7 @@ q46, q70, q73, q81, q93, q97 — pushing the suite past 40 queries.
 
 Same contract as `queries.py`: each query is a rule-acceleratable join
 tree with a pandas oracle, and the 3-way equality check (rules on ==
-rules off == oracle) runs in `tests/test_tpcds.py` / `bench_tpcds.py`.
+rules off == oracle) runs in `tests/test_tpcds.py`.
 Shapes introduced here: per-group average join-backs with HAVING (q1 /
 q6 / q32 / q81), ROLLUP as grouping-set unions with per-branch
 `lochierarchy` and rank-within-parent windows (q27/q36/q70), ticket-

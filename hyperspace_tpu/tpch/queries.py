@@ -18,8 +18,8 @@ Queries whose official ORDER BY does not totally order rows append a
 deterministic key to BOTH lanes (q3/q10/q18: the 3-way equality check
 needs a stable top-N; the TPC-DS suite does the same for q79).
 
-Each oracle doubles as the CPU baseline; `tests/test_tpch.py` and
-`bench_tpch.py` assert rules-on == rules-off == oracle — the reference's
+Each oracle doubles as the CPU baseline; `tests/test_tpch.py`
+asserts rules-on == rules-off == oracle — the reference's
 E2E guarantee (`E2EHyperspaceRulesTests.scala:330-346`) across the full
 TPC-H set its serde layer pins (`index/serde/package.scala:46-49`).
 """
@@ -40,7 +40,7 @@ _EPOCH = datetime.date(1970, 1, 1)
 
 def normalize_result(df: pd.DataFrame) -> pd.DataFrame:
     """THE result-normalization contract the 3-way equality checks use
-    (tests + bench): stringify non-str object columns (date objects),
+    (`tests/test_tpch.py`): stringify non-str object columns (date objects),
     sort by every column, widen numerics to float64."""
     out = df.copy()
     for c in out.columns:

@@ -34,9 +34,9 @@ import pkgutil
 import re
 import sys
 
-# A lint/diff tool over committed artifacts and source: it never needs the
-# chip, and pinning the CPU keeps it (and the tests that shell out to it)
-# from taking the one process slot a chip allows.
+# A lint over source and docs: it never needs the chip, and pinning the
+# CPU keeps it (and the tests that shell out to it) from taking the one
+# process slot a chip allows.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -88,9 +88,8 @@ def check_jit_entry_points(package_dir: str):
 # through the pipelined transfer engine (chunked staging, in-flight
 # byte window, fault injection, link.{h2d,d2h}.* counters). A raw
 # `jax.device_put` anywhere else in the package is a link crossing the
-# engine cannot pipeline, observe, or fault-inject. Tests and bench
-# drivers live outside the package tree and stay exempt (the raw-link
-# probe in bench_common.py MUST bypass the engine by design).
+# engine cannot pipeline, observe, or fault-inject. Tests live outside
+# the package tree and stay exempt.
 _RAW_PUT_RE = re.compile(r"jax\.device_put\s*\(|partial\(\s*jax\.device_put\b")
 _PUT_ALLOWED = os.path.join("io", "transfer.py")
 
@@ -150,37 +149,6 @@ def check_segment_cache_seam(package_dir: str):
                             "device-batch cache access bypasses the "
                             "HBM segment cache — route it through "
                             "io/segcache.py")
-    return failures
-
-
-# The ONE sanctioned artifact emitter: every bench driver's committed
-# JSON routes through telemetry.artifact.make_artifact, which stamps
-# `schema_version` and unconditionally attaches `process_metrics`,
-# `memory`, and `transfer`. A driver assembling its own top-level
-# artifact can silently drop the telemetry the regression differ
-# attributes from — exactly how the r03/r04 TPC-DS rounds became
-# mechanically incomparable.
-_BENCH_EXEMPT = ("bench_common.py",)  # helpers; prints no artifact
-
-
-def check_bench_artifact_seam(repo_root: str):
-    """Source lint: every `bench*.py` driver at the repo root must
-    route its artifact through `telemetry.artifact.make_artifact`."""
-    import glob as _glob
-
-    failures = []
-    for path in sorted(_glob.glob(os.path.join(repo_root, "bench*.py"))):
-        fname = os.path.basename(path)
-        if fname in _BENCH_EXEMPT:
-            continue
-        with open(path, encoding="utf-8") as f:
-            src = f.read()
-        if "make_artifact(" not in src:
-            failures.append(
-                f"{fname}: bench driver emits an artifact without "
-                "routing through telemetry.artifact.make_artifact — "
-                "schema_version/process_metrics can silently go "
-                "missing from a committed round")
     return failures
 
 
@@ -646,8 +614,8 @@ def check_metric_doc_rows(package_dir: str, repo_root: str):
 # `_tenant.set(...)` — or even a `tenant_scope(...)` entered anywhere
 # else in the package — is a query whose device/link/cache charges
 # land on a tenant the admission plane never admitted, silently
-# breaking the chargeback exactness contract
-# (`bench_regress.py --serve` gates per-tenant sums == globals).
+# breaking the chargeback exactness contract (per-tenant sums ==
+# globals: `tests/test_tenancy.py`).
 _RAW_TENANT_RE = re.compile(r"\b_tenant\s*\.\s*set\s*\(|"
                             r"\btenant_scope\s*\(")
 _TENANT_ALLOWED = (os.path.join("telemetry", "__init__.py"),
@@ -1067,8 +1035,6 @@ def main() -> int:
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
     failures.extend(check_string_remap_seam(
         os.path.dirname(hyperspace_tpu.__file__)))
-    failures.extend(check_bench_artifact_seam(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
     failures.extend(check_http_server_seam(
         os.path.dirname(hyperspace_tpu.__file__)))
     failures.extend(check_tenant_seam(

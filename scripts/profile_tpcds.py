@@ -108,8 +108,8 @@ def main():
 
     if args.mode == "class":
         # SELF seconds per operator class over the LAST run, from the
-        # recorder's parent/child linkage (the same subtraction
-        # `QueryMetrics.summary` ships in bench artifacts).
+        # recorder's parent/child linkage (the subtraction is
+        # `QueryMetrics.summary`'s).
         per_op = metrics.summary()["operators"]
         print(f"\nper-class SELF seconds, last run "
               f"(of {walls[-1]:.3f}s):")

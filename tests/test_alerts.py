@@ -252,7 +252,7 @@ def test_slo_burn_chaos_fires_one_evidence_bundled_incident(
                   if d["reason"] == "incident"]
         assert states == ["firing", "resolved"]
 
-        # The digest bench artifacts embed reflects the same story.
+        # The manager's digest tells the same story.
         digest = m.digest()
         assert digest["active"] == 0
         assert digest["incidents"][-1]["rule"] == "slo_burn"
@@ -370,32 +370,6 @@ def test_history_cross_process_merge_and_cli_report(
     assert "queries.total" in doc["counters"]
 
 
-def test_history_cli_baseline_regression(tmp_path, conf, capsys,
-                                         scripted_global_sampler):
-    """`--baseline` regresses the history's latest cumulative counters
-    against a committed canonical bench artifact."""
-    from hyperspace_tpu.telemetry import artifact
-
-    telemetry.get_registry().counter("queries.total").inc()
-    scripted_global_sampler.tick(t=1000.0)
-    d = tmp_path / "hist"
-    TelemetryHistory(str(d)).flush(conf=conf, reason="manual",
-                                   now=1000.0)
-    doc = artifact.make_artifact(driver="bench.py", metric="wall_s",
-                                 value=1.0, unit="s", vs_baseline=None)
-    base = tmp_path / "BENCH_r01.json"
-    base.write_text(json.dumps(doc))
-    rc = history._main(["report", "--dir", str(d),
-                        "--baseline", str(base)])
-    assert rc == 0
-    out = json.loads(capsys.readouterr().out)
-    vs = out["vs_baseline"]
-    assert vs["driver"] == "bench.py"
-    assert "queries.total" in vs["counters"]
-    row = vs["counters"]["queries.total"]
-    assert row["history"] >= row["baseline"] > 0
-
-
 # ---------------------------------------------------------------------------
 # The false-positive gate in miniature: a clean lap fires nothing
 # ---------------------------------------------------------------------------
@@ -403,7 +377,7 @@ def test_history_cli_baseline_regression(tmp_path, conf, capsys,
 
 def test_clean_closed_loop_lap_fires_zero_incidents(
         tmp_path, fresh_scheduler, no_history):
-    """bench_serve.py's `clean_run_fired == 0` gate, in miniature: a
+    """The false-positive gate in miniature: a
     healthy concurrent closed-loop lap with the GLOBAL alert manager
     live (the sampler's tick hook evaluating every default rule) must
     fire ZERO incidents — the plane evaluates, nothing alarms."""

@@ -71,6 +71,36 @@ def test_one_chip_phases_hold_on_the_cpu(tiny, lossy_float64_decode, tmp_path,
     lake.sess.close()
 
 
+def test_process_notes_read_the_registry(tiny, tmp_path, capsys):
+    """The closing notes of a chip run, after a build here: the link's
+    totals are the registry's, which belong to the process (so the
+    build's share is read as a difference), and the allocator's peak is
+    asked of a chip only: the CPU's accountant is refused, after the
+    totals."""
+    import json
+
+    from hyperspace_tpu import telemetry
+
+    before = telemetry.get_registry().counters_dict()
+    lake = chip_smoke.Lake(str(tmp_path), 22)
+    chip_smoke.phase_build(lake)
+    lake.sess.close()
+    capsys.readouterr()
+    with pytest.raises(AssertionError, match="HBM accountant"):
+        chip_smoke.print_process_notes()
+    said = capsys.readouterr().out.splitlines()
+    line = next(ln for ln in said if ln.startswith("[smoke] link totals: "))
+    totals = json.loads(line.split("link totals: ", 1)[1])
+    assert len(totals) == 9
+    now = telemetry.get_registry().counters_dict()
+    assert totals == {name: now.get(name, 0) for name in totals}
+    moved = {name: totals[name] - before.get(name, 0) for name in totals}
+    assert moved["link.h2d.bytes"] > 0 and moved["link.d2h.bytes"] > 0
+    assert moved["link.h2d.chunks"] >= moved["link.h2d.transfers"] > 0
+    assert any(ln.startswith("[smoke] registry totals: compile.seconds")
+               for ln in said)
+
+
 def test_mesh_phases_hold_on_the_virtual_mesh(tiny, lossy_float64_decode,
                                               tmp_path):
     import jax

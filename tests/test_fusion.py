@@ -191,7 +191,12 @@ def test_build_columns_defer_to_post_compaction(env):
 
     session, fact, dim = env
     sess = session()
+    # Metadata and executables retire together, as in `_execute_device`:
+    # a kept executable without its metadata runs eager for the rest of
+    # the process, in whatever test file this worker is handed next.
     fusion._OUT_META.clear()
+    if fusion._run_stage_jit is not None:
+        fusion._run_stage_jit.clear_cache()
     out = run_query(sess, fact, dim, "left_outer")
     # name/w are carried (never filtered on) -> recorded as lazy specs.
     lazy_names = {spec[0]

@@ -107,8 +107,7 @@ def test_sampler_lifecycle_and_exports(stopped_profiler, busy_thread):
 def test_sampling_cost_is_bounded(stopped_profiler, busy_thread):
     """The continuous-profiling promise in microcosm: the sampler's
     own measured loop cost over a real window is a small fraction of
-    that window (the full closed-loop QPS gate lives in
-    bench_regress.py --serve)."""
+    that window."""
     cost0 = _counter("profiler.sample.seconds")
     samples0 = _counter("profiler.samples")
     p = profiler.start_profiler(hz=50)

@@ -307,8 +307,8 @@ def test_deadline_mid_query_is_typed_and_flight_recorded(
     assert _counter(f"serve.interrupted.{exc.phase}") >= 1
 
     # The cancelled query's recorder joined the flight ring WITH the
-    # interrupted phase — that is what lets bench_diff attribute a
-    # timeout cluster to a bucket instead of residual.
+    # interrupted phase — that is what lets `telemetry.diff` attribute
+    # a timeout cluster to a bucket instead of residual.
     ring = telemetry.get_recorder().queries(5)
     dumped = [m for m in ring
               if getattr(m, "query_id", None) == exc.query_id]

@@ -7,7 +7,6 @@ gate."""
 import gc
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -367,67 +366,3 @@ def test_no_device_array_leak_across_repeat_queries(sales_env,
     with leak_sentinel():
         for _ in range(3):
             q().collect()
-
-
-# ---------------------------------------------------------------------------
-# Artifact section + bench_regress peak-HBM gate
-# ---------------------------------------------------------------------------
-
-
-def test_artifact_section_shape(sales_env):
-    session, fact_dir = sales_env
-    sess = session()
-    sess.read_parquet(fact_dir).filter(
-        col("qty") > lit(1)).select("key").collect()
-    section = telemetry.memory.artifact_section()
-    assert section["peak_hbm_bytes"] > 0
-    assert section["devices"]
-    assert "segments" in section["caches"]
-    series = section["caches"]["segments"]
-    assert {"hits", "misses", "evictions", "bytes_held",
-            "entries"} <= set(series)
-    assert section["compile"].get("traces", 0) >= 1
-    assert section["compile"].get("cache_hits", 0) >= 0
-
-
-def _write_artifact(path, headline, peak_hbm=None):
-    # Canonical-schema fixture; a round MAY predate the memory
-    # section (peak_hbm=None) and must then not gate on it.
-    doc = {"schema_version": 1, "metric": "fixture", "value": 1.0,
-           "process_metrics": {},
-           "vs_baseline": headline,
-           "rungs": {"1_build": {"vs_baseline": headline}}}
-    if peak_hbm is not None:
-        doc["memory"] = {"peak_hbm_bytes": peak_hbm}
-    with open(path, "w") as f:
-        json.dump(doc, f)
-
-
-def test_bench_regress_gates_on_peak_hbm(tmp_path):
-    script = os.path.join(REPO_ROOT, "scripts", "bench_regress.py")
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    old = str(tmp_path / "BENCH_r01.json")
-    ok = str(tmp_path / "BENCH_r02.json")
-    bad = str(tmp_path / "BENCH_r03.json")
-    legacy = str(tmp_path / "BENCH_r00.json")
-    _write_artifact(old, 2.0, peak_hbm=1_000_000)
-    _write_artifact(ok, 2.0, peak_hbm=1_100_000)    # +10%: passes
-    _write_artifact(bad, 2.0, peak_hbm=1_600_000)   # +60%: fails
-    _write_artifact(legacy, 2.0)                    # no memory: no gate
-    good = subprocess.run([sys.executable, script, old, ok],
-                          capture_output=True, text=True, env=env)
-    assert good.returncode == 0, good.stdout + good.stderr
-    assert "peak_hbm_bytes" in good.stdout
-    regress = subprocess.run([sys.executable, script, old, bad],
-                             capture_output=True, text=True, env=env)
-    assert regress.returncode == 1
-    assert "peak_hbm_bytes" in regress.stderr
-    # Wall-time regressions still gate in BOTH directions of the ratio.
-    _write_artifact(bad, 1.0, peak_hbm=1_000_000)
-    slow = subprocess.run([sys.executable, script, old, bad],
-                          capture_output=True, text=True, env=env)
-    assert slow.returncode == 1
-    # Artifacts predating the memory section never gate on it.
-    legacy_run = subprocess.run([sys.executable, script, legacy, old],
-                                capture_output=True, text=True, env=env)
-    assert legacy_run.returncode == 0, legacy_run.stdout + legacy_run.stderr

@@ -4,8 +4,6 @@ reports, and mesh-path telemetry on the virtual 8-device mesh."""
 
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pyarrow as pa
@@ -17,8 +15,6 @@ from hyperspace_tpu.config import HyperspaceConf
 from hyperspace_tpu.engine.session import HyperspaceSession
 from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.plan.expr import col, lit
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
@@ -361,48 +357,3 @@ def test_mesh_join_query_attributes_to_recorder(sales_env, tracing):
     reg = telemetry.get_registry()
     assert reg.counter("mesh.join.execs").value >= 1
     assert reg.histogram("mesh.join.shard_rows").count >= 8
-
-
-# ---------------------------------------------------------------------------
-# bench_regress gate
-# ---------------------------------------------------------------------------
-
-
-def _write_artifact(path, ratios, wrap_parsed=False):
-    # Canonical-schema fixture (telemetry/artifact.py): bench_regress
-    # refuses legacy shapes outright, so gate fixtures carry the
-    # required stamp fields.
-    doc = {"schema_version": 1, "metric": "fixture", "value": 1.0,
-           "process_metrics": {},
-           "vs_baseline": ratios.get("headline", 1.0),
-           "rungs": {k: {"vs_baseline": v} for k, v in ratios.items()
-                     if k != "headline"}}
-    if wrap_parsed:
-        doc = {"parsed": doc, "rc": 0, "cmd": "python bench.py"}
-    with open(path, "w") as f:
-        json.dump(doc, f)
-
-
-def test_bench_regress_gate(tmp_path):
-    script = os.path.join(REPO_ROOT, "scripts", "bench_regress.py")
-    old = str(tmp_path / "BENCH_r01.json")
-    ok = str(tmp_path / "BENCH_r02.json")
-    bad = str(tmp_path / "BENCH_r03.json")
-    _write_artifact(old, {"headline": 2.0, "1_build": 2.0,
-                          "2_filter": 100.0})
-    # within 15%: passes (one rung only present in new: never gates)
-    _write_artifact(ok, {"headline": 1.8, "1_build": 1.8,
-                         "2_filter": 90.0, "9_new": 1.0},
-                    wrap_parsed=True)
-    # 2_filter drops 40%: fails
-    _write_artifact(bad, {"headline": 2.0, "1_build": 2.0,
-                          "2_filter": 60.0})
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    good = subprocess.run([sys.executable, script, old, ok],
-                          capture_output=True, text=True, env=env)
-    assert good.returncode == 0, good.stdout + good.stderr
-    assert "bench_regress: OK" in good.stdout
-    regress = subprocess.run([sys.executable, script, old, bad],
-                             capture_output=True, text=True, env=env)
-    assert regress.returncode == 1
-    assert "2_filter" in regress.stderr

@@ -576,7 +576,7 @@ def test_prometheus_conformance_hostile_tenant_ids():
     assert len(families) == 2 * len(hostile)
     # The HELP line carries the original dotted name for reverse
     # mapping, correctly escaped (the tab rides through as-is; the
-    # newline rules are pinned by test_artifact_diff's conformance).
+    # newline rules are pinned by test_tree_diff's conformance).
     assert 'acme"corp"eu 1' in text
 
 

@@ -148,12 +148,6 @@ class HyperspaceConf:
                 constants.DISTRIBUTION_REPLICATION_HOT_FRACTION_DEFAULT)
 
     @property
-    def distribution_capacity_factor(self) -> float:
-        value = self.get(constants.DISTRIBUTION_CAPACITY_FACTOR)
-        return (float(value) if value is not None
-                else constants.DISTRIBUTION_CAPACITY_FACTOR_DEFAULT)
-
-    @property
     def distribution_dict_max_entries(self) -> int:
         """Per-range string-dictionary entry cap for the recorded
         born-sharded layout (`_shard_layout.json`); <= 0 disables

@@ -334,13 +334,6 @@ DISTRIBUTION_REPLICATION_HOT_FRACTION_DEFAULT = 0.5
 # (the escape hatch if a workload hits an SPMD-lane defect).
 DISTRIBUTION_SPMD = "spark.hyperspace.distribution.spmd.enabled"
 DISTRIBUTION_SPMD_DEFAULT = "true"
-# First-attempt static per-shard output capacity factor of the SPMD
-# join expansion (and the in-program repartition's per-peer slabs):
-# capacity = factor x per-shard input rows, doubled on exact on-device
-# overflow detection. Larger = fewer retries, more HBM per attempt.
-DISTRIBUTION_CAPACITY_FACTOR = \
-    "spark.hyperspace.distribution.capacity.factor"
-DISTRIBUTION_CAPACITY_FACTOR_DEFAULT = 2.0
 # Born-sharded string layout: a mesh build records each device range's
 # sorted local string dictionary in `_shard_layout.json` so query-time
 # global-dictionary resolution is pure JSON (no data read). A range

@@ -619,9 +619,8 @@ class FilterExec(PhysicalNode):
         (`spmd.sharded_predicate_mask`) in which each device evaluates
         its shard, nothing crosses the link, and the downstream join /
         aggregate skips masked rows exactly as it skips padding. The
-        per-bucket histogram is stale after filtering, so it is dropped
-        (capacity heuristics fall back to the overflow-retry loop); a
-        child's virtual-sub-shard split survives (row-local narrowing
+        per-bucket histogram is stale after filtering, so it is dropped;
+        a child's virtual-sub-shard split survives (row-local narrowing
         cannot move rows across shards)."""
         sh = self.child.execute_sharded(num_buckets, mesh,
                                         align_plan=align_plan)
@@ -1335,11 +1334,9 @@ class SortMergeJoinExec(PhysicalNode):
             telemetry.annotate(lane="spmd")
             from hyperspace_tpu.ops.bucketed_join import (
                 assemble_join_output)
-            factor = (self.conf.distribution_capacity_factor
-                      if self.conf is not None else None)
             ri, li = spmd.sharded_join_indices(
                 rsh, lsh, self.right_keys, self.left_keys, how="inner",
-                capacity_factor=factor, conf=self.conf)
+                conf=self.conf)
             return assemble_join_output(lsh.batch, rsh.batch, li, ri,
                                         how="inner",
                                         columns=self.out_columns)
@@ -1350,17 +1347,14 @@ class SortMergeJoinExec(PhysicalNode):
                 anti=self.how == "left_anti", conf=self.conf)
             return lsh.batch.take(idx)
         from hyperspace_tpu.ops.bucketed_join import assemble_join_output
-        factor = (self.conf.distribution_capacity_factor
-                  if self.conf is not None else None)
         if self.how == "right_outer":
             ri, li = spmd.sharded_join_indices(
                 rsh, lsh, self.right_keys, self.left_keys,
-                how="left_outer", capacity_factor=factor,
-                conf=self.conf)
+                how="left_outer", conf=self.conf)
         else:
             li, ri = spmd.sharded_join_indices(
                 lsh, rsh, self.left_keys, self.right_keys, how=self.how,
-                capacity_factor=factor, conf=self.conf)
+                conf=self.conf)
         return assemble_join_output(lsh.batch, rsh.batch, li, ri,
                                     how=self.how,
                                     columns=self.out_columns)

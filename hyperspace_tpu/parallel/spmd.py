@@ -14,21 +14,21 @@ with ZERO link traffic, `mesh.assemble_sharded_rows`), and the
 shuffle-free sort-merge join, predicate scan, and group-by aggregate
 execute as single jitted SPMD programs under the canonical row sharding:
 
-- **one program per join**: key-lane decomposition, the counting match,
-  and the static-capacity pair expansion trace into ONE `instrumented_jit`
-  dispatch. The legacy path's host-side sizing sync between match and
-  expansion (it read `sum(counts)` to shape the expansion) is replaced
-  by a STATIC per-shard output capacity with
-  on-device overflow detection — the expansion never waits on the host,
-  and the one scalar readback per join carries (total, extra, overflow)
-  together *after* everything has dispatched. Overflow triggers an exact
-  retry at doubled capacity (the build's all_to_all discipline), and the
-  capacity is CLIPPED by the exact per-shard upper bound derived from the
-  two sides' bucket histograms, so the retry loop terminates.
+- **a join is two programs and one readback**: key-lane decomposition
+  and the counting match are one `instrumented_jit` dispatch whose
+  shapes depend on the inputs alone; the host reads the per-shard
+  totals (with the unmatched-right counts and the route-overflow
+  scalar) ONCE; the pair expansion is a second dispatch over the
+  power-of-two rung just above the largest total (`_expand_rung`). An
+  expansion sized by what the match found cannot overflow, so there is
+  no capacity to guess, double or remember, and its cost follows the
+  answer, not the inputs (a static capacity of 2x the input rows spent
+  98% of a four-chip Q12 placing 23,438 pairs in 11.3 M slots: PERF.md
+  section 6, PR 33).
 - **ICI repartition in-program**: when the two sides' bucket counts
   mismatch (the ranker's fallback), the smaller-bucket side's key lanes
   re-bucket to the larger count through a `shard_map` all_to_all *inside
-  the same jitted program* — row payload never routes (the expansion
+  the match program* — row payload never routes (the expansion
   carries routed original-row ids and the output gather reaches across
   shards), and nothing crosses through the host.
 - **stage-to-stage residency**: join output stays a device-resident
@@ -60,8 +60,8 @@ through compact rank-remap tables (`string_remap_tables`, THE
 lint-enforced remap seam): the int32 local-code -> pair-merged-rank
 tables are built once on the host from the dictionaries (derived from
 the same precomputed value-hash identity the bucket layout uses), cached
-content-keyed in the segment cache, and replicated into the single
-jitted SMJ program over ICI — warm repeats serve them straight from HBM
+content-keyed in the segment cache, and replicated into the jitted
+match program over ICI — warm repeats serve them straight from HBM
 (`spmd.strings.remap_cache_hits`) and ship zero string bytes. String
 predicates compile to code-space range tests against the global
 dictionary (`engine/compiler.py`), so the jitted filter program never
@@ -80,6 +80,7 @@ import hyperspace_tpu._jax_config  # noqa: F401
 from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.io.columnar import ColumnBatch, DeviceColumn
 from hyperspace_tpu.ops import keys as keymod
+from hyperspace_tpu.ops.bucketed_join import next_pow2
 from hyperspace_tpu.parallel.mesh import (DCN_AXIS, SHARD_AXIS,
                                           assemble_sharded_rows,
                                           bucket_owner, bucket_ranges,
@@ -89,10 +90,12 @@ from hyperspace_tpu.parallel.mesh import (DCN_AXIS, SHARD_AXIS,
                                           shard_row_segments, shard_rows,
                                           total_shards)
 
-# Static-capacity discipline: first attempt sizes the per-shard output at
-# CAPACITY_FACTOR x the per-shard input rows; on-device overflow
-# detection doubles it until the expansion fits (exact — nothing is ever
-# silently dropped).
+# Route-slab discipline: the first attempt of a repartition sizes each
+# per-peer slab at CAPACITY_FACTOR x the even share of the per-shard
+# rows; on-device overflow detection doubles it until every row fits
+# (exact — nothing is ever silently dropped). The join's pair expansion
+# is NOT sized this way: it is sized by what its match found
+# (`_expand_rung`).
 CAPACITY_FACTOR = 2.0
 
 # Born-sharded skew guard: when the padded [S, C] layout would out-size
@@ -1125,20 +1128,22 @@ def _repartition_lanes(lanes, hash_lanes, null, valid, gid,
             overflow)
 
 
-def _match_expand(l_lanes2d, r_lanes2d, l_null, r_null, l_pad, r_pad,
-                  r_gid, cap: int, left_outer: bool, need_right: bool):
-    """The counting match + static-capacity expansion over the combined
-    [S, T] layout (T = Cl + Cr). Per shard: ONE stable sort by
-    (pad, null, *lanes, side, slot), run grouping from adjacent lane
-    differences, right-run brackets by cumulative counting, then the
-    expansion into the [S, cap] output slots — all traced into the ONE
-    enclosing jit, no host sizing sync between match and expansion.
+def _match(l_lanes2d, r_lanes2d, l_null, r_null, l_pad, r_pad, r_gid,
+           left_outer: bool, need_right: bool):
+    """The counting match over the combined [S, T] layout (T = Cl + Cr).
+    Per shard: ONE stable sort by (pad, null, *lanes, side, slot), run
+    grouping from adjacent lane differences, right-run brackets by
+    cumulative counting, and each left element's window
+    [starts, starts + counts) of the shard's output. Nothing here has a
+    shape that depends on the answer: the pair expansion (`_expand`) is
+    a second program, sized from `shard_total` once the host has read
+    it.
 
     `r_gid` maps a right slot to its ORIGINAL global row id (identity
     for co-bucketed sides; the routed ids after an in-program
-    repartition). Returns (li, ri, out_valid [S, cap], shard_total [S],
-    expand_overflow, right_unmatched_gid [S, T] | None, matchable,
-    rights, pos_s)."""
+    repartition). Returns (starts, rights, rstart, pos_s [S, T],
+    shard_total [S], right_unmatched_gid [S, T] | None, un_counts [S] |
+    None, is_left, matchable)."""
     import jax
     import jax.numpy as jnp
 
@@ -1191,25 +1196,6 @@ def _match_expand(l_lanes2d, r_lanes2d, l_null, r_null, l_pad, r_pad,
     counts64 = counts.astype(jnp.int64)
     starts = jnp.cumsum(counts64, axis=1) - counts64  # per-shard excl.
     shard_total = starts[:, -1] + counts64[:, -1]
-    expand_overflow = jnp.maximum(jnp.max(shard_total) - cap, 0)
-
-    # Static-capacity expansion: output slot j of shard s belongs to the
-    # left element whose [starts, starts+counts) window covers j.
-    slots = jnp.arange(cap, dtype=jnp.int64)
-    row = jax.vmap(lambda st: jnp.searchsorted(st, slots,
-                                               side="right"))(starts) - 1
-    row = jnp.clip(row, 0, T - 1).astype(jnp.int32)
-    offset = (slots[None, :] - take(starts, row, axis=1)).astype(jnp.int32)
-    l_slot = take(pos_s, row, axis=1)
-    li = l_slot.astype(jnp.int64) \
-        + (jnp.arange(S, dtype=jnp.int64) * Cl)[:, None]
-    matched = offset < take(rights, row, axis=1)
-    r_sorted = jnp.clip(take(rstart, row, axis=1) + offset, 0, T - 1)
-    r_slot = take(pos_s, r_sorted, axis=1) - Cl
-    ri = jnp.where(matched,
-                   take(r_gid, jnp.clip(r_slot, 0, Cr - 1), axis=1),
-                   jnp.int64(-1))
-    out_valid = slots[None, :] < jnp.minimum(shard_total, cap)[:, None]
 
     un_gid_sorted = un_counts = None
     if need_right:
@@ -1229,8 +1215,40 @@ def _match_expand(l_lanes2d, r_lanes2d, l_null, r_null, l_pad, r_pad,
             num_keys=1, is_stable=True, dimension=1)
         un_gid_sorted = un_sorted[1]
         un_counts = jnp.sum(un_gid >= 0, axis=1)
-    return (li, ri, out_valid, shard_total, expand_overflow,
-            un_gid_sorted, un_counts, is_left, matchable, rights, pos_s)
+    return (starts, rights, rstart, pos_s, shard_total, un_gid_sorted,
+            un_counts, is_left, matchable)
+
+
+def _expand(starts, rights, rstart, pos_s, r_gid, Cl: int, cap: int):
+    """The pair expansion over [S, cap] output slots, from the match
+    state: output slot j of shard s belongs to the left element whose
+    [starts, starts + counts) window covers j. `cap` is at or above
+    every shard's total (`_expand_rung` of the largest), so each
+    shard's pairs are the contiguous prefix of its row and nothing can
+    overflow. Returns (li, ri) [S, cap] int64: indices into the flat
+    padded left space and ORIGINAL right row ids (-1: no match, a left
+    outer row)."""
+    import jax
+    import jax.numpy as jnp
+
+    S, T = starts.shape
+    Cr = r_gid.shape[1]
+    take = jnp.take_along_axis
+    slots = jnp.arange(cap, dtype=jnp.int64)
+    row = jax.vmap(lambda st: jnp.searchsorted(st, slots,
+                                               side="right"))(starts) - 1
+    row = jnp.clip(row, 0, T - 1).astype(jnp.int32)
+    offset = (slots[None, :] - take(starts, row, axis=1)).astype(jnp.int32)
+    l_slot = take(pos_s, row, axis=1)
+    li = l_slot.astype(jnp.int64) \
+        + (jnp.arange(S, dtype=jnp.int64) * Cl)[:, None]
+    matched = offset < take(rights, row, axis=1)
+    r_sorted = jnp.clip(take(rstart, row, axis=1) + offset, 0, T - 1)
+    r_slot = take(pos_s, r_sorted, axis=1) - Cl
+    ri = jnp.where(matched,
+                   take(r_gid, jnp.clip(r_slot, 0, Cr - 1), axis=1),
+                   jnp.int64(-1))
+    return li, ri
 
 
 # Per-device dispatch serialization on EMULATED meshes: the CPU
@@ -1310,23 +1328,25 @@ def _cached_program(key: tuple, builder):
     return prog
 
 
-def _join_program(mesh, n_keys: int, Cl: int, Cr: int, cap: int,
-                  left_outer: bool, need_right: bool,
-                  repartition_to: Optional[int], route_capacity: int,
-                  membership: Optional[str] = None,
-                  remap_idx: Tuple[int, ...] = ()):
-    """Compile THE join as one jitted SPMD program: (optional) in-program
-    ICI repartition of the right side, lane decomposition, counting
-    match, static-capacity expansion, per-shard output compaction. All
-    shape parameters are static; the only host readback after dispatch
-    is the small per-shard count vector + overflow scalars, fetched in
-    ONE sync — every device-side output the host then gathers is a
-    contiguous per-shard prefix, so no data-dependent shape ever forces
-    an eager recompile on the sharded arrays.
+def _match_program(mesh, n_keys: int, Cl: int, Cr: int,
+                   left_outer: bool, need_right: bool,
+                   repartition_to: Optional[int], route_capacity: int,
+                   membership: Optional[str] = None,
+                   remap_idx: Tuple[int, ...] = ()):
+    """Compile the join's MATCH as one jitted SPMD program: (optional)
+    in-program ICI repartition of the right side, lane decomposition,
+    counting match. All shape parameters are static and none depends on
+    the answer; the program returns the match state device-resident
+    (starts, rights, rstart, pos_s [S, T], the right slots' original
+    row ids [S, Cr']), the compacted unmatched-right ids, and the small
+    vectors the host reads in ONE sync (per-shard totals, unmatched
+    counts, the route-overflow scalar). The pair expansion is
+    `_expand_program`, sized from those totals.
 
-    `membership`: None (pair expansion) or "semi"/"anti" — membership
-    reads the match-phase masks and compacts hit LEFT indices per shard
-    in-program instead of expanding pairs.
+    `membership`: None (pairs) or "semi"/"anti" — membership reads the
+    match-phase masks and compacts hit LEFT indices per shard
+    in-program; it returns (hits, hit_counts, route_overflow) and never
+    meets an expansion.
 
     `remap_idx` marks the STRING key positions: those keys arrive as
     int32 code lanes plus per-side rank-remap tables
@@ -1344,11 +1364,12 @@ def _join_program(mesh, n_keys: int, Cl: int, Cr: int, cap: int,
     S = total_shards(mesh)
 
     def build():
-        # Named for the trace (the device's program is `jit_spmd_join`);
-        # every op of it under the device scope `hs.mesh.join`.
+        # Named for the trace (the device's program is
+        # `jit_spmd_join_match`); every op of it under the device scope
+        # `hs.mesh.join`.
         @device_scoped("hs.mesh.join")
-        def spmd_join(l_datas, l_ok, l_valid, r_datas, r_ok, r_valid,
-                      l_remaps, r_remaps, r_hash_tables):
+        def spmd_join_match(l_datas, l_ok, l_valid, r_datas, r_ok,
+                            r_valid, l_remaps, r_remaps, r_hash_tables):
             l_d = list(l_datas)
             r_d = list(r_datas)
             r_hash_sub = {}
@@ -1389,11 +1410,10 @@ def _join_program(mesh, n_keys: int, Cl: int, Cr: int, cap: int,
             r_null2d = r_null_f.reshape(S, Cr_eff) & ~r_pad
             r_gid2d = r_gid_f.reshape(S, Cr_eff)
 
-            (li, ri, _out_valid, shard_total, expand_ovf, un_gid,
-             un_counts, is_left, matchable, rights, pos_s) = \
-                _match_expand(l_lanes, r_lanes2d, l_null, r_null2d,
-                              l_pad, r_pad, r_gid2d, cap, left_outer,
-                              need_right)
+            (starts, rights, rstart, pos_s, shard_total, un_gid,
+             un_counts, is_left, matchable) = _match(
+                l_lanes, r_lanes2d, l_null, r_null2d, l_pad, r_pad,
+                r_gid2d, left_outer, need_right)
             if membership is not None:
                 # Semi/anti over the match masks: per-shard in-program
                 # compaction (hits first), host gathers the prefixes.
@@ -1406,18 +1426,47 @@ def _join_program(mesh, n_keys: int, Cl: int, Cr: int, cap: int,
                     is_stable=True, dimension=1)
                 hit_counts = jnp.sum(hit, axis=1)
                 return hit_sorted[1], hit_counts, route_ovf
-            counts = jnp.minimum(shard_total, cap)
             if un_counts is None:
                 un_gid = jnp.zeros((S, 1), dtype=jnp.int64)
                 un_counts = jnp.zeros(S, dtype=jnp.int64)
-            return (li, ri, counts, un_gid, un_counts, expand_ovf,
-                    route_ovf)
+            # The state stays where the match made it, a row a shard
+            # (left alone, GSPMD replicates the co-bucketed side's
+            # identity `r_gid2d` on every chip).
+            state = tuple(
+                jax.lax.with_sharding_constraint(x, shard_rows(mesh))
+                for x in (starts, rights, rstart, pos_s, r_gid2d))
+            return state, un_gid, (shard_total, un_counts, route_ovf)
 
-        return instrumented_jit("mesh.spmd_join", spmd_join)
+        return instrumented_jit("mesh.spmd_join_match", spmd_join_match)
 
-    key = ("join", mesh, n_keys, Cl, Cr, cap, left_outer, need_right,
+    key = ("join_match", mesh, n_keys, Cl, Cr, left_outer, need_right,
            repartition_to, route_capacity, membership, remap_idx)
     return _cached_program(key, build)
+
+
+# The expansion's ladder: the per-shard output slots are the smallest
+# power of two (from 16) at or above the largest per-shard total the
+# match found, so answers of nearby sizes share one compiled program
+# and between half and all of the slots hold a pair.
+_expand_rung = next_pow2
+
+
+def _expand_program(Cl: int, cap: int):
+    """Compile the join's pair EXPANSION as one jitted SPMD program over
+    `cap` output slots a shard (a rung of the ladder, part of the key):
+    match state in, (li, ri) [S, cap] out, nothing read back. The mesh
+    and the row sharding are the state's own."""
+    from hyperspace_tpu.telemetry import device_scoped, instrumented_jit
+
+    def build():
+        # `jit_spmd_join_expand` in a trace, under the match's scope.
+        @device_scoped("hs.mesh.join")
+        def spmd_join_expand(starts, rights, rstart, pos_s, r_gid):
+            return _expand(starts, rights, rstart, pos_s, r_gid, Cl, cap)
+
+        return instrumented_jit("mesh.spmd_join_expand", spmd_join_expand)
+
+    return _cached_program(("join_expand", Cl, cap), build)
 
 
 def _prefix_index(counts, width: int) -> np.ndarray:
@@ -1467,40 +1516,6 @@ def _gather_prefixes(arrays, counts, width: int, as_int32: bool = False):
     return fn(tuple(arrays), idx)
 
 
-# Working-capacity memo: a warm repeat of the same join shape starts at
-# the capacity that last succeeded instead of re-discovering it through
-# the overflow-retry ladder (each failed attempt is a full dispatch).
-_CAP_MEMO: Dict[tuple, int] = {}
-
-
-def _join_capacity(left: ShardedBatch, right: ShardedBatch,
-                   left_outer: bool, factor: float,
-                   memo_key: Optional[tuple] = None) -> int:
-    """First-attempt static per-shard output capacity. When both sides'
-    per-bucket histograms are known, the EXACT per-shard upper bound
-    (sum of l_b*r_b [+ l_b for outer] over the shard's bucket range)
-    clips the heuristic — an expansion at the bound can never overflow,
-    so the doubling retry loop terminates — and a bound within 4x of
-    the heuristic is taken OUTRIGHT (one guaranteed-fit dispatch beats
-    a maybe-retry at modest extra slots)."""
-    if memo_key is not None and memo_key in _CAP_MEMO:
-        return _CAP_MEMO[memo_key]
-    heur = max(16, int(factor * (left.rows_per_shard
-                                 + right.rows_per_shard)))
-    if left.lengths is None or right.lengths is None \
-            or len(left.lengths) != len(right.lengths):
-        return heur
-    ll = left.lengths.astype(np.int64)
-    rl = right.lengths.astype(np.int64)
-    per_bucket = ll * rl + (ll if left_outer else 0)
-    bound = max(int(per_bucket[lo:hi].sum())
-                for lo, hi in bucket_ranges(len(ll), left.n_shards))
-    bound = max(bound, 1)
-    if bound <= 4 * heur:
-        return max(16, bound)
-    return max(16, min(heur, bound))
-
-
 def _route_cap(right: ShardedBatch) -> int:
     """First-attempt per-peer slab capacity for the in-program
     repartition (the build's `_stage_capacity` sizing)."""
@@ -1548,15 +1563,17 @@ def _repartition_target(left: ShardedBatch, right: ShardedBatch):
 def sharded_join_indices(left: ShardedBatch, right: ShardedBatch,
                          left_keys: Sequence[str],
                          right_keys: Sequence[str],
-                         how: str = "inner",
-                         capacity_factor: Optional[float] = None,
-                         conf=None):
-    """Join-pair indices over two born-sharded sides as ONE jitted SPMD
-    program per attempt (static capacity, on-device overflow detection,
-    in-program ICI repartition on bucket-count mismatch). Returns
-    (li, ri) device int32 arrays indexing the FLAT padded row spaces of
-    the two sides. `how`: inner / left_outer / full_outer (callers swap
-    sides for right_outer)."""
+                         how: str = "inner", conf=None):
+    """Join-pair indices over two born-sharded sides as TWO jitted SPMD
+    programs with one host readback between them: the match
+    (`_match_program`: in-program ICI repartition on bucket-count
+    mismatch, counting match), ONE read of the per-shard totals, and
+    the expansion (`_expand_program`) over the ladder's rung just above
+    the largest total — sized by the answer, so it cannot overflow.
+    Only a route overflow of the repartition re-runs the match.
+    Returns (li, ri) device int32 arrays indexing the FLAT padded row
+    spaces of the two sides. `how`: inner / left_outer / full_outer
+    (callers swap sides for right_outer)."""
     import time as _time
 
     import jax
@@ -1584,12 +1601,6 @@ def sharded_join_indices(left: ShardedBatch, right: ShardedBatch,
     remap_idx, l_remaps, r_remaps, r_hashes = _string_key_plan(
         left, right, left_keys, right_keys,
         need_hashes=repartition_to is not None, conf=conf)
-    factor = (capacity_factor if capacity_factor is not None
-              else CAPACITY_FACTOR)
-    memo_key = ("cap", mesh, left.rows_per_shard, right.rows_per_shard,
-                tuple(left_keys), tuple(right_keys), how)
-    cap = _join_capacity(left, right, left_outer, factor,
-                         memo_key=memo_key)
 
     reg = telemetry.get_registry()
     tracer = telemetry.tracer()
@@ -1598,43 +1609,44 @@ def sharded_join_indices(left: ShardedBatch, right: ShardedBatch,
     with _dispatch_guard(mesh):
         while True:
             attempt += 1
-            program = _join_program(mesh, len(left_keys),
-                                    left.rows_per_shard,
-                                    right.rows_per_shard, cap, left_outer,
-                                    need_right, repartition_to,
-                                    route_capacity, remap_idx=remap_idx)
+            match = _match_program(mesh, len(left_keys),
+                                   left.rows_per_shard,
+                                   right.rows_per_shard, left_outer,
+                                   need_right, repartition_to,
+                                   route_capacity, remap_idx=remap_idx)
             if repartition_to is not None:
                 # Slab-volume attribution of this attempt's in-program
                 # exchange, split by the link that carries each hop.
                 _record_repartition_bytes(
                     mesh, route_capacity, 8 * len(right_keys) + 10)
             with telemetry.span("hs.mesh.join.spmd", "mesh", how=how,
-                                shards=S, cap=cap):
-                (li, ri, counts_d, un_gid, un_counts_d, expand_ovf,
-                 route_ovf) = program(*l_in, *r_in, l_remaps, r_remaps,
-                                      r_hashes)
+                                shards=S) as join_span:
+                state, un_gid, small = match(*l_in, *r_in, l_remaps,
+                                             r_remaps, r_hashes)
                 t0 = _time.perf_counter()
-                # THE one host readback per attempt: the tiny per-shard
-                # count vectors + overflow scalars together, after
-                # everything (match AND expansion AND compaction) has
-                # dispatched — not a sizing sync in the middle.
-                with telemetry.span("hs.mesh.join.sync", "mesh", cap=cap,
+                # THE one host readback of the join: the per-shard
+                # totals that size the expansion, the unmatched-right
+                # counts and the route-overflow scalar together. The
+                # expansion is dispatched after it and read by nobody:
+                # the prefix gather below already has its counts.
+                with telemetry.span("hs.mesh.join.sync", "mesh",
                                     attempt=attempt):
-                    counts, un_counts, e_ovf, r_ovf = jax.device_get(
-                        (counts_d, un_counts_d, expand_ovf, route_ovf))
+                    counts, un_counts, r_ovf = jax.device_get(small)
                 sync_s = _time.perf_counter() - t0
-            reg.counter("mesh.join.sync_s").inc(sync_s)
-            telemetry.add_seconds("mesh.sync_s", sync_s)
-            if int(e_ovf) == 0 and int(r_ovf) == 0:
-                if len(_CAP_MEMO) > 256:
-                    _CAP_MEMO.clear()
-                _CAP_MEMO[memo_key] = cap
-                break
+                reg.counter("mesh.join.sync_s").inc(sync_s)
+                telemetry.add_seconds("mesh.sync_s", sync_s)
+                if int(r_ovf) == 0:
+                    pairs = int(np.max(counts))
+                    cap = _expand_rung(pairs) if pairs else 0
+                    join_span.set(cap=cap, pairs=pairs)
+                    if pairs:
+                        reg.histogram("mesh.spmd.expand_fill").observe(
+                            pairs / cap)
+                        li, ri = _expand_program(left.rows_per_shard,
+                                                 cap)(*state)
+                    break
             reg.counter("mesh.spmd.overflow_retries").inc()
-            if int(e_ovf):
-                cap *= 2
-            if int(r_ovf):
-                route_capacity *= 2
+            route_capacity *= 2
 
         total = int(np.asarray(counts).sum())
         extra = int(np.asarray(un_counts).sum()) if need_right else 0
@@ -1674,10 +1686,11 @@ def sharded_semi_anti_indices(left: ShardedBatch, right: ShardedBatch,
                               right_keys: Sequence[str],
                               anti: bool = False, conf=None):
     """LEFT SEMI / LEFT ANTI membership over born-sharded sides through
-    the same single program (anti emits null-key left rows — NOT EXISTS
-    semantics). Membership reads the match-phase masks; the expansion's
-    capacity is irrelevant, so only a repartition-route overflow can
-    force a retry. Returns indices into the left flat padded space."""
+    the match program alone (anti emits null-key left rows — NOT EXISTS
+    semantics). Membership reads the match-phase masks and compacts its
+    hits in that program; no expansion is compiled or run, and only a
+    repartition-route overflow can force a retry. Returns indices into
+    the left flat padded space."""
     import jax
     import jax.numpy as jnp
 
@@ -1694,14 +1707,14 @@ def sharded_semi_anti_indices(left: ShardedBatch, right: ShardedBatch,
     reg = telemetry.get_registry()
     with _dispatch_guard(mesh):
         while True:
-            program = _join_program(mesh, len(left_keys),
-                                    left.rows_per_shard,
-                                    right.rows_per_shard, 16,
-                                    left_outer=True, need_right=False,
-                                    repartition_to=repartition_to,
-                                    route_capacity=route_capacity,
-                                    membership="anti" if anti else "semi",
-                                    remap_idx=remap_idx)
+            program = _match_program(mesh, len(left_keys),
+                                     left.rows_per_shard,
+                                     right.rows_per_shard,
+                                     left_outer=True, need_right=False,
+                                     repartition_to=repartition_to,
+                                     route_capacity=route_capacity,
+                                     membership="anti" if anti else "semi",
+                                     remap_idx=remap_idx)
             if repartition_to is not None:
                 _record_repartition_bytes(
                     mesh, route_capacity, 8 * len(right_keys) + 10)

@@ -109,10 +109,14 @@ SPAN_NAMES = {
     "hs.mesh.filter": "the distributed / SPMD filter",
     "hs.mesh.aggregate": "the distributed aggregate",
     "hs.mesh.build.dispatch": "the mesh build step's dispatch",
-    "hs.mesh.join.spmd": "the SPMD join program: one attempt's dispatch "
-                         "and readback (how, shards, cap)",
-    "hs.mesh.join.sync": "the host blocked on that attempt's one "
-                         "readback (cap, attempt)",
+    "hs.mesh.join.spmd": "one attempt of the SPMD join: the match's "
+                         "dispatch, the one readback and, where no "
+                         "route overflowed, the expansion's dispatch "
+                         "(how, shards; then pairs: the fullest shard's "
+                         "total, and cap: the expansion's rung, 0 with "
+                         "no pair)",
+    "hs.mesh.join.sync": "the host blocked on the join's one readback, "
+                         "the match's per-shard totals (attempt)",
     # ring only (recognised in hindsight, `completed`)
     "hs.compile.<name>": "a dispatch of jit entry point <name> that "
                          "traced + compiled",
@@ -133,8 +137,9 @@ DEVICE_SCOPES = {
     "hs.join.expand": "the counting join's expansion to row pairs",
     # mesh: the three SPMD programs, on every chip's plane
     "hs.mesh.filter": "the SPMD predicate mask (`jit_spmd_filter`)",
-    "hs.mesh.join": "the SPMD join: match + static-capacity expansion "
-                    "(`jit_spmd_join`)",
+    "hs.mesh.join": "the SPMD join's two programs: the match "
+                    "(`jit_spmd_join_match`) and the expansion sized by "
+                    "its totals (`jit_spmd_join_expand`)",
     "hs.mesh.aggregate": "the per-shard partial aggregation "
                          "(`jit_aggregate_step`)",
 }

@@ -145,10 +145,13 @@ def test_spmd_semi_anti_matches_pandas(mesh):
         assert np.isin(keys, list(rset)).all() != anti or exp == 0
 
 
-def test_spmd_join_hot_bucket_overflow_retry(mesh):
+def test_spmd_join_hot_bucket_is_sized_in_one_attempt(mesh):
     """A hot key concentrating most rows in ONE bucket must still join
-    exactly: the static-capacity expansion overflows and the doubling
-    retry recovers every pair (nothing silently truncated)."""
+    exactly: the expansion's slots are sized by that shard's total once
+    the match has found it, in one match dispatch (no doubling, no
+    re-run), and the fill lands in (1/2, 1]."""
+    from hyperspace_tpu import telemetry
+
     n = 1200
     hot = np.full(n - 100, 7, dtype=np.int64)
     rest = np.arange(100, dtype=np.int64) + 100
@@ -159,15 +162,21 @@ def test_spmd_join_hot_bucket_overflow_retry(mesh):
         "k": np.asarray([7, 7, 120, 150], dtype=np.int64),
         "w": np.arange(4, dtype=np.float64)}))
     lsh, rsh, lb, rb = _sharded_pair(mesh, left, right)
-    spmd._CAP_MEMO.clear()
-    li, ri = spmd.sharded_join_indices(lsh, rsh, ["k"], ["k"],
-                                       capacity_factor=0.01)
+    reg = telemetry.get_registry()
+    fill = reg.histogram("mesh.spmd.expand_fill")
+    retries = reg.counters_dict().get("mesh.spmd.overflow_retries", 0)
+    observed, filled = fill.count, fill.sum
+    li, ri = spmd.sharded_join_indices(lsh, rsh, ["k"], ["k"])
+    assert reg.counters_dict().get("mesh.spmd.overflow_retries",
+                                   0) == retries
     lk = np.asarray(lsh.batch.column("k").data)[np.asarray(li)]
     rk = np.asarray(rsh.batch.column("k").data)[np.asarray(ri)]
     assert (lk == rk).all()
     # hot key expands (n-100)*2; the two singles match once each
     assert len(np.asarray(li)) == (n - 100) * 2 + 2
-    spmd._CAP_MEMO.clear()
+    assert fill.count == observed + 1
+    # the hot shard holds 2,200 pairs or a few more: the 4,096 rung
+    assert 2200 / 4096 <= fill.sum - filled < 2208 / 4096
 
 
 def test_spmd_join_memory_is_sharded(mesh):

@@ -272,7 +272,12 @@ def test_the_mesh_spans_are_in_the_table_and_emitted(lake):
     (sync,) = by_name["hs.mesh.join.sync"]
     (join,) = by_name["hs.mesh.join.spmd"]
     assert sync["args"]["attempt"] == 1
-    assert sync["args"]["cap"] == join["args"]["cap"]
+    # sized by what the match found: the rung just above the fullest
+    # shard's pairs, not the inputs' rows
+    pairs, cap = join["args"]["pairs"], join["args"]["cap"]
+    kept = survivors(lake, ("MAIL", "SHIP"), 1994).sum()
+    assert kept / lake["devices"] <= pairs <= kept
+    assert pairs <= cap < max(2 * pairs, 32) and cap & (cap - 1) == 0
     # a child: inside the join's span, on its thread
     assert sync["tid"] == join["tid"]
     assert join["ts"] <= sync["ts"] and \
@@ -281,7 +286,7 @@ def test_the_mesh_spans_are_in_the_table_and_emitted(lake):
 
 @pytest.fixture
 def recorded_programs(monkeypatch):
-    """The three SPMD programs of one Q12 (they are kept in one table,
+    """The four SPMD programs of one Q12 (they are kept in one table,
     `spmd._cached_program`), with the arguments they were called with."""
     from hyperspace_tpu.parallel import spmd
 
@@ -302,7 +307,8 @@ def recorded_programs(monkeypatch):
 
 @pytest.mark.parametrize("kind,scope,program", [
     ("filter", "hs.mesh.filter", "spmd_filter"),
-    ("join", "hs.mesh.join", "spmd_join"),
+    ("join_match", "hs.mesh.join", "spmd_join_match"),
+    ("join_expand", "hs.mesh.join", "spmd_join_expand"),
     ("aggregate", "hs.mesh.aggregate", "aggregate_step")])
 def test_the_spmd_programs_are_named_and_scoped(lake, recorded_programs,
                                                 kind, scope, program):

@@ -1,6 +1,6 @@
 """Born-sharded SPMD execution (`parallel/spmd.py`): bit-identity with
 the single-device operators at 1/2/4/8 virtual devices, the in-program
-mismatched-bucket repartition, static-capacity overflow recovery, the
+mismatched-bucket repartition, the expansion sized by its match, the
 per-device segment-cache read path, and the device-resident stage-flow
 telemetry contract (zero D2H between stages of a warm two-stage SMJ)."""
 
@@ -164,10 +164,9 @@ def test_mismatched_bucket_counts_repartition_in_program():
     assert len(idx) == int((~member).sum())
 
 
-def test_skewed_overflow_retries_exactly():
-    """A hot key whose match expansion blows past the first-attempt
-    static capacity must be recovered EXACTLY by the on-device overflow
-    detection + doubled retry — never silently truncated."""
+def skewed_pair():
+    """A hot key (70% of the left, half of the right) on one shard: the
+    answer is larger than both inputs together."""
     mesh = make_mesh(4)
     n = 2000
     rng = np.random.default_rng(9)
@@ -180,20 +179,197 @@ def test_skewed_overflow_retries_exactly():
         "v": rng.random(300)}))
     lb, ll = distributed_build(left, ["k"], 16, mesh)
     rb, rl = distributed_build(right, ["k"], 16, mesh)
+    return (spmd.shard_bucket_ordered(lb, ll, mesh),
+            spmd.shard_bucket_ordered(rb, rl, mesh), lb, rb)
+
+
+def counter(name):
+    return telemetry.get_registry().counters_dict().get(name, 0)
+
+
+def fill_state():
+    h = telemetry.get_registry().histogram("mesh.spmd.expand_fill")
+    return h.count, h.sum
+
+
+def join_spans(fn):
+    """`fn()` under the ring tracer: (its result, the `hs.mesh.join.*`
+    spans it emitted)."""
+    telemetry.enable_tracing()
+    try:
+        out = fn()
+        events = [e for e in telemetry.tracer().events
+                  if e["name"].startswith("hs.mesh.join.")]
+    finally:
+        telemetry.disable_tracing()
+    return out, events
+
+
+def test_skewed_join_is_sized_by_its_answer():
+    """A hot key whose pairs outnumber the input rows: ONE match
+    dispatch, one read, and an expansion over the rung just above the
+    fullest shard's total — exact, with nothing doubled and re-run."""
+    lsh, rsh, lb, rb = skewed_pair()
+    retries = counter("mesh.spmd.overflow_retries")
+    fills = fill_state()
+    (li, ri), events = join_spans(
+        lambda: spmd.sharded_join_indices(lsh, rsh, ["k"], ["k"]))
+    assert counter("mesh.spmd.overflow_retries") == retries
+    got = pairs_frame(lsh, rsh, li, ri)
+    want = oracle_frame(lb, rb, "inner")
+    pd.testing.assert_frame_equal(got, want)
+    (sync,) = [e for e in events if e["name"] == "hs.mesh.join.sync"]
+    (join,) = [e for e in events if e["name"] == "hs.mesh.join.spmd"]
+    assert sync["args"]["attempt"] == 1
+    cap, pairs = join["args"]["cap"], join["args"]["pairs"]
+    hot_pairs = int((want.lk == 7).sum())
+    assert hot_pairs > lsh.rows_per_shard + rsh.rows_per_shard
+    assert hot_pairs <= pairs <= len(want)
+    assert pairs <= cap < 2 * pairs and cap & (cap - 1) == 0
+    count, total = fill_state()
+    assert count == fills[0] + 1
+    assert total - fills[1] == pytest.approx(pairs / cap)
+    assert 0.5 < pairs / cap <= 1
+
+
+@pytest.mark.parametrize("pairs,rung", [
+    (1, 16), (16, 16), (17, 32), (23438, 32768), (32768, 32768),
+    (32769, 65536), (3_000_000_000, 1 << 32)])
+def test_the_expansions_ladder(pairs, rung):
+    assert spmd._expand_rung(pairs) == rung
+
+
+@pytest.mark.parametrize("how", ["inner", "full_outer"])
+def test_a_join_with_no_pair_runs_no_expansion(how, monkeypatch):
+    mesh = make_mesh(4)
+    left = columnar.from_arrow(pa.table({
+        "k": np.arange(0, 400, 2, dtype=np.int64), "v": np.zeros(200)}))
+    right = columnar.from_arrow(pa.table({
+        "k": np.arange(1, 241, 2, dtype=np.int64), "v": np.zeros(120)}))
+    lb, ll = distributed_build(left, ["k"], 16, mesh)
+    rb, rl = distributed_build(right, ["k"], 16, mesh)
     lsh = spmd.shard_bucket_ordered(lb, ll, mesh)
     rsh = spmd.shard_bucket_ordered(rb, rl, mesh)
-    spmd._CAP_MEMO.clear()
-    before = telemetry.get_registry().counters_dict().get(
-        "mesh.spmd.overflow_retries", 0)
-    # Tiny first-attempt capacity forces the overflow path.
-    li, ri = spmd.sharded_join_indices(lsh, rsh, ["k"], ["k"],
-                                       capacity_factor=0.01)
-    after = telemetry.get_registry().counters_dict().get(
-        "mesh.spmd.overflow_retries", 0)
-    assert after > before, "overflow retry never fired"
+    if how == "inner":
+        monkeypatch.setattr(spmd, "_expand_program", None)  # never asked
+    fills = fill_state()
+    li, ri = spmd.sharded_join_indices(lsh, rsh, ["k"], ["k"], how=how)
     got = pairs_frame(lsh, rsh, li, ri)
-    pd.testing.assert_frame_equal(got, oracle_frame(lb, rb, "inner"))
-    spmd._CAP_MEMO.clear()
+    pd.testing.assert_frame_equal(got, oracle_frame(lb, rb, how))
+    assert len(got) == (0 if how == "inner" else 320)
+    if how == "inner":
+        assert fill_state() == fills
+
+
+def test_full_outer_keeps_the_unmatched_rights_after_the_pairs():
+    mesh, lsh, rsh, lb, rb, _ll, _rl = sharded_pair(n=900, m=700,
+                                                    keyspace=400)
+    li, ri = spmd.sharded_join_indices(lsh, rsh, ["k"], ["k"],
+                                       how="full_outer")
+    got = pairs_frame(lsh, rsh, li, ri)
+    pd.testing.assert_frame_equal(got, oracle_frame(lb, rb, "full_outer"))
+    li = np.asarray(li)
+    unmatched = int((li < 0).sum())
+    assert unmatched > 0 and (li[-unmatched:] < 0).all() \
+        and (li[:-unmatched] >= 0).all()
+
+
+def test_a_route_overflow_still_retries_the_match_exactly():
+    """The right side re-buckets in-program and its hot key sends more
+    rows to one peer than a route slab holds: the match is re-run with
+    doubled slabs (the one retry left), the expansion runs once."""
+    mesh = make_mesh(4)
+    rng = np.random.default_rng(21)
+    left = columnar.from_arrow(pa.table({
+        "k": rng.integers(0, 64, 600).astype(np.int64),
+        "v": rng.random(600)}))
+    right = columnar.from_arrow(pa.table({
+        "k": np.where(rng.random(400) < 0.7, 7,
+                      rng.integers(0, 64, 400)).astype(np.int64),
+        "v": rng.random(400)}))
+    lb, ll = distributed_build(left, ["k"], 16, mesh)
+    rb, rl = distributed_build(right, ["k"], 8, mesh)
+    lsh = spmd.shard_bucket_ordered(lb, ll, mesh)
+    rsh = spmd.shard_bucket_ordered(rb, rl, mesh)
+    retries = counter("mesh.spmd.overflow_retries")
+    fills = fill_state()
+    (li, ri), events = join_spans(
+        lambda: spmd.sharded_join_indices(lsh, rsh, ["k"], ["k"],
+                                          how="left_outer"))
+    assert counter("mesh.spmd.overflow_retries") > retries
+    pd.testing.assert_frame_equal(pairs_frame(lsh, rsh, li, ri),
+                                  oracle_frame(lb, rb, "left_outer"))
+    syncs = [e["args"]["attempt"] for e in events
+             if e["name"] == "hs.mesh.join.sync"]
+    assert syncs == list(range(1, len(syncs) + 1)) and len(syncs) > 1
+    sized = [e["args"] for e in events
+             if e["name"] == "hs.mesh.join.spmd" and "cap" in e["args"]]
+    assert len(sized) == 1 and fill_state()[0] == fills[0] + 1
+    before = counter("mesh.spmd.overflow_retries")
+    idx = np.asarray(spmd.sharded_semi_anti_indices(
+        lsh, rsh, ["k"], ["k"], anti=False))
+    assert counter("mesh.spmd.overflow_retries") > before
+    lk = np.asarray(lb.column("k").data)
+    assert len(idx) == int(np.isin(
+        lk, np.asarray(rb.column("k").data)).sum())
+
+
+def test_answers_on_one_rung_share_one_compiled_expansion():
+    """Two joins over the same layouts whose totals differ but fall on
+    the same rung: the second traces neither a match nor an expansion."""
+    mesh = make_mesh(4)
+    rng = np.random.default_rng(33)
+    k = rng.integers(0, 150, 1200).astype(np.int64)
+    k2 = np.where(rng.random(1200) < 0.1, -1, k)  # a tenth match nothing
+    left = columnar.from_arrow(pa.table({"k": k, "k2": k2}))
+    right = make_batch(500, seed=34, keyspace=150)
+    lb, ll = distributed_build(left, ["k"], 16, mesh)
+    rb, rl = distributed_build(right, ["k"], 16, mesh)
+    lsh = spmd.shard_bucket_ordered(lb, ll, mesh)
+    rsh = spmd.shard_bucket_ordered(rb, rl, mesh)
+
+    def run(key):
+        (li, ri), events = join_spans(
+            lambda: spmd.sharded_join_indices(lsh, rsh, [key], ["k"]))
+        lk = np.asarray(lsh.batch.column(key).data)[np.asarray(li)]
+        rk = np.asarray(rsh.batch.column("k").data)[np.asarray(ri)]
+        assert (lk == rk).all()
+        want = pd.DataFrame({"k": np.asarray(lb.column(key).data)}).merge(
+            pd.DataFrame({"k": np.asarray(rb.column("k").data)}), on="k")
+        assert len(lk) == len(want)
+        (join,) = [e["args"] for e in events
+                   if e["name"] == "hs.mesh.join.spmd"]
+        return join["cap"], join["pairs"]
+
+    names = ("compile.traces", "compile.mesh.spmd_join_match.traces",
+             "compile.mesh.spmd_join_expand.traces",
+             "compile.mesh.spmd_gather_i32.traces")
+    cap, pairs = run("k")
+    before = [counter(n) for n in names]
+    cap2, pairs2 = run("k2")
+    assert cap2 == cap and pairs2 < pairs
+    traced, match, expand, gather = (
+        counter(n) - b for n, b in zip(names, before))
+    # the prefix gather alone is compiled per exact pair count, as ever
+    assert (match, expand) == (0, 0) and traced == gather
+
+
+@pytest.mark.parametrize("anti", [False, True])
+def test_semi_anti_compile_no_expansion(anti, monkeypatch):
+    mesh, lsh, rsh, lb, rb, _ll, _rl = sharded_pair(n=700, m=90,
+                                                    keyspace=300)
+    monkeypatch.setattr(spmd, "_expand_program", None)  # never asked
+    fills = fill_state()
+    expands = counter("compile.mesh.spmd_join_expand.traces")
+    idx = np.asarray(spmd.sharded_semi_anti_indices(
+        lsh, rsh, ["k"], ["k"], anti=anti))
+    lk = np.asarray(lb.column("k").data)
+    member = np.isin(lk, np.asarray(rb.column("k").data))
+    assert len(idx) == int((~member if anti else member).sum())
+    got = np.asarray(lsh.batch.column("k").data)[idx]
+    assert sorted(got) == sorted(lk[~member if anti else member])
+    assert fill_state() == fills
+    assert counter("compile.mesh.spmd_join_expand.traces") == expands
 
 
 def test_pad_blowup_guard():

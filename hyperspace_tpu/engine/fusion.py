@@ -829,6 +829,16 @@ class FusedStageExec(PhysicalNode):
                         trigger="device-resident sources")
         schema, reduced_schema, aux, lazy_specs = meta
         base = tree_to_batch(out_tree, reduced_schema, aux)
+        for n in self._bhj_nodes:
+            # the joins this stage ran as direct-address probes inside
+            # its one program (the eager operator says the same of
+            # itself on its record: `BroadcastHashJoinExec.execute`)
+            build = n.right if n.build_side == "right" else n.left
+            telemetry.event(
+                "join", "broadcast", path="fused", lane="device",
+                probe_rows=(int(sel.shape[0]) if sel is not None
+                            else base.num_rows),
+                build_rows=build._batch.num_rows)
         idx = None
         if sel is not None:
             t0 = _time.perf_counter()

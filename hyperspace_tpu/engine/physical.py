@@ -81,8 +81,9 @@ def _instrument(fn, bucketed: bool):
                     rec.finish_operator(op, error=repr(exc))
                 raise
             rows = _batch_rows(out)
-            sp.set(rows=rows,
-                   lane=op.detail.get("lane") if op is not None else None)
+            detail = op.detail if op is not None else {}
+            sp.set(rows=rows, lane=detail.get("lane"),
+                   appended=detail.get("appended"))
             if op is not None:
                 rec.finish_operator(op, rows_out=rows)
         # Operator-span boundary: fold a device-memory sample into the
@@ -229,6 +230,9 @@ class ScanExec(PhysicalNode):
                   "roots": list(self.scan.root_paths)}
         if self.shared_members:
             detail["shared_members"] = self.shared_members
+        if self.scan.appended:
+            # hybrid scan's branch over the files an index lacks
+            detail["appended"] = len(facts.files)
         spec = self.scan.bucket_spec
         if spec is not None:
             detail["buckets_total"] = spec.num_buckets
@@ -424,6 +428,14 @@ class ScanExec(PhysicalNode):
         # batches come through the stamped decoded-batch cache.
         host = bucket is None and facts.rows < self._min_device_rows()
         self._annotate_read(facts, host)
+        if self.scan.appended:
+            # What hybrid scan reads beside the index, per scan: a file
+            # that two branches of one query read counts twice. Bytes
+            # only where `_resolve` had them (an operator record asked).
+            reg = telemetry.get_registry()
+            reg.counter("hybrid.appended_files").inc(len(files))
+            reg.counter("hybrid.appended_bytes").inc(
+                facts.bytes_scanned or 0)
         if host:
             batch = parquet.read_host_batch(files, self.columns,
                                             self.out_schema,
@@ -1426,10 +1438,22 @@ class BroadcastHashJoinExec(PhysicalNode):
 
         lbatch = self.left.execute(bucket)
         rbatch = self.right.execute(bucket)
+        probe, build = ((lbatch, rbatch) if self.build_side == "right"
+                        else (rbatch, lbatch))
+
+        def served(path: str) -> None:
+            # which path served the join, on the operator's record:
+            # the direct-address probe, or the counting join it
+            # declined to (strings, floats, duplicate build keys, ...)
+            telemetry.annotate(
+                lane="host" if probe.is_host else "device", path=path,
+                probe_rows=probe.num_rows, build_rows=build.num_rows)
+
         if self.how in ("left_semi", "left_anti"):
             anti = self.how == "left_anti"
             idx = broadcast_membership(lbatch, rbatch, self.left_keys,
                                        self.right_keys, anti=anti)
+            served("direct-address" if idx is not None else "counting")
             if idx is None:
                 idx = semi_anti_indices(lbatch, rbatch, self.left_keys,
                                         self.right_keys, anti=anti)
@@ -1439,6 +1463,7 @@ class BroadcastHashJoinExec(PhysicalNode):
                                           self.right_keys, self.how)
             if pair is not None:
                 li, ri = pair
+                served("direct-address")
                 return assemble_join_output(lbatch, rbatch, li, ri,
                                             how=self.how,
                                             columns=self.out_columns)
@@ -1448,9 +1473,11 @@ class BroadcastHashJoinExec(PhysicalNode):
                 "left_outer" if self.how == "right_outer" else "inner")
             if pair is not None:
                 ri, li = pair
+                served("direct-address")
                 return assemble_join_output(lbatch, rbatch, li, ri,
                                             how=self.how,
                                             columns=self.out_columns)
+        served("counting")
         return sort_merge_join(lbatch, rbatch, self.left_keys,
                                self.right_keys, how=self.how,
                                columns=self.out_columns)

@@ -1229,6 +1229,12 @@ class QueryScheduler:
                                 if session is not None else df.plan)
                     if plan is not df.plan:
                         self._credit_rewritten(ent, plan, metrics)
+                        if any(getattr(leaf, "appended", False)
+                               for leaf in plan.collect_leaves()):
+                            # served through hybrid scan: an index
+                            # UNION the files appended since its build
+                            telemetry.get_registry().counter(
+                                "hybrid.queries").inc()
                     # Inter-query batched execution (`engine/batcher.py`):
                     # concurrent same-signature point/filter queries
                     # coalesce into one jitted predicate invocation over

@@ -35,6 +35,7 @@ column on either side matches nothing.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
@@ -115,6 +116,54 @@ def build_broadcast_table(build: ColumnBatch, build_keys: Sequence[str]):
     return table, mins, ranges
 
 
+def _lookup(xp, arrays, valid, table, mins, maxs, ranges):
+    """(build_row_or_minus1, matched) per probe row over `xp` (numpy, or
+    jax.numpy inside `_broadcast_probe`)."""
+    n = arrays[0].shape[0]
+    ok = xp.ones(n, dtype=bool) if valid is None else valid
+    idx = xp.zeros(n, dtype=np.int64)
+    for i, a in enumerate(arrays):
+        av = a.astype(np.int64)
+        mn, r = mins[i], ranges[i]
+        # Range-check on the ORIGINAL values (comparisons cannot wrap);
+        # `av - mn` can wrap in int64 for adversarial probe keys near
+        # INT64_MIN against builds near INT64_MAX, and a wrapped digit
+        # must never slip into [0, r) as a false match. `maxs` is the
+        # build max, mn + (r - 1), computed exactly in Python ints.
+        ok = ok & (av >= mn) & (av <= maxs[i])
+        idx = idx * r + xp.clip(av - mn, 0, r - 1)
+    hit = xp.where(ok, xp.take(table, xp.where(ok, idx, 0)),
+                   np.int32(-1)).astype(np.int32)
+    return hit, hit >= 0
+
+
+_probe_jit = None
+
+
+def _device_probe(arrays, valid, table, mins, maxs, ranges):
+    """`_lookup` on the device as ONE program, `jit__broadcast_probe`,
+    its ops under the device scope `hs.join.broadcast` (inside a fused
+    stage the call is inlined into the stage's program and the scope
+    stays on its ops). The packing rides as arrays, not as constants:
+    one compile per probe shape, whatever the build side holds."""
+    global _probe_jit
+    if _probe_jit is None:
+        import jax
+        import jax.numpy as jnp
+
+        from hyperspace_tpu.telemetry import device_scoped
+
+        @jax.jit
+        @device_scoped("hs.join.broadcast")
+        def _broadcast_probe(arrays, valid, table, mins, maxs, ranges):
+            return _lookup(jnp, arrays, valid, table, mins, maxs, ranges)
+
+        _probe_jit = _broadcast_probe
+    return _probe_jit(tuple(arrays), valid, table,
+                      *(np.asarray(v, dtype=np.int64)
+                        for v in (mins, maxs, ranges)))
+
+
 def _probe_lookup(probe: ColumnBatch, probe_keys: Sequence[str], table,
                   mins, ranges):
     """(build_row_or_minus1, matched) per probe row, on the probe's lane.
@@ -123,29 +172,9 @@ def _probe_lookup(probe: ColumnBatch, probe_keys: Sequence[str], table,
     if prep is None:
         return None
     arrays, valid = prep
-    if probe.is_host:
-        xp = np
-        table_x = table
-    else:
-        import jax.numpy as jnp
-        xp = jnp
-        table_x = jnp.asarray(table)
-    n = probe.num_rows
-    ok = xp.ones(n, dtype=bool) if valid is None else xp.asarray(valid)
-    idx = xp.zeros(n, dtype=np.int64)
-    for a, mn, r in zip(arrays, mins, ranges):
-        av = xp.asarray(a).astype(np.int64)
-        # Range-check on the ORIGINAL values (comparisons cannot wrap);
-        # `av - mn` can wrap in int64 for adversarial probe keys near
-        # INT64_MIN against builds near INT64_MAX, and a wrapped digit
-        # must never slip into [0, r) as a false match. mn + (r - 1) is
-        # the build max, exact in Python ints.
-        ok = ok & (av >= mn) & (av <= mn + (r - 1))
-        d = av - mn
-        idx = idx * r + xp.clip(d, 0, r - 1)
-    hit = xp.where(ok, xp.take(table_x, xp.where(ok, idx, 0)),
-                   np.int32(-1)).astype(np.int32)
-    return hit, hit >= 0
+    maxs = [mn + (r - 1) for mn, r in zip(mins, ranges)]
+    lookup = partial(_lookup, np) if probe.is_host else _device_probe
+    return lookup(arrays, valid, table, mins, maxs, ranges)
 
 
 def broadcast_join_indices(probe: ColumnBatch, build: ColumnBatch,

@@ -121,7 +121,8 @@ class Scan(LogicalPlan):
                  bucket_spec: Optional[BucketSpec] = None,
                  files: Optional[Sequence[str]] = None,
                  index_name: Optional[str] = None,
-                 pinned_version: Optional[int] = None):
+                 pinned_version: Optional[int] = None,
+                 appended: bool = False):
         from hyperspace_tpu.utils.storage import canonical
         self.root_paths = [canonical(p) for p in root_paths]
         self._schema = schema
@@ -144,6 +145,11 @@ class Scan(LogicalPlan):
         # (identity/serde), since a serialized plan never carries rule
         # rewrites.
         self.index_name = index_name
+        # Set iff a rewrite rule made this scan hybrid scan's branch
+        # over the files APPENDED since an index was built (its explicit
+        # file list is exactly those). In-process only, like index_name:
+        # what the scan's telemetry keys on.
+        self.appended = appended
         # An EXPLICIT file list (hybrid scan / incremental deltas) restricts
         # the scan and is part of its identity; a lazily-cached glob is not.
         self._explicit_files = files is not None

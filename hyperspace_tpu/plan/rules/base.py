@@ -5,6 +5,7 @@ from __future__ import annotations
 import logging
 from typing import List, Optional
 
+from hyperspace_tpu import telemetry
 from hyperspace_tpu.constants import States
 from hyperspace_tpu.index.log_entry import IndexLogEntry
 from hyperspace_tpu.index.signature import SignatureProviderFactory
@@ -181,6 +182,43 @@ class Rule:
             except Exception:
                 scan.files()  # odd backend: pay the generic listing
         return scan
+
+    def hybrid_delta(self, entry: IndexLogEntry, scan: Scan):
+        """(appended files, deleted lineage ids) where `entry` can serve
+        `scan`'s relation through hybrid scan as the lake stands now, or
+        None where it cannot: the ONE classification both rewrite rules
+        run, under the span `hs.plan.hybrid`. A lineage-enabled entry is
+        held file by file (appends and whole-file deletes serve; a file
+        rewritten in place invalidates its index rows with no way to
+        tell which, so it declines). A pre-lineage entry has no per-file
+        stamps: deletions are un-servable, and that the captured files
+        are untouched is proven by the aggregate signature over exactly
+        the stored file set (a path-set check alone misses in-place
+        rewrites)."""
+        from hyperspace_tpu.index.source_delta import (classify_current,
+                                                       restricted_scan,
+                                                       split_current)
+
+        with telemetry.span("hs.plan.hybrid", "plan",
+                            index=entry.name) as sp:
+            files = scan.files()
+            usable = None
+            delta = classify_current(entry, files)
+            if delta is not None:
+                appended, deleted_ids, modified = delta
+                if not modified and (appended or deleted_ids):
+                    usable = (appended, deleted_ids)
+            else:
+                appended, missing, stored = split_current(entry, files)
+                if (appended and stored and not missing
+                        and self.signature_matches(
+                            entry, restricted_scan(entry, scan,
+                                                   sorted(stored)))):
+                    usable = (appended, [])
+            sp.set(files=len(files),
+                   appended=len(usable[0]) if usable else -1,
+                   deleted=len(usable[1]) if usable else 0)
+        return usable
 
     @staticmethod
     def lineage_exclusion(deleted_ids):

@@ -312,34 +312,15 @@ class FilterIndexRule(Rule):
         if self.session.conf.get(constants.HYBRID_SCAN_ENABLED,
                                  "false").lower() != "true":
             return None
-        from hyperspace_tpu.index.source_delta import (classify_current,
-                                                       restricted_scan,
-                                                       split_current)
         needed = ({c for c in filter_columns}
                   | {c for c in project_columns})
         for entry in self._covering_indexes():
             if not self._covers(entry, project_columns, filter_columns):
                 continue
-            delta = classify_current(entry, scan.files())
-            if delta is not None:
-                appended, deleted_ids, modified = delta
-                # In-place rewrites invalidate the index rows of that file
-                # with no way to tell which rows changed — decline.
-                if modified or not (appended or deleted_ids):
-                    continue
-            else:
-                # Pre-lineage entry: per-file stamps absent, so deletions
-                # are un-servable and untouched-survivor proof falls back
-                # to the aggregate signature over the stored file set.
-                # (Path-set subset alone misses in-place rewrites.)
-                appended, missing, stored = split_current(entry, scan.files())
-                deleted_ids = []
-                if missing or not appended or not stored:
-                    continue
-                if not self.signature_matches(entry,
-                                              restricted_scan(entry, scan,
-                                                              sorted(stored))):
-                    continue
+            usable = self.hybrid_delta(entry, scan)
+            if usable is None:
+                continue
+            appended, deleted_ids = usable
             index_source = self.index_scan(entry, bucketed=True)
             if deleted_ids:
                 index_source = Filter(self.lineage_exclusion(deleted_ids),
@@ -372,7 +353,7 @@ class FilterIndexRule(Rule):
             if not appended:
                 return Project(needed_cols, index_source)
             appended_scan = Scan(scan.root_paths, scan.schema,
-                                 files=appended)
+                                 files=appended, appended=True)
             return Union([Project(needed_cols, index_source),
                           Project(needed_cols, appended_scan)])
         return None

@@ -144,7 +144,8 @@ class JoinIndexRule(Rule):
                 branches = [Project(names, replacement)]
                 if appended:
                     branches.append(Project(names, Scan(
-                        scan.root_paths, scan.schema, files=appended)))
+                        scan.root_paths, scan.schema, files=appended,
+                        appended=True)))
                 replacement = (Union(branches) if len(branches) > 1
                                else branches[0])
 
@@ -238,9 +239,6 @@ class JoinIndexRule(Rule):
         appended files ride along as a union branch, and (lineage-enabled
         indexes) deleted files' rows are excluded by a lineage filter."""
         from hyperspace_tpu import constants
-        from hyperspace_tpu.index.source_delta import (classify_current,
-                                                       restricted_scan,
-                                                       split_current)
 
         hybrid = (self.session.conf.get(constants.HYBRID_SCAN_ENABLED,
                                         "false").lower() == "true")
@@ -261,20 +259,9 @@ class JoinIndexRule(Rule):
                 continue
             if not hybrid or scan is None:
                 continue
-            delta = classify_current(entry, scan.files())
-            if delta is not None:
-                appended, deleted_ids, modified = delta
-                if modified or not (appended or deleted_ids):
-                    continue
-                out.append((entry, appended or None, deleted_ids))
-                continue
-            appended, missing, stored = split_current(entry, scan.files())
-            if missing or not appended or not stored:
-                continue
-            if self.signature_matches(entry,
-                                      restricted_scan(entry, scan,
-                                                      sorted(stored))):
-                out.append((entry, appended, []))
+            usable = self.hybrid_delta(entry, scan)
+            if usable is not None:
+                out.append((entry, usable[0] or None, usable[1]))
         return out
 
     def _best_index_pair(self, join: Join, mapping: Dict[str, str]):

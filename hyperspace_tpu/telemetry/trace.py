@@ -73,8 +73,15 @@ SPAN_NAMES = {
     "hs.plan.optimize": "session.optimize: the rewrite rules",
     "hs.plan.compile": "compile_plan: physical planning + fusion "
                        "grouping",
+    "hs.plan.hybrid": "hybrid scan, inside hs.plan.optimize: one index "
+                      "held against the relation's current files "
+                      "(index, then files: the listing's, appended and "
+                      "deleted: what the index lacks and has lost; "
+                      "appended -1 where it is declined)",
     # operators — engine/physical wrapper, on the executing thread
-    "hs.op.<Name>": "one physical operator (lane, rows)",
+    "hs.op.<Name>": "one physical operator (lane, rows; a Scan of "
+                    "hybrid scan's appended files also appended: the "
+                    "files it read)",
     # fused stage — engine/fusion.py; sync + compact also in the unfused
     # filters (engine/physical.FilterExec, engine/compiler.apply_filter)
     "hs.stage.dispatch": "the stage program's dispatch (ops, cache_hit)",
@@ -135,6 +142,9 @@ DEVICE_SCOPES = {
     "hs.compact": "mask -> survivor indices (rank select or sort select)",
     "hs.join.match": "the counting join's match program",
     "hs.join.expand": "the counting join's expansion to row pairs",
+    "hs.join.broadcast": "the broadcast join's direct-address probe "
+                         "(`jit__broadcast_probe`, or inlined into a "
+                         "fused stage's program)",
     # mesh: the three SPMD programs, on every chip's plane
     "hs.mesh.filter": "the SPMD predicate mask (`jit_spmd_filter`)",
     "hs.mesh.join": "the SPMD join's two programs: the match "

@@ -212,3 +212,45 @@ def test_filter_sizing_programs_are_scoped_on_v5e(one_chip, no_compile_cache,
     bare = [line.split("=")[0].strip() for line in timed
             if f'op_name="jit({program})/{scope}/' not in line]
     assert timed and not bare, bare
+
+
+def test_broadcast_probe_is_named_and_scoped_on_v5e(one_chip,
+                                                    no_compile_cache):
+    """What a device capture will show of `ops/broadcast_join.py`'s probe
+    at the hybrid cell's shape (17,999,998 int64 keys against a
+    36,000-slot table): one program named `jit__broadcast_probe`, its
+    gather under the device scope `hs.join.broadcast`, and the table's
+    packing as arguments (a second build side compiles nothing)."""
+    import re
+
+    import numpy as np
+
+    from hyperspace_tpu.ops import broadcast_join
+
+    broadcast_join._device_probe(  # builds the jitted program
+        [jnp.zeros(8, jnp.int64)], None, jnp.zeros(4, jnp.int32), [0], [3],
+        [4])
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    packing = [shape(1, jnp.int64)] * 3
+    lowered = jax.jit(broadcast_join._probe_jit.__wrapped__).lower(
+        (shape(17_999_998, jnp.int64),), None, shape(36_000, jnp.int32),
+        *packing)
+    hlo = lowered.compile().as_text()
+    assert "jit__broadcast_probe" in hlo.splitlines()[0]
+    entry = hlo[hlo.index("\nENTRY "):]
+    timed = [line for line in entry.splitlines()
+             if re.search(r" (fusion|gather|sort|scatter|while)\(", line)]
+    assert timed and not [
+        line.split("=")[0].strip() for line in timed
+        if 'op_name="jit(_broadcast_probe)/hs.join.broadcast/' not in line]
+    # the probe finds what numpy finds, out-of-range keys and all
+    table = np.array([2, -1, 0, 1], dtype=np.int32)
+    keys = np.array([10, 11, 12, 13, 9, 14, -2 ** 63], dtype=np.int64)
+    hit, matched = broadcast_join._device_probe(
+        [jnp.asarray(keys)], None, table, [10], [13], [4])
+    assert np.asarray(hit).tolist() == [2, -1, 0, 1, -1, -1, -1]
+    assert np.asarray(matched).tolist() == [True, False, True, True,
+                                            False, False, False]

@@ -1,20 +1,29 @@
-"""Device merge-join kernels over sorted columnar batches.
+"""Device equi-join kernels over columnar batches.
 
 The reference's query-time win is Spark's SortMergeJoin with Exchange+Sort
 elided thanks to bucketed relations (`index/rules/JoinIndexRule.scala:41-43`).
-The device equivalent joins two *sorted* key columns entirely with
-vectorized XLA primitives — no scalar merge loop (which would defeat the
-TPU's vector units):
+The device equivalent joins two key column sets entirely with vectorized
+XLA primitives — no scalar merge loop (which would defeat the TPU's
+vector units). The device lane is the COUNTING join, in original row
+space, two programs and one host sync:
 
-1. multi-column keys are first reduced to order-preserving dense group ids
-   by a joint sort over both sides (`encode_join_keys`) — this also makes
-   string keys from different dictionaries comparable;
-2. per left row, the matching right range is found with two
-   `searchsorted` calls (lo/hi);
-3. the ragged match expansion is linearized by an exclusive cumsum and one
-   `searchsorted` over output slots — static shapes everywhere except one
-   host sync for the total match count, which happens at result
-   materialization anyway.
+1. the match (`_counting_match_lanes`, scope `hs.join.match`): ONE sort
+   of both sides' 32-bit key lanes with (side, row number) as trailing
+   keys — which also makes string keys from different dictionaries and
+   multi-column keys comparable — then `_runs_to_counts`: key runs from
+   adjacent differences, and each run's right-row count and bracket by
+   five scans over the sorted rows (two prefix sums, one `cummax`, two
+   reverse `cummin`). No `searchsorted` and no gather over the sorted
+   rows: the chip scans them some 20-60 times faster than it gathers
+   them (see `_runs_to_counts`);
+2. the total pair count is the one host sync; it sizes the result, which
+   is materialized anyway;
+3. the expansion (`_counting_expand`, scope `hs.join.expand`): `repeat`
+   over the per-row counts and gathers of the output's size.
+
+`merge_join_indices` (two `searchsorted` calls over sorted dense ids
+from `encode_join_keys`) is the older formulation and no operator's
+path; the host lane (numpy, native merge) is at the end of the file.
 """
 
 from __future__ import annotations
@@ -92,21 +101,32 @@ def _join_lane_operands(left: ColumnBatch, right: ColumnBatch,
 def _runs_to_counts(differs, side_s, left_outer: bool):
     """Shared tail of the counting match: per-run right-counts and
     bracket starts from the (T-1) adjacent-key-difference vector over
-    the sorted (key, side, orig) sequence."""
+    the sorted (key, side, orig) sequence.
+
+    Scans only, no gather over the T sorted rows: the inclusive count
+    of right rows `R` and the exclusive one `R - side_s` never
+    decrease, so the value at a run's first row is carried forwards
+    from the run starts by a `cummax` and the value at its last row
+    backwards from the run ends by a reverse `cummin` (sentinel T, at
+    or above every count); `rights` is their difference. On a v5e a
+    scan over 4.6 M rows costs 1.75 ms and a gather of them 34-108 ms
+    (PERF.md section 6, PR 35)."""
     import jax
     import jax.numpy as jnp
 
     T = side_s.shape[0]
+    sentinel = jnp.int32(T)
     pos = jnp.arange(T, dtype=jnp.int32)
-    run_start = jnp.concatenate([jnp.ones(1, bool), differs])
-    run_first = jax.lax.cummax(jnp.where(run_start, pos, 0))
-    nxt = jnp.flip(jax.lax.cummin(jnp.flip(
-        jnp.where(run_start, pos, jnp.int32(T)))))
-    run_last = jnp.concatenate([nxt[1:], jnp.full(1, T, jnp.int32)]) - 1
+    edge = jnp.ones(1, bool)
+    run_start = jnp.concatenate([edge, differs])
+    run_end = jnp.concatenate([differs, edge])
     R = jnp.cumsum(side_s)  # inclusive right-element count
-    rights = (jnp.take(R, run_last) - jnp.take(R, run_first)
-              + jnp.take(side_s, run_first))
-    rstart = run_last - rights + 1
+    before = jax.lax.cummax(jnp.where(run_start, R - side_s, 0))
+    upto = jax.lax.cummin(jnp.where(run_end, R, sentinel), reverse=True)
+    rights = upto - before
+    run_last = jax.lax.cummin(jnp.where(run_end, pos, sentinel),
+                              reverse=True)
+    rstart = run_last - rights + 1  # first right element of the run
     counts = jnp.where(side_s == 0, rights, 0).astype(jnp.int32)
     if left_outer:
         counts = jnp.where(side_s == 0, jnp.maximum(counts, 1), 0)
@@ -121,7 +141,8 @@ def _counting_match_lanes(lanes_l, lanes_r, left_outer: bool):
     """The counting match directly over raw key LANES — ONE staged sort
     of (marker, *value lanes, side, orig) replaces the earlier two-sort
     pipeline (dense-id encode sort + id/side match sort): runs come from
-    adjacent lane differences in the single sorted sequence. Orig
+    adjacent lane differences in the single sorted sequence, their
+    brackets from `_runs_to_counts`' scans (no gather). Orig
     indices ride as trailing sort keys (unique, so equivalent to the
     stable carried-value formulation)."""
     import jax.numpy as jnp
@@ -263,8 +284,8 @@ def counting_join_indices(l_ids, r_ids, how: str = "inner") -> Tuple:
     scale (measured ~17-20s of a 22s 39M-row join); a flat 1-D
     `lax.sort` of the same rows runs in ~1s. So: sort (id, side,
     original index) once, derive per-id-run right-row counts and bracket
-    starts from cumulative sums over the SORTED sequence, and expand
-    matches with `jnp.repeat`. 4-5x faster end-to-end at 39M rows, and
+    starts by scans over the SORTED sequence (`_runs_to_counts`), and
+    expand matches with `jnp.repeat`. 4-5x faster end-to-end at 39M rows, and
     callers no longer pre-sort their payload batches — indices come back
     in original row space.
 
@@ -297,11 +318,12 @@ def counting_join_indices(l_ids, r_ids, how: str = "inner") -> Tuple:
                                  static_argnames=("left_outer",))
 @device_scoped("hs.join.match")
 def _counting_match(l_ids, r_ids, left_outer: bool):
+    """The counting match in id space: one stable sort of (id, side)
+    carrying the row numbers, then `_runs_to_counts`."""
     import jax
     import jax.numpy as jnp
 
     n, m = l_ids.shape[0], r_ids.shape[0]
-    T = n + m
     ids2 = jnp.concatenate([l_ids, r_ids])
     side = jnp.concatenate([jnp.zeros(n, jnp.int32),
                             jnp.ones(m, jnp.int32)])
@@ -309,22 +331,8 @@ def _counting_match(l_ids, r_ids, left_outer: bool):
                             jnp.arange(m, dtype=jnp.int32)])
     ids_s, side_s, orig_s = jax.lax.sort([ids2, side, orig], num_keys=2,
                                          is_stable=True)
-    pos = jnp.arange(T, dtype=jnp.int32)
-    run_start = jnp.concatenate([jnp.ones(1, bool),
-                                 ids_s[1:] != ids_s[:-1]])
-    run_first = jax.lax.cummax(jnp.where(run_start, pos, 0))
-    # Exclusive run end: position of the NEXT run start (reverse cummin).
-    nxt = jnp.flip(jax.lax.cummin(jnp.flip(
-        jnp.where(run_start, pos, jnp.int32(T)))))
-    run_last = jnp.concatenate([nxt[1:], jnp.full(1, T, jnp.int32)]) - 1
-    R = jnp.cumsum(side_s)  # inclusive right-element count
-    rights = (jnp.take(R, run_last) - jnp.take(R, run_first)
-              + jnp.take(side_s, run_first))
-    rstart = run_last - rights + 1  # first right element of the run
-    counts = jnp.where(side_s == 0, rights, 0).astype(jnp.int32)
-    if left_outer:
-        counts = jnp.where(side_s == 0, jnp.maximum(counts, 1), 0)
-    starts = jnp.cumsum(counts) - counts
+    counts, starts, rights, rstart = _runs_to_counts(
+        ids_s[1:] != ids_s[:-1], side_s, left_outer)
     return counts, starts, rights, rstart, orig_s
 
 

@@ -794,3 +794,110 @@ def test_hashed_counting_match_collision_fallback():
     got = sorted(zip(np.asarray(li).tolist(), np.asarray(ri).tolist()))
     want = sorted(zip(np.asarray(li2).tolist(), np.asarray(ri2).tolist()))
     assert got == want
+
+
+def _loop_brackets(keys, side, left_outer):
+    """Plain reference for `join._runs_to_counts`: walk the key runs of
+    the sorted (key, side) rows one by one."""
+    T = len(side)
+    rights = np.zeros(T, dtype=np.int32)
+    rstart = np.zeros(T, dtype=np.int32)
+    first = 0
+    while first < T:
+        last = first
+        while last + 1 < T and (keys[last + 1] == keys[first]).all():
+            last += 1
+        in_run = int(side[first:last + 1].sum())
+        rights[first:last + 1] = in_run
+        rstart[first:last + 1] = last - in_run + 1
+        first = last + 1
+    counts = np.where(side == 0, rights, 0).astype(np.int32)
+    if left_outer:
+        counts = np.where(side == 0, np.maximum(counts, 1), 0).astype(
+            np.int32)
+    starts = (np.cumsum(counts) - counts).astype(np.int32)
+    return counts, starts, rights, rstart
+
+
+def _bracket_cases():
+    """name -> (marker, value, side) of the rows a counting match sorts;
+    marker 0 = valid key, 1 = left row with a null key, 2 = right row
+    with one (`join._join_lane_operands`)."""
+    rng = np.random.default_rng(35)
+    left, right = np.zeros, np.ones
+    cases = {
+        "all_distinct": (np.arange(64), rng.integers(0, 2, 64)),
+        "one_hot_key": (left(50), np.repeat([0, 1], [20, 30])),
+        "left_only_runs": (np.repeat(np.arange(9), 5), left(45)),
+        "right_only_runs": (np.repeat(np.arange(9), 5), right(45)),
+        "two_rows_one_run": (np.array([0, 0]), np.array([0, 1])),
+        "two_rows_two_runs": (np.array([0, 1]), np.array([1, 0])),
+        "duplicate_heavy": (rng.integers(0, 40, 5000),
+                            rng.integers(0, 2, 5000)),
+    }
+    cases = {name: (np.zeros(len(side)), value, side)
+             for name, (value, side) in cases.items()}
+    # single-side runs behind the valid keys, equal values among them
+    cases["null_marker_runs"] = (
+        np.repeat([0, 1, 2], [12, 5, 4]),
+        np.concatenate([np.repeat(np.arange(4), 3), [0, 0, 0, 7, 7],
+                        [0, 0, 3, 3]]),
+        np.concatenate([np.tile([0, 0, 1], 4), left(5), right(4)]))
+    return cases
+
+
+BRACKET_CASES = _bracket_cases()
+
+
+@pytest.mark.parametrize("left_outer", [False, True])
+@pytest.mark.parametrize("case", sorted(BRACKET_CASES))
+def test_runs_to_counts_matches_a_loop_over_runs(case, left_outer):
+    import jax.numpy as jnp
+
+    marker, value, side = BRACKET_CASES[case]
+    order = np.lexsort((side, value, marker))  # as the match's sort does
+    keys = np.stack([marker[order], value[order]], axis=1)
+    side = side[order].astype(np.int32)
+    differs = (keys[1:] != keys[:-1]).any(axis=1)
+    got = join._runs_to_counts(jnp.asarray(differs), jnp.asarray(side),
+                               left_outer)
+    want = _loop_brackets(keys, side, left_outer)
+    for name, g, w in zip(("counts", "starts", "rights", "rstart"),
+                          got, want):
+        assert g.dtype == w.dtype, name
+        np.testing.assert_array_equal(np.asarray(g), w, err_msg=name)
+
+
+def _primitives(jaxpr, into):
+    """Names of every primitive in `jaxpr`, nested calls included."""
+    for eqn in jaxpr.eqns:
+        into.add(eqn.primitive.name)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, into)
+    return into
+
+
+@pytest.mark.parametrize("entry", ["lanes", "ids"])
+def test_counting_match_holds_no_gather(entry):
+    """A gather over the sorted rows costs the chip 34-108 ms where a
+    scan costs 1.75 (PERF.md, PR 35); a CPU run cannot see that, so the
+    traced program is held to it."""
+    import jax
+    import jax.numpy as jnp
+
+    ids_l, ids_r = jnp.arange(12, dtype=jnp.int32) % 5, jnp.arange(
+        9, dtype=jnp.int32) % 4
+    if entry == "lanes":
+        lanes_l = (jnp.zeros(12, jnp.int32), ids_l)
+        lanes_r = (jnp.zeros(9, jnp.int32), ids_r)
+        jaxpr = jax.make_jaxpr(lambda a, b: join._counting_match_lanes(
+            a, b, left_outer=True))(lanes_l, lanes_r)
+    else:
+        jaxpr = jax.make_jaxpr(lambda a, b: join._counting_match(
+            a, b, left_outer=True))(ids_l, ids_r)
+    found = _primitives(jaxpr.jaxpr, set())
+    assert {"sort", "cumsum", "cummax", "cummin"} <= found  # walked inside
+    assert not {p for p in found if "gather" in p}, found

@@ -13,6 +13,7 @@ Exchange/Sort elision, and execution actually skips the work.
 from __future__ import annotations
 
 import functools
+import os
 
 from typing import List, Optional, Sequence, Set, Tuple
 
@@ -83,7 +84,8 @@ def _instrument(fn, bucketed: bool):
             rows = _batch_rows(out)
             detail = op.detail if op is not None else {}
             sp.set(rows=rows, lane=detail.get("lane"),
-                   appended=detail.get("appended"))
+                   appended=detail.get("appended"),
+                   index=detail.get("index"), source=detail.get("source"))
             if op is not None:
                 rec.finish_operator(op, rows_out=rows)
         # Operator-span boundary: fold a device-memory sample into the
@@ -228,6 +230,11 @@ class ScanExec(PhysicalNode):
                   # per-relation scan-bytes signal.
                   "bytes_scanned": facts.bytes_scanned,
                   "roots": list(self.scan.root_paths)}
+        if self.scan.index_name is not None:
+            detail["index"] = self.scan.index_name
+        elif self.scan.root_paths:
+            detail["source"] = os.path.basename(
+                self.scan.root_paths[0].rstrip("/"))
         if self.shared_members:
             detail["shared_members"] = self.shared_members
         if self.scan.appended:
@@ -1254,6 +1261,10 @@ class SortMergeJoinExec(PhysicalNode):
 
         lbatch = unwrap(self.left).execute(bucket)
         rbatch = unwrap(self.right).execute(bucket)
+        telemetry.annotate(lane=("host" if lbatch.is_host
+                                 and rbatch.is_host else "device"),
+                           left_rows=lbatch.num_rows,
+                           right_rows=rbatch.num_rows)
         return sort_merge_join(lbatch, rbatch, self.left_keys,
                                self.right_keys, how=self.how,
                                columns=self.out_columns)

@@ -8,6 +8,16 @@ vectorized, one host sync (the group count) to size the output.
 SQL null semantics: sum/min/max/avg ignore null inputs; count(col) counts
 non-null; count(*) counts rows; a group whose inputs are all null yields
 null (validity False) for sum/min/max/avg and 0 for count.
+
+avg and stddev of an INTEGER column are exact on both lanes: each
+group's integer moments (count, sum, sum of squares) are summed in int64,
+which no lane rounds, and finished on the host in IEEE float64, one
+correctly rounded division (and one square root) from integers that
+float64 holds exactly (`_finish_exact`). The chip's own float64 is an
+f32 pair of about 48 bits whose division is not correctly rounded, so
+computing these on the device would differ from the host lane, and from
+SQL's value, in the last bits. Where the moments could overflow or
+leave float64's 53 exact bits, the float path below serves.
 """
 
 from __future__ import annotations
@@ -21,9 +31,12 @@ from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.io.columnar import ColumnBatch, DeviceColumn
 from hyperspace_tpu.plan.nodes import AggSpec
 from hyperspace_tpu.plan.schema import Schema
+from hyperspace_tpu import telemetry
+from hyperspace_tpu.telemetry import device_scoped
 
 
 @__import__("jax").jit
+@device_scoped("hs.aggregate")
 def _group_phase_a(operands):
     """(sort permutation, sorted-space segment ids) of the group-key
     lanes, fused into one executable (staged sort + adjacent-difference
@@ -55,6 +68,7 @@ HASH_GROUP_MIN_LANES = 5
 
 
 @__import__("jax").jit
+@device_scoped("hs.aggregate")
 def _group_phase_a_hashed(operands):
     """(perm, segment ids, collision flag) via ONE u64-hash-lane sort.
     Equal keys share a hash, so a stable hash sort puts every group in
@@ -87,6 +101,54 @@ def _group_phase_a_hashed(operands):
     packed = (segment_ids[-1].astype(jnp.int64) * jnp.int64(2)
               + collision.astype(jnp.int64))
     return perm, segment_ids, packed
+
+
+@__import__("functools").partial(__import__("jax").jit,
+                                 static_argnames=("num_groups",))
+@device_scoped("hs.aggregate")
+def _exact_moments(values, valid, segment_ids, num_groups: int):
+    """Per-group count, sum and sum of squares of an integer column in
+    int64, and the largest magnitude in it (which says on the host
+    whether the squares overflowed)."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.where(valid, values, 0).astype(jnp.int64)
+    moments = [jax.ops.segment_sum(y, segment_ids, num_segments=num_groups)
+               for y in (valid.astype(jnp.int64), x, x * x)]
+    return (*moments, jnp.max(jnp.abs(x).astype(jnp.uint64)))
+
+
+_EXACT = 1 << 53  # integers float64 holds exactly
+
+
+def _exact_candidate(spec: AggSpec, src: DeviceColumn, out_dtype: str):
+    """avg / stddev of an integer column with a float64 result."""
+    return (spec.func in ("avg", "stddev") and out_dtype == "float64"
+            and not src.is_string
+            and np.dtype(src.raw.dtype).kind in "iu")
+
+
+def _finish_exact(func: str, n, total, squares, amax: int):
+    """avg = total / n, stddev_samp = sqrt((n sq - total^2) / (n (n-1)))
+    from exact int64 moments, each division (and the square root) one
+    IEEE rounding of integers below 2**53, so both lanes give SQL's value
+    to the nearest double. None where the moments may have overflowed
+    or left the exact range: the caller's float path serves."""
+    n_max = int(n.max()) if len(n) else 0
+    amax = int(amax)
+    if amax * n_max >= _EXACT:
+        return None
+    with np.errstate(divide="ignore", invalid="ignore"):
+        if func == "avg":
+            return total.astype(np.float64) / np.maximum(n, 1).astype(
+                np.float64)
+        if max(amax, 1) ** 2 * n_max ** 2 >= _EXACT:
+            return None
+        num = n * squares - total * total
+        den = n * (n - 1)
+        return np.sqrt(num.astype(np.float64)
+                       / np.maximum(den, 1).astype(np.float64))
 
 
 def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
@@ -156,6 +218,8 @@ def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
             perm, segment_ids, packed = _group_phase_a_hashed(ops)
             packed = int(packed)  # the one host sync
             if packed & 1:  # hash collision split a group: exact re-run
+                telemetry.get_registry().counter(
+                    "aggregate.hashed.fallbacks").inc()
                 perm, segment_ids = _group_phase_a(ops)
                 num_groups = int(segment_ids[-1]) + 1
             else:
@@ -182,6 +246,22 @@ def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
             jnp.take(src.raw, firsts),
             (jnp.take(src.validity, firsts)
              if src.validity is not None else None))
+
+    # Integer avg / stddev: exact moments on the device, ONE fetch of
+    # all of them, finished on the host (module docstring).
+    moments, funcs = {}, {}
+    for spec in aggregates:
+        if spec.column == "*":
+            continue
+        src = sorted_batch.column(spec.column)
+        if _exact_candidate(spec, src, out_schema.field(spec.alias).dtype):
+            valid = (src.validity if src.validity is not None
+                     else jnp.ones(n, dtype=bool))
+            funcs[spec.alias] = spec.func
+            moments[spec.alias] = _exact_moments(src.raw, valid, segment_ids,
+                                                 num_groups=num_groups)
+    exact = {alias: _finish_exact(funcs[alias], *fetched)
+             for alias, fetched in jax.device_get(moments).items()}
 
     for spec in aggregates:
         out_field = out_schema.field(spec.alias)
@@ -228,6 +308,13 @@ def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
             continue
         values = src.data
         validity_out = counts > 0
+        if exact.get(spec.alias) is not None:
+            # float64 as its bits: exact through every later move
+            columns[out_field.name] = DeviceColumn(
+                jnp.asarray(exact[spec.alias].view(np.int64)), "float64",
+                validity=validity_out if spec.func == "avg"
+                else counts > 1)
+            continue
         if spec.func in ("sum", "avg"):
             acc_dtype = (jnp.float64 if out_field.dtype == "float64"
                          else jnp.int64)
@@ -367,7 +454,18 @@ def _host_group_aggregate(batch: ColumnBatch,
             continue
         values = np.asarray(src.data)
         validity_out = counts > 0
-        if spec.func in ("sum", "avg"):
+        data = None
+        if _exact_candidate(spec, src, out_field.dtype):
+            # the device lane's exact moments, in numpy
+            x = np.where(valid, values, 0).astype(np.int64)
+            data = _finish_exact(spec.func, counts,
+                                 np.add.reduceat(x, starts),
+                                 np.add.reduceat(x * x, starts),
+                                 np.abs(x).astype(np.uint64).max())
+        if data is not None:
+            if spec.func == "stddev":
+                validity_out = counts > 1
+        elif spec.func in ("sum", "avg"):
             acc = (np.float64 if out_field.dtype == "float64" else np.int64)
             total = np.add.reduceat(
                 np.where(valid, values, 0).astype(acc), starts)

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 from typing import List, Sequence, Tuple
 
+from hyperspace_tpu import telemetry
 from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.io.columnar import ColumnBatch
 from hyperspace_tpu.telemetry import device_scoped
@@ -226,6 +227,11 @@ def _match_lanes(lanes_l, lanes_r, left_outer: bool):
     return (*_counting_match_lanes(lanes_l, lanes_r, left_outer), None)
 
 
+def _hashed_fallback() -> None:
+    """Count one re-run of the exact match after a hash collision."""
+    telemetry.get_registry().counter("join.hashed.fallbacks").inc()
+
+
 def _packed_sync(value_dev, collision):
     """ONE device fetch carrying (sizing value, collision flag): returns
     (int value, collided). `value_dev` must be an int64 device scalar."""
@@ -259,16 +265,21 @@ def counting_join_batch_indices(left: ColumnBatch, right: ColumnBatch,
                                            right_keys)
     counts, starts, rights, rstart, orig_s, collision = _match_lanes(
         lanes_l, lanes_r, left_outer)
+    match = "exact"
     if collision is None:
         total = int(jnp.sum(counts, dtype=jnp.int64))  # the one host sync
     else:
         # One sync carries (total, collision); a collision re-runs exact.
         total, collided = _packed_sync(jnp.sum(counts, dtype=jnp.int64),
                                        collision)
+        match = "hashed"
         if collided:
+            _hashed_fallback()
+            match = "hashed-fallback"
             counts, starts, rights, rstart, orig_s = _counting_match_lanes(
                 lanes_l, lanes_r, left_outer)
             total = int(jnp.sum(counts, dtype=jnp.int64))
+    telemetry.annotate(match=match, keys=len(left_keys))
     if total == 0:
         return empty, empty
     return _counting_expand(counts, starts, rights, rstart, orig_s,
@@ -469,6 +480,7 @@ def semi_anti_indices(left: ColumnBatch, right: ColumnBatch,
         count, collided = _packed_sync(jnp.sum(mask, dtype=jnp.int64),
                                        collision)
         if collided:  # hash collision: exact re-run
+            _hashed_fallback()
             counts, _starts, rights, _rstart, orig_s = \
                 _counting_match_lanes(lanes_l, lanes_r, True)
             mask = membership_mask(counts, rights, orig_s)

@@ -79,9 +79,10 @@ SPAN_NAMES = {
                       "deleted: what the index lacks and has lost; "
                       "appended -1 where it is declined)",
     # operators — engine/physical wrapper, on the executing thread
-    "hs.op.<Name>": "one physical operator (lane, rows; a Scan of "
-                    "hybrid scan's appended files also appended: the "
-                    "files it read)",
+    "hs.op.<Name>": "one physical operator (lane, rows; a Scan also "
+                    "index: the index it reads, or source: the source "
+                    "directory's name, and a Scan of hybrid scan's "
+                    "appended files appended: the files it read)",
     # fused stage — engine/fusion.py; sync + compact also in the unfused
     # filters (engine/physical.FilterExec, engine/compiler.apply_filter)
     "hs.stage.dispatch": "the stage program's dispatch (ops, cache_hit)",
@@ -145,6 +146,10 @@ DEVICE_SCOPES = {
     "hs.join.broadcast": "the broadcast join's direct-address probe "
                          "(`jit__broadcast_probe`, or inlined into a "
                          "fused stage's program)",
+    "hs.aggregate": "the group-by's programs: the grouping sort "
+                    "(`jit__group_phase_a`, `jit__group_phase_a_hashed`) "
+                    "and the exact integer moments of avg / stddev "
+                    "(`jit__exact_moments`)",
     # mesh: the three SPMD programs, on every chip's plane
     "hs.mesh.filter": "the SPMD predicate mask (`jit_spmd_filter`)",
     "hs.mesh.join": "the SPMD join's two programs: the match "

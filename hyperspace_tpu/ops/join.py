@@ -15,7 +15,10 @@ space, two programs and one host sync:
    five scans over the sorted rows (two prefix sums, one `cummax`, two
    reverse `cummin`). No `searchsorted` and no gather over the sorted
    rows: the chip scans them some 20-60 times faster than it gathers
-   them (see `_runs_to_counts`);
+   them (see `_runs_to_counts`). Keys of `HASH_MATCH_MIN_LANES` lanes
+   and more (`_counting_match_lanes_hashed`) sort by (u64 key hash,
+   side, row number) instead, the key lanes carried by that same sort
+   as payload, so runs come from sorted lanes there too;
 2. the total pair count is the one host sync; it sizes the result, which
    is materialized anyway;
 3. the expansion (`_counting_expand`, scope `hs.join.expand`): `repeat`
@@ -180,14 +183,19 @@ HASH_MATCH_MIN_LANES = 4
                                  static_argnames=("left_outer",))
 @device_scoped("hs.join.match")
 def _counting_match_lanes_hashed(lanes_l, lanes_r, left_outer: bool):
-    """Hashed counting match: sort (u64 key-hash, side, orig) — one
-    3-operand sort regardless of key width — then derive runs from the
-    FULL lane differences (gathered through the permutation). Equal keys
-    share a hash so runs stay contiguous unless two different keys
-    collide; `collision` (any full-key boundary inside an equal-hash
-    run, exactly the split/interleave case) tells the caller to re-run
-    the exact path. Run order within a key run is (side, orig), same as
-    the exact sort's trailing operands."""
+    """Hashed counting match: sort by (u64 key-hash, side, orig) — three
+    sort keys regardless of key width — with the key lanes carried by
+    the same sort as payload operands, then derive runs from the FULL
+    sorted lane differences. No gather through the permutation: on a
+    v5e, over TPC-DS q17's 31.7 M rows and 7 lanes, this program takes
+    459 ms where the sort and a gather a lane took 2,968 (PERF.md
+    section 6). Payload adds no comparisons, so the comparator stays
+    three keys wide (cf. `keys.MAX_SORT_OPERANDS`). Equal keys share a
+    hash so runs stay contiguous unless two different keys collide;
+    `collision` (any full-key boundary inside an equal-hash run,
+    exactly the split/interleave case) tells the caller to re-run the
+    exact path. Run order within a key run is (side, orig), same as the
+    exact sort's trailing operands."""
     import jax
     import jax.numpy as jnp
 
@@ -202,13 +210,11 @@ def _counting_match_lanes_hashed(lanes_l, lanes_r, left_outer: bool):
                             jnp.ones(m, jnp.int32)])
     orig = jnp.concatenate([jnp.arange(n, dtype=jnp.int32),
                             jnp.arange(m, dtype=jnp.int32)])
-    h_s, side_s, orig_s = jax.lax.sort([h, side, orig], num_keys=3,
-                                       is_stable=False)
-    gidx = orig_s + side_s * jnp.int32(n)
+    h_s, side_s, orig_s, *lanes_s = jax.lax.sort(
+        [h, side, orig, *lanes], num_keys=3, is_stable=False)
     differs = jnp.zeros(T - 1, dtype=bool)
-    for k in lanes:
-        ks = jnp.take(k, gidx)
-        differs = differs | (ks[1:] != ks[:-1])
+    for k in lanes_s:
+        differs = differs | (k[1:] != k[:-1])
     h_differs = h_s[1:] != h_s[:-1]
     collision = jnp.any(differs & ~h_differs)
     counts, starts, rights, rstart = _runs_to_counts(differs, side_s,

@@ -880,11 +880,12 @@ def _primitives(jaxpr, into):
     return into
 
 
-@pytest.mark.parametrize("entry", ["lanes", "ids"])
+@pytest.mark.parametrize("entry", ["lanes", "ids", "hashed"])
 def test_counting_match_holds_no_gather(entry):
     """A gather over the sorted rows costs the chip 34-108 ms where a
     scan costs 1.75 (PERF.md, PR 35); a CPU run cannot see that, so the
-    traced program is held to it."""
+    traced program is held to it. The hashed match carries its seven
+    lanes (a marker and three int64 keys, q17's width) through its sort."""
     import jax
     import jax.numpy as jnp
 
@@ -895,6 +896,17 @@ def test_counting_match_holds_no_gather(entry):
         lanes_r = (jnp.zeros(9, jnp.int32), ids_r)
         jaxpr = jax.make_jaxpr(lambda a, b: join._counting_match_lanes(
             a, b, left_outer=True))(lanes_l, lanes_r)
+    elif entry == "hashed":
+        lanes_l = (jnp.zeros(12, jnp.int32),
+                   *[ids_l + k for k in range(3)],
+                   *[ids_l.astype(jnp.uint32) * k for k in range(3)])
+        lanes_r = (jnp.zeros(9, jnp.int32),
+                   *[ids_r + k for k in range(3)],
+                   *[ids_r.astype(jnp.uint32) * k for k in range(3)])
+        assert len(lanes_l) == 7 >= join.HASH_MATCH_MIN_LANES
+        jaxpr = jax.make_jaxpr(
+            lambda a, b: join._counting_match_lanes_hashed(
+                a, b, left_outer=True))(lanes_l, lanes_r)
     else:
         jaxpr = jax.make_jaxpr(lambda a, b: join._counting_match(
             a, b, left_outer=True))(ids_l, ids_r)

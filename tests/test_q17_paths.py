@@ -81,6 +81,91 @@ def test_a_forced_collision_reruns_the_match_and_counts_it():
     assert _pairs(*got) == _pairs(*want)
 
 
+def _np_fmix32(h):
+    h = h ^ (h >> np.uint32(16))
+    h = h * np.uint32(0x85EBCA6B)
+    h = h ^ (h >> np.uint32(13))
+    h = h * np.uint32(0xC2B2AE35)
+    return h ^ (h >> np.uint32(16))
+
+
+def _np_hash64(lanes):
+    """numpy mirror of `hash_partition.dual_hash64` over 32-bit lanes."""
+    def u32(lane):
+        if lane.dtype == np.int32:
+            return lane.view(np.uint32) ^ np.uint32(0x80000000)
+        return lane.astype(np.uint32)
+
+    def combine(a, b):
+        return a ^ (b + np.uint32(0x9E3779B9) + (a << np.uint32(6))
+                    + (a >> np.uint32(2)))
+
+    salt = np.uint32(0x6A09E667)
+    h1, h2 = _np_fmix32(u32(lanes[0])), _np_fmix32(u32(lanes[0]) ^ salt)
+    for lane in lanes[1:]:
+        h1 = combine(h1, _np_fmix32(u32(lane)))
+        h2 = combine(h2, _np_fmix32(u32(lane) ^ salt))
+    return (h1.astype(np.uint64) << np.uint64(32)) | h2.astype(np.uint64)
+
+
+def _hashed_match_reference(lanes_l, lanes_r, left_outer: bool):
+    """The hashed match's pairs in its order, in numpy: rows sorted by
+    (hash, side, row), runs of equal full keys, and each left row of a
+    run paired with the run's right rows in sorted order (-1 for an
+    unmatched left row of a left outer join)."""
+    n = len(lanes_l[0])
+    lanes = [np.concatenate([np.asarray(a), np.asarray(b)])
+             for a, b in zip(lanes_l, lanes_r)]
+    side = np.repeat([0, 1], [n, len(lanes_r[0])])
+    orig = np.concatenate([np.arange(n), np.arange(len(lanes_r[0]))])
+    order = np.lexsort((orig, side, _np_hash64(lanes)))
+    keys = np.stack([lane[order] for lane in lanes], axis=1)
+    side, orig = side[order], orig[order]
+    cuts = np.flatnonzero((keys[1:] != keys[:-1]).any(axis=1)) + 1
+    li, ri = [], []
+    for run in np.split(np.arange(len(side)), cuts):
+        rights = orig[run][side[run] == 1].tolist()
+        for row in orig[run][side[run] == 0].tolist():
+            if rights:
+                li.extend([row] * len(rights))
+                ri.extend(rights)
+            elif left_outer:
+                li.append(row)
+                ri.append(-1)
+    return np.array(li, dtype=np.int32), np.array(ri, dtype=np.int32)
+
+
+@pytest.mark.parametrize("how", ["inner", "left_outer"])
+def test_hashed_match_pairs_equal_a_numpy_reference_in_order(how):
+    """The same (li, ri) arrays, element by element, as the reference:
+    NULL keys on both sides (marker lanes 1 and 2) and 36 keys over
+    3,500 rows, runs of about 85 rows of one key."""
+    rng = np.random.default_rng(40)
+
+    def side(rows):
+        def key(domain, scale=1):
+            values = rng.integers(0, domain, rows).astype(np.int64) * scale
+            return pa.array(values, mask=rng.random(rows) < 0.04)
+        return columnar.from_arrow(pa.table({
+            "c": key(4), "i": key(3, 2 ** 33), "t": key(3, -1)}),
+            device=True)
+    left, right = side(2_001), side(1_503)
+    keys = ["c", "i", "t"]
+    lanes_l, lanes_r = join_mod._join_lane_operands(left, right, keys, keys)
+    assert len(lanes_l) >= join_mod.HASH_MATCH_MIN_LANES
+    assert set(np.asarray(lanes_l[0]).tolist()) == {0, 1}
+    assert set(np.asarray(lanes_r[0]).tolist()) == {0, 2}
+    before = _counter("join.hashed.fallbacks")
+    li, ri = join_mod.counting_join_batch_indices(left, right, keys, keys,
+                                                  how=how)
+    assert _counter("join.hashed.fallbacks") == before
+    want_li, want_ri = _hashed_match_reference(lanes_l, lanes_r,
+                                               how == "left_outer")
+    assert len(want_li) > 50_000
+    np.testing.assert_array_equal(np.asarray(li), want_li)
+    np.testing.assert_array_equal(np.asarray(ri), want_ri)
+
+
 def _group_batch(seed: int, n: int):
     rng = np.random.default_rng(seed)
     table = pa.table({"a": rng.integers(0, 6, n).astype(np.int64),

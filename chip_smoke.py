@@ -200,8 +200,8 @@ def join_lane(metrics) -> str:
 
 
 # Seconds jax spent in backend compiles and persistent-cache loads,
-# process-wide (covers the plain `jax.jit` sort programs, which the
-# registry's `compile.seconds` does not see).
+# process-wide (covers the eager ops' compiles too, which the registry's
+# `compile.seconds` does not see).
 COMPILE_S = {"backend": 0.0, "cache_load": 0.0}
 _JAX_EVENTS = {
     "/jax/core/compile/backend_compile_duration": "backend",

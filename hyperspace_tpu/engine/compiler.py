@@ -453,14 +453,10 @@ class ExpressionCompiler:
 
 
 def compile_predicate(expression: E.Expression, batch: ColumnBatch):
-    if batch.is_host:
-        return ExpressionCompiler(batch).predicate(expression)
-    import jax
-
-    # The mask's ops carry the device scope where a jitted program
-    # traces them (the fused stage); an eager dispatch shows none.
-    with jax.named_scope("hs.predicate"):
-        return ExpressionCompiler(batch).predicate(expression)
+    """The predicate's mask over `batch`, on its lane. Carries no device
+    scope: a fused stage's program names its predicate `hs.predicate`
+    (`engine/fusion._interpret`); an eager filter's ops have none."""
+    return ExpressionCompiler(batch).predicate(expression)
 
 
 def apply_filter(batch: ColumnBatch, expression: E.Expression) -> ColumnBatch:

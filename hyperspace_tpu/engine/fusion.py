@@ -423,6 +423,7 @@ class _LazyGatherColumn:
                  "pair_slot", "source_index", "src_name", "_mat")
 
     data = DeviceColumn.data
+    carry = DeviceColumn.carry
     with_raw = DeviceColumn.with_raw
 
     def __init__(self, src, hit, matched, pair_slot: int,
@@ -491,7 +492,15 @@ def _interpret(node, env: Dict[int, ColumnBatch], tables: Dict[int, object]):
         return env[node.index], None
     if isinstance(node, FilterExec):
         batch, sel = _interpret(node.child, env, tables)
-        mask = compile_predicate(node.condition, batch)
+        # A deferred build column the predicate reads is gathered here,
+        # in the stage's own trace: one gathered inside the predicate's
+        # nested call would leave its value behind in that call.
+        for name in sorted(node.condition.references()):
+            col = batch.column(name)
+            if isinstance(col, _LazyGatherColumn):
+                col._materialize()
+        mask = telemetry.device_scoped("hs.predicate")(compile_predicate)(
+            node.condition, batch)
         return batch, (mask if sel is None else sel & mask)
     if isinstance(node, ProjectExec):
         batch, sel = _interpret(node.child, env, tables)
@@ -576,7 +585,7 @@ def _run_stage(prog: _StageProgram, trees, table_args):
         # instrumented_jit: each actual trace records a compile span,
         # compile.* counters, and the retrace cause on the query.
         @partial(telemetry.instrumented_jit, "fusion.run_stage",
-                 static_argnames=("prog",))
+                 scope="hs.stage", static_argnames=("prog",))
         def _run(prog: _StageProgram, trees, table_args):
             import jax.numpy as jnp
 
@@ -627,7 +636,7 @@ def _finalize_lazy(idx, lazy_pairs, srcs, spec):
     global _finalize_lazy_jit
     if _finalize_lazy_jit is None:
         @partial(telemetry.instrumented_jit, "fusion.finalize_lazy",
-                 static_argnames=("spec", "has_idx"))
+                 scope="hs.stage", static_argnames=("spec", "has_idx"))
         def run(idx, lazy_pairs, srcs, spec, has_idx):
             import jax.numpy as jnp
 

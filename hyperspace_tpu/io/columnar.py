@@ -52,7 +52,7 @@ def _fused_take(arrays, indices):
 
         from hyperspace_tpu.telemetry import instrumented_jit
 
-        @instrumented_jit("columnar.fused_take")
+        @instrumented_jit("columnar.fused_take", scope="hs.gather")
         def _take_all(arrs, idx):
             return tuple(jnp.take(a, idx, axis=0) for a in arrs)
 

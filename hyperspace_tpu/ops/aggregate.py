@@ -32,11 +32,10 @@ from hyperspace_tpu.io.columnar import ColumnBatch, DeviceColumn
 from hyperspace_tpu.plan.nodes import AggSpec
 from hyperspace_tpu.plan.schema import Schema
 from hyperspace_tpu import telemetry
-from hyperspace_tpu.telemetry import device_scoped
+from hyperspace_tpu.telemetry import instrumented_jit
 
 
-@__import__("jax").jit
-@device_scoped("hs.aggregate")
+@instrumented_jit("aggregate.group_phase_a", scope="hs.aggregate")
 def _group_phase_a(operands):
     """(sort permutation, sorted-space segment ids) of the group-key
     lanes, fused into one executable (staged sort + adjacent-difference
@@ -67,8 +66,7 @@ def _group_phase_a(operands):
 HASH_GROUP_MIN_LANES = 5
 
 
-@__import__("jax").jit
-@device_scoped("hs.aggregate")
+@instrumented_jit("aggregate.group_phase_a_hashed", scope="hs.aggregate")
 def _group_phase_a_hashed(operands):
     """(perm, segment ids, collision flag) via ONE u64-hash-lane sort.
     Equal keys share a hash, so a stable hash sort puts every group in
@@ -103,9 +101,8 @@ def _group_phase_a_hashed(operands):
     return perm, segment_ids, packed
 
 
-@__import__("functools").partial(__import__("jax").jit,
-                                 static_argnames=("num_groups",))
-@device_scoped("hs.aggregate")
+@instrumented_jit("aggregate.exact_moments", scope="hs.aggregate",
+                  static_argnames=("num_groups",))
 def _exact_moments(values, valid, segment_ids, num_groups: int):
     """Per-group count, sum and sum of squares of an integer column in
     int64, and the largest magnitude in it (which says on the host
@@ -228,24 +225,10 @@ def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
             perm, segment_ids = _group_phase_a(ops)
             num_groups = int(segment_ids[-1]) + 1  # the one host sync
         sorted_batch = batch.take(perm)
-        # Representative row (first of each segment) carries the group keys.
-        firsts = jnp.searchsorted(segment_ids,
-                                  jnp.arange(num_groups, dtype=jnp.int32),
-                                  side="left")
     else:
         segment_ids = jnp.zeros(n, dtype=jnp.int32)
         num_groups = 1
         sorted_batch = batch
-        firsts = jnp.zeros(1, dtype=jnp.int32)
-
-    columns = {}
-    for name in group_columns:
-        src = sorted_batch.column(name)
-        f = batch.schema.field(name)
-        columns[f.name] = src.with_raw(
-            jnp.take(src.raw, firsts),
-            (jnp.take(src.validity, firsts)
-             if src.validity is not None else None))
 
     # Integer avg / stddev: exact moments on the device, ONE fetch of
     # all of them, finished on the host (module docstring).
@@ -263,26 +246,89 @@ def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
     exact = {alias: _finish_exact(funcs[alias], *fetched)
              for alias, fetched in jax.device_get(moments).items()}
 
+    plan, specs_columns = [], []
     for spec in aggregates:
         out_field = out_schema.field(spec.alias)
         if spec.func == "count" and spec.column == "*":
-            data = jax.ops.segment_sum(jnp.ones(n, dtype=jnp.int64),
-                                       segment_ids, num_segments=num_groups)
-            columns[out_field.name] = DeviceColumn(data, "int64")
+            plan.append(("count_rows", None, "int64", False))
+            specs_columns.append(None)
             continue
         src = sorted_batch.column(spec.column)
         if src.is_string and spec.func not in ("count", "count_distinct"):
             raise HyperspaceException(
                 f"Aggregate {spec.func} over string column {spec.column} "
                 "is not supported.")
-        valid = (src.validity if src.validity is not None
-                 else jnp.ones(n, dtype=bool))
-        counts = jax.ops.segment_sum(valid.astype(jnp.int64), segment_ids,
-                                     num_segments=num_groups)
-        if spec.func == "count":
-            columns[out_field.name] = DeviceColumn(counts, "int64")
+        plan.append((spec.func, src.dtype, out_field.dtype,
+                     exact.get(spec.alias) is not None))
+        specs_columns.append((src.raw, src.validity))
+    keys = [sorted_batch.column(name) for name in group_columns]
+    out_keys, out_specs = _group_finish(
+        segment_ids, tuple((k.raw, k.validity) for k in keys),
+        tuple(specs_columns), plan=tuple(plan), num_groups=num_groups)
+
+    columns = {}
+    for name, src, (raw, validity) in zip(group_columns, keys, out_keys):
+        columns[batch.schema.field(name).name] = src.with_raw(raw, validity)
+    for spec, (data, validity) in zip(aggregates, out_specs):
+        out_field = out_schema.field(spec.alias)
+        if data is None:
+            # float64 as its bits: exact through every later move
+            data = jnp.asarray(exact[spec.alias].view(np.int64))
+        dtype = ("int64" if spec.func in ("count", "count_distinct")
+                 else out_field.dtype)
+        columns[out_field.name] = DeviceColumn(data, dtype,
+                                               validity=validity)
+    return ColumnBatch(out_schema, columns)
+
+
+@instrumented_jit("aggregate.group_finish", scope="hs.aggregate",
+                  static_argnames=("plan", "num_groups"))
+def _group_finish(segment_ids, keys, columns, plan, num_groups: int):
+    """Every reduction of the group-by after its grouping sort, as ONE
+    program: each group's first row (a `searchsorted` of the sorted
+    segment ids) and its keys out of `keys`, then each aggregate of
+    `plan` — `(func, dtype, out_dtype, exact)` a spec, func `count_rows`
+    for count(*) — over its `columns` entry, the (raw, validity) of its
+    sorted column. An `exact` spec (integer avg / stddev) returns only its validity; the
+    host has finished its value (`_finish_exact`). SQL null semantics
+    as the module docstring gives them."""
+    import jax
+    import jax.numpy as jnp
+
+    from hyperspace_tpu.io.columnar import HOST_NP_DTYPES
+    from hyperspace_tpu.ops.keys import column_sort_lanes
+
+    rows = segment_ids.shape[0]
+    firsts = jnp.searchsorted(segment_ids,
+                              jnp.arange(num_groups, dtype=jnp.int32),
+                              side="left")
+    out_keys = tuple(
+        (jnp.take(raw, firsts),
+         jnp.take(validity, firsts) if validity is not None else None)
+        for raw, validity in keys)
+
+    def segment_sum(x):
+        return jax.ops.segment_sum(x, segment_ids, num_segments=num_groups)
+
+    def segment_min(x):
+        return jax.ops.segment_min(x, segment_ids, num_segments=num_groups)
+
+    def segment_max(x):
+        return jax.ops.segment_max(x, segment_ids, num_segments=num_groups)
+
+    out = []
+    for (func, dtype, out_dtype, exact), entry in zip(plan, columns):
+        if func == "count_rows":
+            out.append((segment_sum(jnp.ones(rows, dtype=jnp.int64)), None))
             continue
-        if spec.func == "count_distinct":
+        src = DeviceColumn(entry[0], dtype, entry[1])
+        valid = (src.validity if src.validity is not None
+                 else jnp.ones(rows, dtype=bool))
+        counts = segment_sum(valid.astype(jnp.int64))
+        if func == "count":
+            out.append((counts, None))
+            continue
+        if func == "count_distinct":
             # Distinct non-null values per group: ONE more device sort
             # keyed (segment, invalid-last, *value lanes), then count run
             # starts at valid rows. Strings count by dictionary code
@@ -304,28 +350,24 @@ def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
             data = jax.ops.segment_sum(
                 (run_start & (inv_s == 0)).astype(jnp.int64), seg_s,
                 num_segments=num_groups)
-            columns[out_field.name] = DeviceColumn(data, "int64")
+            out.append((data, None))
+            continue
+        validity_out = counts > 0
+        if exact:
+            out.append((None, validity_out if func == "avg"
+                        else counts > 1))
             continue
         values = src.data
-        validity_out = counts > 0
-        if exact.get(spec.alias) is not None:
-            # float64 as its bits: exact through every later move
-            columns[out_field.name] = DeviceColumn(
-                jnp.asarray(exact[spec.alias].view(np.int64)), "float64",
-                validity=validity_out if spec.func == "avg"
-                else counts > 1)
-            continue
-        if spec.func in ("sum", "avg"):
-            acc_dtype = (jnp.float64 if out_field.dtype == "float64"
+        if func in ("sum", "avg"):
+            acc_dtype = (jnp.float64 if out_dtype == "float64"
                          else jnp.int64)
-            total = jax.ops.segment_sum(
-                jnp.where(valid, values, 0).astype(acc_dtype), segment_ids,
-                num_segments=num_groups)
-            if spec.func == "sum":
+            total = segment_sum(
+                jnp.where(valid, values, 0).astype(acc_dtype))
+            if func == "sum":
                 data = total
             else:
                 data = total.astype(jnp.float64) / jnp.maximum(counts, 1)
-        elif spec.func == "stddev":
+        elif func == "stddev":
             # Sample stddev (SQL stddev_samp) via TWO passes: per-group
             # mean, then squared deviations — the one-pass sum-of-squares
             # identity catastrophically cancels in float64 when
@@ -333,29 +375,22 @@ def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
             # 2 non-null inputs.
             x = jnp.where(valid, values, 0).astype(jnp.float64)
             cnt = counts.astype(jnp.float64)
-            mu = jax.ops.segment_sum(
-                x, segment_ids, num_segments=num_groups) / jnp.maximum(cnt, 1)
+            mu = segment_sum(x) / jnp.maximum(cnt, 1)
             dev = jnp.where(valid, x - jnp.take(mu, segment_ids), 0.0)
-            var = jax.ops.segment_sum(
-                dev * dev, segment_ids,
-                num_segments=num_groups) / jnp.maximum(cnt - 1, 1)
+            var = segment_sum(dev * dev) / jnp.maximum(cnt - 1, 1)
             data = jnp.sqrt(jnp.maximum(var, 0.0))
             validity_out = counts > 1
-        elif spec.func == "min":
+        elif func == "min":
             big = _dtype_max(values.dtype)
-            data = jax.ops.segment_min(jnp.where(valid, values, big),
-                                       segment_ids, num_segments=num_groups)
+            data = segment_min(jnp.where(valid, values, big))
         else:  # max
             small = _dtype_min(values.dtype)
-            data = jax.ops.segment_max(jnp.where(valid, values, small),
-                                       segment_ids, num_segments=num_groups)
+            data = segment_max(jnp.where(valid, values, small))
         # Validity is attached unconditionally: deciding with
         # `bool(any(~validity_out))` would cost one blocking device sync
         # per aggregate; an all-True mask is semantically identical.
-        columns[out_field.name] = DeviceColumn(
-            data.astype(_NP_OF[out_field.dtype]), out_field.dtype,
-            validity=validity_out)
-    return ColumnBatch(out_schema, columns)
+        out.append((data.astype(HOST_NP_DTYPES[out_dtype]), validity_out))
+    return out_keys, tuple(out)
 
 
 def _dtype_max(dtype):

@@ -148,13 +148,11 @@ def _device_probe(arrays, valid, table, mins, maxs, ranges):
     one compile per probe shape, whatever the build side holds."""
     global _probe_jit
     if _probe_jit is None:
-        import jax
         import jax.numpy as jnp
 
-        from hyperspace_tpu.telemetry import device_scoped
+        from hyperspace_tpu.telemetry import instrumented_jit
 
-        @jax.jit
-        @device_scoped("hs.join.broadcast")
+        @instrumented_jit("broadcast_join.probe", scope="hs.join.broadcast")
         def _broadcast_probe(arrays, valid, table, mins, maxs, ranges):
             return _lookup(jnp, arrays, valid, table, mins, maxs, ranges)
 

@@ -25,7 +25,6 @@ merge compaction (`ops/merge.py`) — the mesh-sharded distributed join
 
 from __future__ import annotations
 
-from functools import partial
 from typing import List, Sequence, Tuple
 
 import numpy as np
@@ -34,6 +33,7 @@ import hyperspace_tpu._jax_config  # noqa: F401
 from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.io.columnar import ColumnBatch, unify_string_columns
 from hyperspace_tpu.ops import keys as keymod
+from hyperspace_tpu.telemetry import instrumented_jit
 
 _I32_MAX = np.int32(np.iinfo(np.int32).max)
 
@@ -77,7 +77,8 @@ def encode_group_ids(left: ColumnBatch, right: ColumnBatch,
     return _encode_core(tuple(lane_operands), l_valid, r_valid, n)
 
 
-@partial(__import__("jax").jit, static_argnames=("n",))
+@instrumented_jit("bucketed_join.encode", scope="hs.join.match",
+                  static_argnames=("n",))
 def _encode_core(lane_operands, l_valid, r_valid, n: int):
     import jax
     import jax.numpy as jnp

@@ -12,7 +12,6 @@ native 32-bit operands instead of emulated 64-bit compares on the VPU.
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Sequence, Tuple
 
 import hyperspace_tpu._jax_config  # noqa: F401
@@ -20,6 +19,7 @@ from hyperspace_tpu.io.columnar import (ColumnBatch, batch_to_tree,
                                         tree_to_batch)
 from hyperspace_tpu.ops import keys as keymod
 from hyperspace_tpu.ops.pallas.hash_kernel import pallas_available
+from hyperspace_tpu.telemetry import instrumented_jit
 
 
 def _tree_hash_lanes(entry):
@@ -84,8 +84,8 @@ def _tree_bucket_ids(tree, key_names: Tuple[str, ...], num_buckets: int,
     return (h % jnp.uint32(num_buckets)).astype(jnp.int32)
 
 
-@partial(__import__("jax").jit,
-         static_argnames=("key_names", "num_buckets", "use_pallas"))
+@instrumented_jit("build.build_core", scope="hs.build",
+                  static_argnames=("key_names", "num_buckets", "use_pallas"))
 def _build_core(tree, key_names: Tuple[str, ...], num_buckets: int,
                 use_pallas: bool = False):
     import jax
@@ -139,9 +139,9 @@ def _entry_assemble(entry):
     return entry
 
 
-@partial(__import__("jax").jit,
-         static_argnames=("key_names", "num_buckets", "n_chunks",
-                          "use_pallas"))
+@instrumented_jit("build.perm_core", scope="hs.build",
+                  static_argnames=("key_names", "num_buckets", "n_chunks",
+                                   "use_pallas"))
 def _perm_core(key_tree, key_names: Tuple[str, ...], num_buckets: int,
                n_chunks: int, use_pallas: bool = False):
     """Permutation-only build core: hash + ONE stable (bucket, *keys) sort

@@ -126,8 +126,7 @@ def compact_indices(mask, count: int):
         from jax import lax
 
         @partial(telemetry.instrumented_jit, "hs.compact",
-                 static_argnames=("size",))
-        @telemetry.device_scoped("hs.compact")
+                 scope="hs.compact", static_argnames=("size",))
         def hs_compact(mask, size):
             rows = mask.shape[0]
             # the sort's keys are 32 bits, one of them the dead rows',
@@ -155,8 +154,8 @@ def bucket_survivors(mask, lengths):
     if _segsum_jit is None:
         import jax.numpy as jnp
 
-        @partial(telemetry.instrumented_jit, "hs.segsum")
-        @telemetry.device_scoped("hs.segsum")
+        @partial(telemetry.instrumented_jit, "hs.segsum",
+                 scope="hs.segsum")
         def hs_segsum(mask, lengths):
             prefix = _prefix(mask)
             ends = _running(lengths)

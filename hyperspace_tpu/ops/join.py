@@ -36,7 +36,7 @@ from typing import List, Sequence, Tuple
 from hyperspace_tpu import telemetry
 from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.io.columnar import ColumnBatch
-from hyperspace_tpu.telemetry import device_scoped
+from hyperspace_tpu.telemetry import instrumented_jit
 
 
 def encode_join_keys(left: ColumnBatch, right: ColumnBatch,
@@ -138,9 +138,8 @@ def _runs_to_counts(differs, side_s, left_outer: bool):
     return counts, starts, rights, rstart
 
 
-@__import__("functools").partial(__import__("jax").jit,
-                                 static_argnames=("left_outer",))
-@device_scoped("hs.join.match")
+@instrumented_jit("join.counting_match_lanes", scope="hs.join.match",
+                  static_argnames=("left_outer",))
 def _counting_match_lanes(lanes_l, lanes_r, left_outer: bool):
     """The counting match directly over raw key LANES — ONE staged sort
     of (marker, *value lanes, side, orig) replaces the earlier two-sort
@@ -179,9 +178,8 @@ def _counting_match_lanes(lanes_l, lanes_r, left_outer: bool):
 HASH_MATCH_MIN_LANES = 4
 
 
-@__import__("functools").partial(__import__("jax").jit,
-                                 static_argnames=("left_outer",))
-@device_scoped("hs.join.match")
+@instrumented_jit("join.counting_match_lanes_hashed", scope="hs.join.match",
+                  static_argnames=("left_outer",))
 def _counting_match_lanes_hashed(lanes_l, lanes_r, left_outer: bool):
     """Hashed counting match: sort by (u64 key-hash, side, orig) — three
     sort keys regardless of key width — with the key lanes carried by
@@ -331,9 +329,8 @@ def counting_join_indices(l_ids, r_ids, how: str = "inner") -> Tuple:
                             total, left_outer)
 
 
-@__import__("functools").partial(__import__("jax").jit,
-                                 static_argnames=("left_outer",))
-@device_scoped("hs.join.match")
+@instrumented_jit("join.counting_match", scope="hs.join.match",
+                  static_argnames=("left_outer",))
 def _counting_match(l_ids, r_ids, left_outer: bool):
     """The counting match in id space: one stable sort of (id, side)
     carrying the row numbers, then `_runs_to_counts`."""
@@ -353,9 +350,8 @@ def _counting_match(l_ids, r_ids, left_outer: bool):
     return counts, starts, rights, rstart, orig_s
 
 
-@__import__("functools").partial(
-    __import__("jax").jit, static_argnames=("total", "left_outer"))
-@device_scoped("hs.join.expand")
+@instrumented_jit("join.counting_expand", scope="hs.join.expand",
+                  static_argnames=("total", "left_outer"))
 def _counting_expand(counts, starts, rights, rstart, orig_s, total: int,
                      left_outer: bool):
     import jax.numpy as jnp

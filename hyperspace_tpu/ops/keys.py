@@ -22,6 +22,7 @@ from typing import List
 
 import hyperspace_tpu._jax_config  # noqa: F401
 from hyperspace_tpu.io.columnar import DeviceColumn
+from hyperspace_tpu.telemetry import instrumented_jit
 
 
 def _float_order_bits(data, int_dtype, uint_dtype, sign_bit):
@@ -225,7 +226,7 @@ def _staged_perm(operands):
     return _staged_sort(operands)[0]
 
 
-@__import__("jax").jit
+@instrumented_jit("keys.staged_perm", scope="hs.sort")
 def _staged_perm_jit(operands):
     return _staged_perm(list(operands))
 

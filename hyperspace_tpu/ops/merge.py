@@ -39,7 +39,6 @@ permutation chunk-by-chunk while later chunks are still in flight.
 
 from __future__ import annotations
 
-from functools import partial
 from typing import List, Sequence, Tuple
 
 import numpy as np
@@ -50,9 +49,11 @@ from hyperspace_tpu.ops.build import LINK_CHUNK_ROWS, LINK_CHUNKS
 # ONE padded-layout builder and pow2 rounding for every [B, L] consumer
 # (join, distributed join, compaction) — they must stay in lockstep.
 from hyperspace_tpu.ops.bucketed_join import _padded_layout, next_pow2
+from hyperspace_tpu.telemetry import instrumented_jit
 
 
-@partial(__import__("jax").jit, static_argnames=("n_chunks",))
+@instrumented_jit("merge.bucket_sort", scope="hs.build",
+                  static_argnames=("n_chunks",))
 def _bucket_sort_core(lanes, l_idx, l_valid, flat_pick, n_chunks: int):
     """Batched within-bucket sort permutation.
 

@@ -17,7 +17,6 @@ queries (`index/serde/package.scala:64-167`); execution there is Spark's.
 
 from __future__ import annotations
 
-from functools import partial
 from typing import List, Sequence
 
 import numpy as np
@@ -25,6 +24,7 @@ import numpy as np
 import hyperspace_tpu._jax_config  # noqa: F401
 from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.io.columnar import ColumnBatch, unify_string_columns
+from hyperspace_tpu.telemetry import instrumented_jit
 
 
 def _zeroed(xp, data, valid):
@@ -66,7 +66,8 @@ def _device_lanes(left: ColumnBatch, right: ColumnBatch,
     return lanes
 
 
-@partial(__import__("jax").jit, static_argnames=("n", "anti"))
+@instrumented_jit("setops.membership", scope="hs.setop",
+                  static_argnames=("n", "anti"))
 def _setop_core(lanes, n: int, anti: bool):
     import jax.numpy as jnp
 

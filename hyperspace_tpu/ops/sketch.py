@@ -153,7 +153,8 @@ def _bloom_jit():
     global _bloom_kernel_jit
     if _bloom_kernel_jit is None:
         from hyperspace_tpu.telemetry import instrumented_jit
-        _bloom_kernel_jit = instrumented_jit("sketch.bloom")(_bloom_kernel)
+        _bloom_kernel_jit = instrumented_jit(
+            "sketch.bloom", scope="hs.sketch")(_bloom_kernel)
     return _bloom_kernel_jit
 
 
@@ -245,7 +246,8 @@ def zones(col) -> dict:
         global _zones_jit
         if _zones_jit is None:
             from hyperspace_tpu.telemetry import instrumented_jit
-            _zones_jit = instrumented_jit("sketch.zones")(_zones_kernel)
+            _zones_jit = instrumented_jit(
+                "sketch.zones", scope="hs.sketch")(_zones_kernel)
         data = col.data
         if is_bool:
             data = data.astype(jnp.int32)

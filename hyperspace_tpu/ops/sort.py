@@ -137,7 +137,7 @@ def _topk_threshold(prefix, k: int):
         from hyperspace_tpu.telemetry import instrumented_jit
 
         @partial(instrumented_jit, "sort.topk_threshold",
-                 static_argnames=("k",))
+                 scope="hs.topk", static_argnames=("k",))
         def run(prefix, k):
             (sorted_prefix,) = jax.lax.sort([prefix], num_keys=1)
             thresh = sorted_prefix[k - 1]

@@ -123,11 +123,9 @@ def make_partial_step(mesh, num_lanes: int, specs_meta, capacity: int):
 
         from hyperspace_tpu.parallel.mesh import (compat_shard_map,
                                                   row_spec)
-        from hyperspace_tpu.telemetry import (device_scoped,
-                                              instrumented_jit)
+        from hyperspace_tpu.telemetry import instrumented_jit
         rows_spec = row_spec(mesh)
 
-        @device_scoped("hs.mesh.aggregate")
         def shard_partials(tree):
             return _shard_partials(tree, num_lanes=num_lanes,
                                    specs_meta=specs_meta,
@@ -140,7 +138,8 @@ def make_partial_step(mesh, num_lanes: int, specs_meta, capacity: int):
                                                  tree),),
                 out_specs=rows_spec, check_vma=False)(tree)
 
-        return instrumented_jit("mesh.aggregate_step", aggregate_step)
+        return instrumented_jit("mesh.aggregate_step", aggregate_step,
+                                scope="hs.mesh.aggregate")
 
     return _cached_program(
         ("aggregate", mesh, num_lanes, specs_meta, capacity), build)

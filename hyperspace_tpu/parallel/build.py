@@ -203,7 +203,7 @@ def make_distributed_build_step(mesh, key_names: Tuple[str, ...],
     # tracker makes that cost (and any future retrace storm here)
     # visible as compile.mesh.build_step.traces instead of silent wall.
     from hyperspace_tpu.telemetry import instrumented_jit
-    return instrumented_jit("mesh.build_step", step)
+    return instrumented_jit("mesh.build_step", step, scope="hs.mesh.build")
 
 
 def distributed_build(batch: ColumnBatch, key_columns: Sequence[str],

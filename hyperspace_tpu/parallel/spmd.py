@@ -1359,7 +1359,7 @@ def _match_program(mesh, n_keys: int, Cl: int, Cr: int,
     import jax
     import jax.numpy as jnp
 
-    from hyperspace_tpu.telemetry import device_scoped, instrumented_jit
+    from hyperspace_tpu.telemetry import instrumented_jit
 
     S = total_shards(mesh)
 
@@ -1367,7 +1367,6 @@ def _match_program(mesh, n_keys: int, Cl: int, Cr: int,
         # Named for the trace (the device's program is
         # `jit_spmd_join_match`); every op of it under the device scope
         # `hs.mesh.join`.
-        @device_scoped("hs.mesh.join")
         def spmd_join_match(l_datas, l_ok, l_valid, r_datas, r_ok,
                             r_valid, l_remaps, r_remaps, r_hash_tables):
             l_d = list(l_datas)
@@ -1437,7 +1436,8 @@ def _match_program(mesh, n_keys: int, Cl: int, Cr: int,
                 for x in (starts, rights, rstart, pos_s, r_gid2d))
             return state, un_gid, (shard_total, un_counts, route_ovf)
 
-        return instrumented_jit("mesh.spmd_join_match", spmd_join_match)
+        return instrumented_jit("mesh.spmd_join_match", spmd_join_match,
+                                scope="hs.mesh.join")
 
     key = ("join_match", mesh, n_keys, Cl, Cr, left_outer, need_right,
            repartition_to, route_capacity, membership, remap_idx)
@@ -1456,15 +1456,15 @@ def _expand_program(Cl: int, cap: int):
     `cap` output slots a shard (a rung of the ladder, part of the key):
     match state in, (li, ri) [S, cap] out, nothing read back. The mesh
     and the row sharding are the state's own."""
-    from hyperspace_tpu.telemetry import device_scoped, instrumented_jit
+    from hyperspace_tpu.telemetry import instrumented_jit
 
     def build():
         # `jit_spmd_join_expand` in a trace, under the match's scope.
-        @device_scoped("hs.mesh.join")
         def spmd_join_expand(starts, rights, rstart, pos_s, r_gid):
             return _expand(starts, rights, rstart, pos_s, r_gid, Cl, cap)
 
-        return instrumented_jit("mesh.spmd_join_expand", spmd_join_expand)
+        return instrumented_jit("mesh.spmd_join_expand", spmd_join_expand,
+                                scope="hs.mesh.join")
 
     return _cached_program(("join_expand", Cl, cap), build)
 
@@ -1501,11 +1501,11 @@ def _gather_prefixes(arrays, counts, width: int, as_int32: bool = False):
     if _prefix_gather_jit is None:
         from hyperspace_tpu.telemetry import instrumented_jit
 
-        @instrumented_jit("mesh.spmd_gather")
+        @instrumented_jit("mesh.spmd_gather", scope="hs.gather")
         def _take_flat(arrs, ix):
             return tuple(jnp.take(a.reshape(-1), ix) for a in arrs)
 
-        @instrumented_jit("mesh.spmd_gather_i32")
+        @instrumented_jit("mesh.spmd_gather_i32", scope="hs.gather")
         def _take_flat_i32(arrs, ix):
             return tuple(jnp.take(a.reshape(-1), ix).astype(jnp.int32)
                          for a in arrs)
@@ -1882,7 +1882,8 @@ def repartition_sharded(batch: ColumnBatch, key_columns: Sequence[str],
         program = _cached_program(
             ("repartition", mesh, key_names, num_buckets, capacity),
             lambda: instrumented_jit("mesh.spmd_repartition",
-                                     make_step()))
+                                     make_step(),
+                                     scope="hs.mesh.repartition"))
         per_row = sum(
             int(np.dtype(getattr(e["data"], "dtype", np.int64)).itemsize)
             + (1 if "validity" in e else 0)
@@ -1930,19 +1931,19 @@ def sharded_predicate_mask(sh: ShardedBatch, expression,
     from hyperspace_tpu import telemetry
     from hyperspace_tpu.engine.compiler import compile_predicate
     from hyperspace_tpu.io.columnar import batch_to_tree, tree_to_batch
-    from hyperspace_tpu.telemetry import device_scoped, instrumented_jit
+    from hyperspace_tpu.telemetry import instrumented_jit
 
     count_string_predicate_lookups(expression, sh.batch)
     tree, aux = batch_to_tree(sh.batch, computes_on=())
     schema = sh.batch.schema
 
     def build():
-        @device_scoped("hs.mesh.filter")
         def spmd_filter(t, valid):
             b = tree_to_batch(t, schema, aux)
             return compile_predicate(expression, b) & valid
 
-        return instrumented_jit("mesh.spmd_filter", spmd_filter)
+        return instrumented_jit("mesh.spmd_filter", spmd_filter,
+                                scope="hs.mesh.filter")
 
     try:
         if not reuse:
@@ -2112,7 +2113,7 @@ def _batched_predicate_program(shape: tuple, dtypes: tuple,
             return jnp.broadcast_to(
                 total, (iconst.shape[0],) + total.shape[1:])
 
-        return instrumented_jit("serve.batch", body)
+        return instrumented_jit("serve.batch", body, scope="hs.serve.batch")
 
     return _cached_program(("serve.batch", shape, dtypes, valid_flags),
                            build)

@@ -3,10 +3,12 @@ point.
 
 A retrace storm is invisible in wall-time telemetry — the cost hides
 inside whichever dispatch happened to trace — so every jitted entry
-point in the package routes through `instrumented_jit(name)` instead of
-calling `jax.jit` directly (`scripts/check_metrics_coverage.py` fails
-the build on any direct `jax.jit` call outside this module). Each call
-then records:
+point in the package routes through `instrumented_jit(name, scope=...)`
+instead of calling `jax.jit` directly (`scripts/check_metrics_coverage.py`
+fails the build on `jax.jit` in any spelling outside this module, and on
+an `instrumented_jit` whose scope is not in `DEVICE_SCOPES`). The
+program's ops carry its device scope in a capture, and each call then
+records:
 
 - a `compile` span on the executing thread whenever XLA actually traced
   (its own category — and track — in the Perfetto export), covering
@@ -254,18 +256,20 @@ def _capture_cost(name: str, jfn, args, kwargs) -> Optional[tuple]:
 
 def device_scoped(scope: str):
     """Decorator: the ops of `fn` carry the device scope `scope`
-    (`telemetry.DEVICE_SCOPES`) in a capture. For a function that is
-    only ever TRACED inside a jitted program (put it under the jit
-    decorator): called eagerly it would compile at every call.
+    (`telemetry.DEVICE_SCOPES`) in a capture. `instrumented_jit` applies
+    it to every program it builds; used directly, it names a piece
+    traced INSIDE a program (called eagerly it would compile at every
+    call).
 
     A bare `jax.named_scope` is not enough here. This package asks jax
     for one frame per MLIR location (`_jax_config.py`, for the compile
     cache's sake), and in that form XLA's op metadata keeps the name
-    stack only for ops inside a NESTED call: a primitive traced directly
-    in the program's body comes out as `gather`, one traced inside
-    a nested jit as `jit(f)/hs.compact/jit(f)/gather`. So the
-    scope wraps a nested jit of the function; XLA inlines the call, the
-    program computes what it did."""
+    stack only for ops inside a NESTED call that the scope encloses: a
+    primitive traced directly in the program's body, or in a nested
+    call's body under a scope opened there, comes out without it; one
+    traced inside a nested jit as `jit(f)/hs.compact/jit(f)/gather`. So
+    the scope wraps a nested jit of the function; XLA inlines the call,
+    the program computes what it did."""
     def decorate(fn):
         @functools.wraps(fn)
         def scoped(*args, **kwargs):
@@ -283,20 +287,30 @@ def device_scoped(scope: str):
     return decorate
 
 
-def instrumented_jit(name: str, fn=None, **jit_kwargs):
-    """`jax.jit` with compile observability. Use exactly like jit:
+def instrumented_jit(name: str, fn=None, *, scope: str, **jit_kwargs):
+    """`jax.jit` with compile observability, its ops under the device
+    scope `scope` (a key of `telemetry.DEVICE_SCOPES`, through
+    `device_scoped`). Use exactly like jit:
 
-        run = instrumented_jit("fusion.run_stage",
+        run = instrumented_jit("fusion.run_stage", scope="hs.stage",
                                static_argnames=("prog",))(body)
 
-    The returned callable forwards `clear_cache` and exposes
+    The program keeps the function's name (`jit_<function>` in a
+    capture). The returned callable forwards `clear_cache` and exposes
     `cache_size()` (the live executable count, where jax provides it).
-    Usable as `instrumented_jit(name, fn)` or as a decorator factory.
+    Usable as `instrumented_jit(name, fn, scope=...)` or as a decorator
+    factory.
     """
+    if scope not in _trace.DEVICE_SCOPES:
+        raise ValueError(f"instrumented jit {name!r}: device scope "
+                         f"{scope!r} is not in telemetry.DEVICE_SCOPES")
     if fn is None:
-        return lambda f: instrumented_jit(name, f, **jit_kwargs)
+        return lambda f: instrumented_jit(name, f, scope=scope,
+                                          **jit_kwargs)
 
     import jax
+
+    fn = device_scoped(scope)(fn)
 
     @functools.wraps(fn)
     def body(*args, **kwargs):

@@ -29,8 +29,8 @@ chip to the layer the host was in. The ring is for the Chrome export;
 the annotations are for captures. No conf key, no environment
 variable: a sink records when its owner runs (`enable_tracing()`, a
 profiler session), and with neither a span costs two flag reads.
-`DEVICE_SCOPES` are the matching names ON the device
-(`jax.named_scope` inside jitted programs).
+`DEVICE_SCOPES` are the matching names ON the device: each jitted
+program's scope (`instrumented_jit(name, scope=...)`).
 """
 
 from __future__ import annotations
@@ -130,33 +130,56 @@ SPAN_NAMES = {
                          "traced + compiled",
 }
 
-# Names on the DEVICE: scopes inside jitted programs
-# (`telemetry.device_scoped`), so an op's scope path in a capture (the
-# `tf_op` of an `XLA Ops` event's metadata:
-# `jit(hs_compact)/hs.compact/jit(hs_compact)/gather:`) says which
-# piece it belongs to whatever implements it. Metadata only: no program
-# computes anything else.
+# Names on the DEVICE: the scope every jitted program carries
+# (`instrumented_jit(name, scope=...)`, through `device_scoped`), so an
+# op's scope path in a capture (the `tf_op` of an `XLA Ops` event's
+# metadata: `jit(hs_compact)/hs.compact/jit(hs_compact)/gather:`) says
+# which piece it belongs to whatever implements it. An op with none was
+# dispatched eagerly. Metadata only: no program computes anything else.
 DEVICE_SCOPES = {
-    "hs.predicate": "a filter predicate's mask",
+    "hs.predicate": "a filter predicate's mask, where a fused stage's "
+                    "program traces it (an eager filter's mask has none)",
     "hs.segsum": "per-bucket survivor counts (the mask's prefix sum at "
                  "the buckets' ends)",
     "hs.compact": "mask -> survivor indices (rank select or sort select)",
-    "hs.join.match": "the counting join's match program",
+    "hs.gather": "a batch's row gather: every column and validity "
+                 "through one index vector (`jit__take_all`; on a mesh "
+                 "`jit__take_flat`, `jit__take_flat_i32`)",
+    "hs.stage": "a fused stage's own program (`jit__run`) and its "
+                "deferred build-side gathers (`jit_run`); "
+                "`hs.predicate` and an inlined `hs.join.broadcast` nest "
+                "inside it",
+    "hs.topk": "ORDER BY ... LIMIT's threshold over the packed sort "
+               "prefix (`jit_run` of `sort.topk_threshold`)",
+    "hs.sort": "ORDER BY's sort permutation (`jit__staged_perm_jit`)",
+    "hs.join.match": "the counting join's match program, and the "
+                     "bucketed join's key encode (`jit__encode_core`)",
     "hs.join.expand": "the counting join's expansion to row pairs",
     "hs.join.broadcast": "the broadcast join's direct-address probe "
                          "(`jit__broadcast_probe`, or inlined into a "
                          "fused stage's program)",
+    "hs.setop": "INTERSECT / EXCEPT's membership sort (`jit__setop_core`)",
     "hs.aggregate": "the group-by's programs: the grouping sort "
-                    "(`jit__group_phase_a`, `jit__group_phase_a_hashed`) "
-                    "and the exact integer moments of avg / stddev "
-                    "(`jit__exact_moments`)",
-    # mesh: the three SPMD programs, on every chip's plane
+                    "(`jit__group_phase_a`, `jit__group_phase_a_hashed`), "
+                    "the exact integer moments of avg / stddev "
+                    "(`jit__exact_moments`) and every other reduction "
+                    "after the sort (`jit__group_finish`)",
+    "hs.build": "an index build's hash and sort (`jit__build_core`, "
+                "`jit__perm_core`) and a compaction's batched bucket "
+                "sort (`jit__bucket_sort_core`)",
+    "hs.sketch": "a data-skipping sketch's kernel (bloom, zone maps)",
+    "hs.serve.batch": "the batched serve lane's stacked predicates "
+                      "(`jit_body`)",
+    # mesh: the SPMD programs, on every chip's plane
     "hs.mesh.filter": "the SPMD predicate mask (`jit_spmd_filter`)",
     "hs.mesh.join": "the SPMD join's two programs: the match "
                     "(`jit_spmd_join_match`) and the expansion sized by "
                     "its totals (`jit_spmd_join_expand`)",
     "hs.mesh.aggregate": "the per-shard partial aggregation "
                          "(`jit_aggregate_step`)",
+    "hs.mesh.build": "the mesh build step (`jit_step`)",
+    "hs.mesh.repartition": "the SPMD repartition of a side by bucket "
+                           "(`jit_step`)",
 }
 
 _tracer: Optional["Tracer"] = None

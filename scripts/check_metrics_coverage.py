@@ -17,10 +17,14 @@ or otherwise routed around the instrumentation).
 Compile coverage rides the same check: every `jax.jit` entry point
 must route through `telemetry.compilation.instrumented_jit` (the
 compile-span stamp — trace counters, retrace-cause events, Perfetto
-compile track). A direct `jax.jit(...)` / `partial(jax.jit, ...)`
-call anywhere in the package besides telemetry/compilation.py is a
-jit entry point that can trace without being seen, and fails the
-lint; so does a registered wrapper missing its
+compile track — and the program's device scope). `jax.jit` in any
+spelling (`jax.jit(...)`, `@jax.jit`, `__import__("jax").jit`,
+`partial(jax.jit, ...)`, `from jax import jit`) anywhere in the package
+besides telemetry/compilation.py is a jit entry point that can trace
+without being seen, and fails the lint; so does an `instrumented_jit`
+whose `scope=` is missing or not a literal key of `DEVICE_SCOPES`, a
+`DEVICE_SCOPES` that differs from docs/telemetry.md's device-scope
+table, and a registered wrapper missing its
 `__compile_span_instrumented__` stamp.
 
 Runs in the tier-1 flow via `tests/test_telemetry.py`; also runnable
@@ -48,21 +52,96 @@ def _all_subclasses(cls):
 
 
 # Direct jit construction — the only sanctioned caller is the
-# instrumented_jit wrapper itself. Doc mentions of the NAME don't
-# match (the pattern requires a call/partial form).
-_RAW_JIT_RE = re.compile(r"jax\.jit\s*\(|partial\(\s*jax\.jit\b")
+# instrumented_jit wrapper itself. Read from the syntax tree, so a
+# mention of the name in prose never matches and no spelling escapes.
 _JIT_ALLOWED = os.path.join("telemetry", "compilation.py")
+_SCOPE_TABLE_HEADER = "| Device scope | Where | What |"
 
 
-def check_jit_entry_points(package_dir: str):
-    """Source lint: no direct `jax.jit` outside the sanctioned wrapper
-    module, and every registered wrapper carries the compile-span
-    stamp."""
+def _is_jax(node) -> bool:
+    """`jax`, or `__import__("jax")`."""
+    if isinstance(node, ast.Name):
+        return node.id == "jax"
+    return (isinstance(node, ast.Call) and isinstance(node.func, ast.Name)
+            and node.func.id == "__import__" and node.args
+            and isinstance(node.args[0], ast.Constant)
+            and node.args[0].value == "jax")
+
+
+def _names_instrumented_jit(node) -> bool:
+    return ((isinstance(node, ast.Name) and node.id == "instrumented_jit")
+            or (isinstance(node, ast.Attribute)
+                and node.attr == "instrumented_jit"))
+
+
+def _jit_lint(tree, scopes):
+    """(lineno, message) of every raw jit and every instrumented_jit
+    call whose scope is not a literal key of `scopes`."""
+    found = []
+    for node in ast.walk(tree):
+        if (isinstance(node, ast.Attribute) and node.attr == "jit"
+                and _is_jax(node.value)):
+            found.append((node.lineno, "jit entry point lacks the "
+                          "compile-span stamp — route it through "
+                          "telemetry.instrumented_jit"))
+        elif (isinstance(node, ast.ImportFrom) and node.module == "jax"
+              and any(a.name == "jit" for a in node.names)):
+            found.append((node.lineno, "`from jax import jit`: route the "
+                          "entry point through telemetry.instrumented_jit"))
+        elif isinstance(node, ast.Call):
+            func = node.func
+            is_partial = ((isinstance(func, ast.Name)
+                           and func.id == "partial")
+                          or (isinstance(func, ast.Attribute)
+                              and func.attr == "partial"))
+            if not (_names_instrumented_jit(func) or (
+                    is_partial and node.args
+                    and _names_instrumented_jit(node.args[0]))):
+                continue
+            scope = next((k.value for k in node.keywords
+                          if k.arg == "scope"), None)
+            if scope is None:
+                found.append((node.lineno, "instrumented_jit without a "
+                              "scope= — name the program's device scope "
+                              "(telemetry.DEVICE_SCOPES)"))
+            elif not (isinstance(scope, ast.Constant)
+                      and scope.value in scopes):
+                shown = (repr(scope.value) if isinstance(scope, ast.Constant)
+                         else "a computed value")
+                found.append((node.lineno, f"instrumented_jit scope {shown} "
+                              "is not a key of telemetry.DEVICE_SCOPES"))
+    return found
+
+
+def device_scope_table(doc: str):
+    """The scope names of docs/telemetry.md's device-scope table."""
+    lines = doc.splitlines()
+    try:
+        i = lines.index(_SCOPE_TABLE_HEADER) + 2
+    except ValueError:
+        return None
+    names = []
+    while i < len(lines) and lines[i].startswith("|"):
+        m = re.match(r"\|\s*`([^`]+)`", lines[i])
+        if m:
+            names.append(m.group(1))
+        i += 1
+    return names
+
+
+def check_jit_entry_points(package_dir: str, doc_path=None):
+    """Source lint: no `jax.jit` in any spelling outside the sanctioned
+    wrapper module, every `instrumented_jit` names a scope of
+    `DEVICE_SCOPES`, `DEVICE_SCOPES` is docs/telemetry.md's device-scope
+    table (where `doc_path` is given), and every registered wrapper
+    carries the compile-span stamp."""
+    from hyperspace_tpu.telemetry import DEVICE_SCOPES
+
     failures = []
     for root, _dirs, files in os.walk(package_dir):
         if "__pycache__" in root:
             continue
-        for fname in files:
+        for fname in sorted(files):
             if not fname.endswith(".py"):
                 continue
             path = os.path.join(root, fname)
@@ -70,12 +149,29 @@ def check_jit_entry_points(package_dir: str):
             if rel == _JIT_ALLOWED:
                 continue
             with open(path, encoding="utf-8") as f:
-                for lineno, line in enumerate(f, 1):
-                    if _RAW_JIT_RE.search(line):
-                        failures.append(
-                            f"hyperspace_tpu/{rel}:{lineno}: jit entry "
-                            "point lacks the compile-span stamp — route "
-                            "it through telemetry.instrumented_jit")
+                try:
+                    tree = ast.parse(f.read(), filename=path)
+                except SyntaxError:
+                    continue  # surfaced by the import walk instead
+            for lineno, message in sorted(_jit_lint(tree, DEVICE_SCOPES)):
+                failures.append(f"hyperspace_tpu/{rel}:{lineno}: {message}")
+    if doc_path is not None:
+        try:
+            with open(doc_path, encoding="utf-8") as f:
+                table = device_scope_table(f.read())
+        except OSError:
+            table = None
+        if table is None:
+            failures.append(f"{doc_path}: no device-scope table "
+                            f"({_SCOPE_TABLE_HEADER!r})")
+        else:
+            for name in sorted(set(DEVICE_SCOPES) - set(table)):
+                failures.append(f"{doc_path}: device scope {name!r} has no "
+                                "row in the device-scope table")
+            for name in sorted(set(table) - set(DEVICE_SCOPES)):
+                failures.append(f"{doc_path}: the device-scope table names "
+                                f"{name!r}, which telemetry.DEVICE_SCOPES "
+                                "lacks")
     from hyperspace_tpu.telemetry import compilation
     for name, wrapper in sorted(compilation.REGISTRY.items()):
         if not getattr(wrapper, "__compile_span_instrumented__", False):
@@ -1010,7 +1106,9 @@ def main() -> int:
                 "without emitting an action report")
 
     failures.extend(check_jit_entry_points(
-        os.path.dirname(hyperspace_tpu.__file__)))
+        os.path.dirname(hyperspace_tpu.__file__),
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "docs", "telemetry.md")))
     failures.extend(check_device_put_seam(
         os.path.dirname(hyperspace_tpu.__file__)))
     failures.extend(check_segment_cache_seam(

@@ -263,7 +263,7 @@ def test_instrumented_jit_retrace_agreement():
     from hyperspace_tpu.telemetry.compilation import instrumented_jit
 
     name = "test.retrace_agreement"
-    fn = instrumented_jit(name)(lambda x: x * 2)
+    fn = instrumented_jit(name, scope="hs.stage")(lambda x: x * 2)
     reg = telemetry.get_registry()
     base = reg.counter(f"compile.{name}.traces").value
     rec = telemetry.QueryMetrics("retrace probe")
@@ -295,7 +295,8 @@ def test_compile_span_lands_in_trace(tracing):
 
     from hyperspace_tpu.telemetry.compilation import instrumented_jit
 
-    fn = instrumented_jit("test.compile_span")(lambda x: x + 1)
+    fn = instrumented_jit("test.compile_span",
+                          scope="hs.stage")(lambda x: x + 1)
     fn(jnp.ones(4))
     spans = [e for e in tracing.events
              if e["ph"] == "X" and e.get("cat") == "compile"]

@@ -186,7 +186,7 @@ def test_instrumented_jit_charges_active_tenant():
     import jax.numpy as jnp
 
     fn = telemetry.instrumented_jit("test.tenancy_kernel",
-                                    lambda x: x * 2 + 1)
+                                    lambda x: x * 2 + 1, scope="hs.stage")
     x = jnp.arange(64)
     fn(x)  # cold: compile (compile time stays in the compile bucket)
 

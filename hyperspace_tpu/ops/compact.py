@@ -25,7 +25,9 @@ over the rows (the mask's int32 prefix sum, or one sort of row numbers):
   is the difference of the prefix at its two ends.
 
 Which select runs is decided from the two static shapes (`rows`,
-`size`) when the program is traced, by the measured costs below. The
+`size`) when the program is traced, by the measured costs below; the
+counting join's expansion (`ops/join._counting_expand`) chooses between
+the same two selects the same way. The
 indices are what `jnp.nonzero(mask, size=size, fill_value=0)` gives, to
 the bit, as int32 (int64 from 2**31 rows on). The programs compile per
 mask length and survivor count; the host syncs around them are the
@@ -117,13 +119,33 @@ def _rank_select(prefix, size: int):
     return jnp.where(reach < rows, reach, 0)
 
 
+_DEAD = 1 << 31  # a sort select's key bit for the rows it drops
+
+
+def _sort_select(mask, size: int, *payload, order=None):
+    """The first `size` entries of one sort that puts the `mask`'s rows
+    first, in ascending `order` (distinct values under 2**31 where the
+    mask is true; by default the row numbers): the sorted keys, which
+    are each kept row's `order` value and each dropped row's number with
+    the `_DEAD` bit set, and every `payload` operand carried along."""
+    import jax.numpy as jnp
+    from jax import lax
+    row = lax.iota(jnp.uint32, mask.shape[0])
+    kept = row if order is None else order.astype(jnp.uint32)
+    dead = jnp.uint32(_DEAD)
+    # the keys are distinct: a stable sort would carry a second operand
+    # of row numbers for nothing
+    ordered = lax.sort((jnp.where(mask, kept, row | dead), *payload),
+                       num_keys=1, is_stable=False)
+    return [operand[:size] for operand in ordered]
+
+
 def compact_indices(mask, count: int):
     """Row indices of the true entries of the device `mask`, ascending:
     `count` of them (entries past the last survivor are 0)."""
     global _compact_jit
     if _compact_jit is None:
         import jax.numpy as jnp
-        from jax import lax
 
         @partial(telemetry.instrumented_jit, "hs.compact",
                  scope="hs.compact", static_argnames=("size",))
@@ -134,13 +156,9 @@ def compact_indices(mask, count: int):
             sortable = rows < 2**31 and size <= rows
             if not sortable or _rank_select_wins(rows, size):
                 return _rank_select(_prefix(mask), size)
-            row = lax.iota(jnp.uint32, rows)
-            dead = jnp.uint32(1 << 31)
-            # the keys are distinct: a stable sort would carry a second
-            # operand of row numbers for nothing
-            first = lax.sort(jnp.where(mask, row, row | dead),
-                             is_stable=False)[:size]
-            return jnp.where(first < dead, first, 0).astype(jnp.int32)
+            first, = _sort_select(mask, size)
+            return jnp.where(first < jnp.uint32(_DEAD), first,
+                             0).astype(jnp.int32)
 
         _compact_jit = hs_compact
     return _compact_jit(mask, size=int(count))

@@ -21,8 +21,12 @@ space, two programs and one host sync:
    as payload, so runs come from sorted lanes there too;
 2. the total pair count is the one host sync; it sizes the result, which
    is materialized anyway;
-3. the expansion (`_counting_expand`, scope `hs.join.expand`): `repeat`
-   over the per-row counts and gathers of the output's size.
+3. the expansion (`_counting_expand`, scope `hs.join.expand`), sized by
+   the pairs: the sorted rows that own a pair are selected first (one
+   sort of the T rows, or for very few pairs a rank select over their
+   counts' running sum), then spread over their slots by a scatter of at
+   most one update a pair and a running sum; past the select no step
+   grows with T (`jnp.repeat` scattered one update per sorted row).
 
 `merge_join_indices` (two `searchsorted` calls over sorted dense ids
 from `encode_join_keys`) is the older formulation and no operator's
@@ -286,8 +290,8 @@ def counting_join_batch_indices(left: ColumnBatch, right: ColumnBatch,
     telemetry.annotate(match=match, keys=len(left_keys))
     if total == 0:
         return empty, empty
-    return _counting_expand(counts, starts, rights, rstart, orig_s,
-                            total, left_outer)
+    return _expand(counts, starts, rights, rstart, orig_s, total,
+                   left_outer)
 
 
 def counting_join_indices(l_ids, r_ids, how: str = "inner") -> Tuple:
@@ -300,9 +304,9 @@ def counting_join_indices(l_ids, r_ids, how: str = "inner") -> Tuple:
     `lax.sort` of the same rows runs in ~1s. So: sort (id, side,
     original index) once, derive per-id-run right-row counts and bracket
     starts by scans over the SORTED sequence (`_runs_to_counts`), and
-    expand matches with `jnp.repeat`. 4-5x faster end-to-end at 39M rows, and
-    callers no longer pre-sort their payload batches — indices come back
-    in original row space.
+    expand matches by `_counting_expand`. 4-5x faster end-to-end at 39M
+    rows, and callers no longer pre-sort their payload batches — indices
+    come back in original row space.
 
     Supports how='inner' and 'left_outer' (unmatched left rows appear
     once with right index -1); callers express right/full outer by
@@ -325,8 +329,8 @@ def counting_join_indices(l_ids, r_ids, how: str = "inner") -> Tuple:
     total = int(jnp.sum(counts))  # the one host sync
     if total == 0:
         return empty, empty
-    return _counting_expand(counts, starts, rights, rstart, orig_s,
-                            total, left_outer)
+    return _expand(counts, starts, rights, rstart, orig_s, total,
+                   left_outer)
 
 
 @instrumented_jit("join.counting_match", scope="hs.join.match",
@@ -350,22 +354,124 @@ def _counting_match(l_ids, r_ids, left_outer: bool):
     return counts, starts, rights, rstart, orig_s
 
 
+# One v5e at the benchmark's three expansions (4.6 M sorted rows to
+# 93,752 pairs, 31.7 M and 17.3 M to 2.9 M; PERF.md section 6):
+# an operand the select's sort carries costs up to 0.0029 ns per padded
+# sorted row per compare-exchange stage (0.0013 at 4.6 M rows), and
+# gathering it back costs 13-16 ns a selected row at 2.9 M of them (3 at
+# 93,752), so Q12's join gathers and q17's carry.
+_CARRY_STAGE_NS = 0.0029
+_GATHER_NS = 14.0
+
+
+def _expand_path(rows: int, total: int) -> str:
+    """Which select `_counting_expand` runs for `total` pairs over
+    `rows` sorted rows: the rank select of `ops/compact.py` where its
+    measured costs say it beats the sort, else the sort select."""
+    from hyperspace_tpu.ops import compact
+    return "rank" if compact._rank_select_wins(rows, total) else "select"
+
+
+def _carry_wins(rows: int, size: int) -> bool:
+    """Whether the sort select carries an operand for its `size` entries
+    more cheaply than they gather it back out of the `rows` rows."""
+    levels = max(rows - 1, 1).bit_length()
+    carry_ns = (1 << levels) * levels * (levels + 1) / 2 * _CARRY_STAGE_NS
+    return carry_ns < size * _GATHER_NS
+
+
+def _expand(counts, starts, rights, rstart, orig_s, total: int,
+            left_outer: bool):
+    """`_counting_expand`, with the path it takes on the join's record
+    (`expand`) and its fill, pairs over sorted rows, in the registry."""
+    rows = counts.shape[0]
+    telemetry.annotate(expand=_expand_path(rows, total))
+    telemetry.get_registry().histogram("join.expand.fill").observe(
+        total / rows)
+    return _counting_expand(counts, starts, rights, rstart, orig_s,
+                            total=total, left_outer=left_outer)
+
+
 @instrumented_jit("join.counting_expand", scope="hs.join.expand",
                   static_argnames=("total", "left_outer"))
 def _counting_expand(counts, starts, rights, rstart, orig_s, total: int,
                      left_outer: bool):
-    import jax.numpy as jnp
+    """The `total` (left, right) row pairs of a counting match, in
+    sorted-row order: sorted row r owns the `counts[r]` slots from
+    `starts[r]`, its left row is `orig_s[r]` and its k-th slot's right
+    row `orig_s[rstart[r] + k]` (-1 under `left_outer` where the row
+    has no right partner, `rights[r]` 0).
 
-    rows = jnp.repeat(jnp.arange(counts.shape[0], dtype=jnp.int32),
-                      counts, total_repeat_length=total)
-    slots = jnp.arange(total, dtype=starts.dtype)
-    offset = (slots - jnp.take(starts, rows)).astype(jnp.int32)
-    li = jnp.take(orig_s, rows)
-    r_sorted_pos = jnp.clip(jnp.take(rstart, rows) + offset, 0,
-                            orig_s.shape[0] - 1)
-    ri = jnp.take(orig_s, r_sorted_pos)
+    Sized by the pairs, not by the T sorted rows: `jnp.repeat` over the
+    counts scattered one update per sorted row, which a TPU serialises
+    (8.75 ns a row on a v5e: 40 ms of a TPC-H Q12 query for 93,752
+    pairs of 4.6 M rows, 428 ms of a TPC-DS q17 query). The rows that
+    own a slot are selected first, by the cheaper of `ops/compact.py`'s
+    two selects for the static (T, `total`):
+
+    * rank select: slot j belongs to the first row whose running count
+      reaches j + 1, so the select places every slot itself;
+    * sort select: one sort puts the owning rows first, in row order,
+      keyed by their first slot; what the slots read of a row (its left
+      row and the step from a slot to its right row) rides that sort
+      where that is cheaper than gathering it back (`_carry_wins`).
+      Each row's values are then spread over its slots by scattering
+      the change from the row before at its first slot, one update a
+      row, and a running sum, which is exact in integers. Where every
+      row owns one slot there is nothing to spread.
+
+    On a v5e (PERF.md section 6) it takes 7.5 ms for Q12's 93,752
+    pairs, 173 and 143 ms for q17's two joins of 2.9 M, where `repeat`
+    and its gathers took 45.6, 575 and 384. The pairs come in the same
+    order either way: ascending sorted rows."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from hyperspace_tpu.ops import compact
+
+    rows = counts.shape[0]
+    # a slot's right row sits at the slot plus its row's `step`; under
+    # left_outer a row with no right partner steps before slot 0
+    step = rstart - starts
     if left_outer:
-        ri = jnp.where(jnp.take(rights, rows) > 0, ri, jnp.int32(-1))
+        step = jnp.where(rights > 0, step, -total)
+    if _expand_path(rows, total) == "rank":
+        owner = compact._rank_select(compact._running(counts), total)
+        li, step = (a.at[owner].get(mode="promise_in_bounds",
+                                    indices_are_sorted=True)
+                    for a in (orig_s, step))
+    else:
+        size = min(total, rows)  # the most rows that can own a slot
+        if _carry_wins(rows, size):
+            key, li, step = compact._sort_select(counts > 0, size, orig_s,
+                                                 step, order=starts)
+            start = key.astype(jnp.int32)
+        else:
+            key, = compact._sort_select(counts > 0, size)
+            row = jnp.where(key < jnp.uint32(compact._DEAD), key, 0)
+            start, li, step = (a.at[row].get(mode="promise_in_bounds",
+                                             indices_are_sorted=True)
+                               for a in (starts, orig_s, step))
+        owns = key < jnp.uint32(compact._DEAD)
+        # the rows past the owners own no slot: their updates drop
+        at = jnp.where(owns, start, total)
+
+        def spread(li, step):
+            return tuple(
+                compact._running(jnp.zeros(total, v.dtype).at[at].add(
+                    jnp.diff(v, prepend=jnp.zeros(1, v.dtype)),
+                    mode="drop", indices_are_sorted=True))
+                for v in (li, step))
+
+        if size == total:  # as many rows as slots: one each, or a spread
+            li, step = lax.cond(owns[-1], lambda *v: v, spread, li, step)
+        else:
+            li, step = spread(li, step)
+    right = jnp.arange(total, dtype=jnp.int32) + step
+    ri = orig_s.at[jnp.clip(right, 0, rows - 1)].get(
+        mode="promise_in_bounds")
+    if left_outer:
+        ri = jnp.where(right >= 0, ri, jnp.int32(-1))
     return li, ri
 
 

@@ -18,7 +18,7 @@ from hyperspace_tpu import telemetry
 
 def _assert_scoped(program, scope, *args, **static):
     """Every op of the compiled program lies under `scope`, and the
-    program keeps its function's name."""
+    program keeps its function's name. Returns the ops' names."""
     name = program.__wrapped__.__name__
     hlo = program.lower(*args, **static).compile().as_text()
     assert f"jit_{name}" in hlo.splitlines()[0]
@@ -28,6 +28,7 @@ def _assert_scoped(program, scope, *args, **static):
     bare = [n for n in ops if not n.startswith(f"jit({name})/{scope}/")]
     assert not bare, bare
     assert scope in telemetry.DEVICE_SCOPES
+    return ops
 
 
 def _lanes(rng, n, width):
@@ -63,10 +64,10 @@ def test_topk_threshold_is_scoped():
 @pytest.mark.parametrize("program", ["_counting_match_lanes",
                                      "_counting_match_lanes_hashed",
                                      "_counting_match", "_counting_expand"])
-def test_the_counting_join_programs_are_scoped(program):
+def test_the_counting_join_programs_are_scoped(program, monkeypatch):
     import jax.numpy as jnp
 
-    from hyperspace_tpu.ops import join
+    from hyperspace_tpu.ops import compact, join
 
     rng = np.random.default_rng(2)
     if program == "_counting_match":
@@ -74,13 +75,22 @@ def test_the_counting_join_programs_are_scoped(program):
                 jnp.asarray(rng.integers(0, 40, 200).astype(np.int32)))
         scope = "hs.join.match"
     elif program == "_counting_expand":
-        counts, starts, rights, rstart, orig_s = join._counting_match(
-            jnp.asarray(rng.integers(0, 40, 300).astype(np.int32)),
-            jnp.asarray(rng.integers(0, 40, 200).astype(np.int32)), False)
-        total = int(jnp.sum(counts))
-        _assert_scoped(join._counting_expand, "hs.join.expand", counts,
-                       starts, rights, rstart, orig_s, total=total,
-                       left_outer=False)
+        # few pairs among many rows; each select, forced, traces afresh
+        match = join._counting_match(
+            jnp.asarray(rng.integers(0, 400, 300).astype(np.int32)),
+            jnp.asarray(rng.integers(0, 4000, 200).astype(np.int32)),
+            False)
+        total = int(jnp.sum(match[0]))
+        assert 0 < total < 300
+        for select, primitive in (("sort", "/sort"), ("rank", "/gather")):
+            monkeypatch.setattr(compact, "_rank_select_wins",
+                                lambda rows, size: select == "rank")
+            join._counting_expand.clear_cache()
+            ops = _assert_scoped(join._counting_expand, "hs.join.expand",
+                                 *match, total=total, left_outer=False)
+            # the select's own ops sit under the scope with the rest
+            assert any(n.endswith(primitive) for n in ops), ops
+        join._counting_expand.clear_cache()
         return
     else:
         width = 2 if program == "_counting_match_lanes" else 4

@@ -913,3 +913,186 @@ def test_counting_match_holds_no_gather(entry):
     found = _primitives(jaxpr.jaxpr, set())
     assert {"sort", "cumsum", "cummax", "cummin"} <= found  # walked inside
     assert not {p for p in found if "gather" in p}, found
+
+
+# -- the counting join's expansion ------------------------------------------
+
+
+def _repeat_expand(counts, starts, rights, rstart, orig_s, total,
+                   left_outer):
+    """The expansion as it was before it was sized by the pairs: a
+    `jnp.repeat` of the sorted row numbers over their counts, then
+    gathers of every slot's row out of the sorted arrays. Kept as the
+    reference the new expansion must equal, bit for bit and in order."""
+    import jax.numpy as jnp
+
+    rows = jnp.repeat(jnp.arange(counts.shape[0], dtype=jnp.int32),
+                      counts, total_repeat_length=total)
+    slots = jnp.arange(total, dtype=starts.dtype)
+    offset = (slots - jnp.take(starts, rows)).astype(jnp.int32)
+    li = jnp.take(orig_s, rows)
+    r_sorted_pos = jnp.clip(jnp.take(rstart, rows) + offset, 0,
+                            orig_s.shape[0] - 1)
+    ri = jnp.take(orig_s, r_sorted_pos)
+    if left_outer:
+        ri = jnp.where(jnp.take(rights, rows) > 0, ri, jnp.int32(-1))
+    return li, ri
+
+
+def _expand_cases():
+    """name -> (left ids, right ids) of a counting match in id space."""
+    rng = np.random.default_rng(39)
+    fan = rng.integers(1, 51, 40)
+    return {
+        "one_to_one": (rng.permutation(1000)[:100], np.arange(1000)),
+        "one_to_many": (np.arange(40), np.repeat(np.arange(40), fan)),
+        "many_to_many": (rng.integers(0, 30, 300), rng.integers(0, 30, 200)),
+        "unmatched_left": (rng.integers(0, 100, 200),
+                           rng.integers(50, 150, 100)),
+        "single_matched_row": (np.arange(100), np.array([37])),
+        "every_row_matched": (np.arange(64) % 16, np.arange(16)),
+        "rows_under_pow2": (rng.integers(0, 400, 523),
+                            rng.integers(0, 400, 500)),
+        "rows_over_pow2": (rng.integers(0, 400, 525),
+                           rng.integers(0, 400, 500)),
+    }
+
+
+EXPAND_CASES = _expand_cases()
+
+
+@pytest.fixture(params=["rank", "sort_carry", "sort_gather"])
+def expand_path(request, monkeypatch):
+    """Each way the expansion can go, forced through its cost models
+    whatever they say of test sizes; the choice is made when the
+    program is traced, so it traces afresh either side."""
+    from hyperspace_tpu.ops import compact
+    monkeypatch.setattr(compact, "_rank_select_wins",
+                        lambda rows, size: request.param == "rank")
+    monkeypatch.setattr(join, "_carry_wins",
+                        lambda rows, size: request.param == "sort_carry")
+    join._counting_expand.clear_cache()
+    yield request.param
+    join._counting_expand.clear_cache()
+
+
+@pytest.mark.parametrize("how", ["inner", "left_outer"])
+@pytest.mark.parametrize("case", sorted(EXPAND_CASES))
+def test_counting_expand_is_the_repeat_expansion(case, how, expand_path):
+    import jax.numpy as jnp
+
+    l_ids, r_ids = EXPAND_CASES[case]
+    if case.startswith("rows_"):
+        assert len(l_ids) + len(r_ids) in (1023, 1025)
+    left_outer = how == "left_outer"
+    match = join._counting_match(jnp.asarray(l_ids, jnp.int32),
+                                 jnp.asarray(r_ids, jnp.int32), left_outer)
+    total = int(jnp.sum(match[0]))
+    got = join._counting_expand(*match, total=total, left_outer=left_outer)
+    want = _repeat_expand(*match, total, left_outer)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    if left_outer and case == "unmatched_left":
+        assert (np.asarray(got[1]) == -1).any()
+
+
+def _wide_sides(seed, n, m):
+    """Two int64 keys a side: five lanes, so the hashed match serves."""
+    rng = np.random.default_rng(seed)
+
+    def side(rows):
+        return columnar.from_arrow(pa.table({
+            "k1": rng.integers(0, 20, rows).astype(np.int64),
+            "k2": rng.integers(0, 10, rows).astype(np.int64)}), device=True)
+    return side(n), side(m)
+
+
+@pytest.mark.parametrize("how", ["inner", "left_outer"])
+@pytest.mark.parametrize("match", ["exact", "hashed", "hashed-fallback"])
+def test_counting_join_pairs_are_the_repeat_expansions(match, how):
+    """Through `counting_join_batch_indices`: whichever match served it,
+    the pairs are the repeat expansion of that match's counts, in
+    order, and the expansion's fill is in the registry."""
+    from hyperspace_tpu import telemetry
+    from hyperspace_tpu.ops import hash_partition as hp
+
+    left, right = _wide_sides(139, 1_500, 1_200)
+    left_outer = how == "left_outer"
+    lanes = join._join_lane_operands(left, right, ["k1", "k2"],
+                                     ["k1", "k2"])
+    fill = telemetry.get_registry().histogram("join.expand.fill")
+    before = fill.count
+    orig = hp._fmix32
+    old_lanes = join.HASH_MATCH_MIN_LANES
+    if match == "exact":
+        join.HASH_MATCH_MIN_LANES = 10 ** 9
+    elif match == "hashed-fallback":
+        join._counting_match_lanes_hashed.clear_cache()
+        hp._fmix32 = lambda h: h * 0  # every key has one hash
+    try:
+        got = join.counting_join_batch_indices(
+            left, right, ["k1", "k2"], ["k1", "k2"], how=how)
+        if match == "hashed":
+            served = join._counting_match_lanes_hashed(*lanes, left_outer)
+            assert not bool(served[-1])  # no collision
+            served = served[:-1]
+        else:
+            served = join._counting_match_lanes(*lanes, left_outer)
+    finally:
+        hp._fmix32 = orig
+        join.HASH_MATCH_MIN_LANES = old_lanes
+        join._counting_match_lanes_hashed.clear_cache()
+    total = int(np.asarray(served[0]).sum())
+    want = _repeat_expand(*served, total, left_outer)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert fill.count == before + 1
+    assert fill.max >= total / (1_500 + 1_200) > 0
+
+
+@pytest.mark.parametrize("path", ["rank", "select"])
+def test_counting_expand_moves_nothing_by_the_sorted_rows(path,
+                                                           monkeypatch):
+    """A scatter or a gather costs a v5e one serialised step per element
+    (PERF.md section 6), so the expansion's are held to the pairs: no
+    scatter of more updates and no gather of more indices than there
+    are pairs, over 5,000 sorted rows for 625 pairs. A CPU run cannot
+    time that, so the traced program is held to it."""
+    import jax
+    import jax.numpy as jnp
+
+    from hyperspace_tpu.ops import compact
+
+    monkeypatch.setattr(compact, "_rank_select_wins",
+                        lambda rows, size: path == "rank")
+    rows = 5_000
+    at = jnp.arange(rows, dtype=jnp.int32)
+    counts = jnp.where(at % 16 == 0, 1 + at % 3, 0).astype(jnp.int32)
+    total = int(counts.sum())
+    starts = jnp.cumsum(counts) - counts
+    join._counting_expand.clear_cache()  # the path is chosen as it traces
+    try:
+        jaxpr = jax.make_jaxpr(lambda *a: join._counting_expand(
+            *a, total=total, left_outer=True))(counts, starts, counts, at, at)
+    finally:
+        join._counting_expand.clear_cache()
+    moved = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if "scatter" in eqn.primitive.name:
+                moved.append(("scatter", eqn.invars[2].aval.shape[0]))
+            elif eqn.primitive.name == "gather":
+                moved.append(("gather", eqn.invars[1].aval.shape[0]))
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (tuple, list))
+                            else (value,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+    walk(jaxpr.jaxpr)
+    kinds = {kind for kind, _ in moved}
+    assert kinds == ({"gather"} if path == "rank"
+                     else {"gather", "scatter"}), moved
+    assert all(n <= total < rows for _, n in moved), moved

@@ -249,7 +249,8 @@ def test_moments_that_leave_53_bits_take_the_float_path():
 
 def test_scan_spans_say_index_or_source_and_joins_their_match(env):
     """`hs.op.Scan` spans carry the index a scan reads, or its source
-    directory; a global join's record says its lane and its match."""
+    directory; a global join's record says its lane, its match and its
+    expansion's select."""
     hs, fact, dim, tmp = env
     hs.create_index(fact, IndexConfig("q17_fact", ["key"], ["qty"]))
     query = fact.filter((col("key") >= lit(10)) & (col("key") < lit(20))) \
@@ -266,4 +267,5 @@ def test_scan_spans_say_index_or_source_and_joins_their_match(env):
     (smj,) = [op for op in metrics.operators if op.name == "SortMergeJoin"]
     assert smj.detail["lane"] == "device"
     assert smj.detail["match"] == "exact" and smj.detail["keys"] == 1
+    assert smj.detail["expand"] == "select"  # few pairs, not too few
     assert (smj.detail["left_rows"], smj.detail["right_rows"]) == (150, 6000)

@@ -85,7 +85,8 @@ def _instrument(fn, bucketed: bool):
             detail = op.detail if op is not None else {}
             sp.set(rows=rows, lane=detail.get("lane"),
                    appended=detail.get("appended"),
-                   index=detail.get("index"), source=detail.get("source"))
+                   index=detail.get("index"), source=detail.get("source"),
+                   files=detail.get("files_scanned"))
             if op is not None:
                 rec.finish_operator(op, rows_out=rows)
         # Operator-span boundary: fold a device-memory sample into the

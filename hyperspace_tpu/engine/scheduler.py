@@ -1132,10 +1132,18 @@ class QueryScheduler:
         session's sticky `session.tenant(...)` default, else the
         DEFAULT tenant) is the billing identity the query charges:
         admission quotas, DRR dequeue weight, SLO window, and every
-        chargeback counter key on it."""
+        chargeback counter key on it. Each local file the query stamps
+        is stat'ed once, from admission to answer
+        (`storage.one_stat_per_file`)."""
+        from hyperspace_tpu.utils import storage
+
+        with storage.one_stat_per_file():
+            return self._collect(df, timeout, tenant)
+
+    def _collect(self, df, timeout, tenant):
         from hyperspace_tpu.io.columnar import to_arrow
         from hyperspace_tpu.plan import footprint as _footprint
-        from hyperspace_tpu.utils import faults
+        from hyperspace_tpu.utils import faults, storage
 
         session = df.session
         conf = session.conf if session is not None else None
@@ -1196,6 +1204,9 @@ class QueryScheduler:
             try:
                 t_admit0 = time.perf_counter()
                 wait_s = self._admit(ent, conf)
+                if wait_s:
+                    # queued: the files may have moved meanwhile
+                    storage.forget_stats()
                 # Critical-path sources: the recorder's wall started at
                 # construction (before admission), so queue wait and the
                 # admission bookkeeping around it are genuine wall

@@ -12,7 +12,6 @@ query's relation signature equals the one captured at index-build time.
 from __future__ import annotations
 
 import importlib
-import os
 from abc import ABC, abstractmethod
 from typing import Optional
 
@@ -51,7 +50,7 @@ def file_stamp(path: str):
                 or info.get("generation") or "")
         return int(size), str(mtime) + str(etag)
     try:
-        stat = os.stat(path)
+        stat = storage.stat(path)
     except OSError:
         return None
     return int(stat.st_size), str(int(stat.st_mtime_ns))

@@ -240,6 +240,12 @@ def write_sketches(version_dir: str, sketches: Sequence[FileSketch],
         {_META_KEY: json.dumps(meta).encode("utf-8")})
     blob_path = storage.join(version_dir, SKETCH_BLOB)
     parquet.write_table(table, blob_path)
+    # A version dir is immutable once committed, but its PATH is not
+    # unique over a process's life: an index dropped, vacuumed and made
+    # again under its name writes `v__=0` anew. The loader caches by
+    # path, so the blob it cached there goes with the write.
+    with _cache_lock:
+        _cache.pop(os.path.normpath(version_dir), None)
     from hyperspace_tpu.index.signature import file_stamp
     stamp = file_stamp(blob_path)
     return int(stamp[0]) if stamp is not None else 0

@@ -123,11 +123,13 @@ import atexit as _atexit  # noqa: E402
 _atexit.register(shutdown_io_executor)
 
 
-def _file_stamp(path: str):
+def _file_stamp(path: str, fresh: bool = False):
     """(size, mtime) of a FILE, or None when the path is a directory or
     the backend exposes no modification time — both must disable caching
     (a directory's own stamp does not change when a member file is
-    rewritten in place; without mtime a same-size rewrite would collide)."""
+    rewritten in place; without mtime a same-size rewrite would collide).
+    A local file is stat'ed once per query (`storage.stat`) unless
+    `fresh`."""
     if storage.is_url(path):
         fs, real = storage.get_fs(path)
         info = fs.info(real)
@@ -139,18 +141,19 @@ def _file_stamp(path: str):
         if not mtime:
             return None
         return (info.get("size", 0) or 0, str(mtime))
-    st = os.stat(path)
+    st = storage.stat(path, fresh)
     import stat as _stat
     if _stat.S_ISDIR(st.st_mode):
         return None
     return (st.st_size, st.st_mtime_ns)
 
 
-def _stamps(paths: Sequence[str]):
+def _stamps(paths: Sequence[str], fresh: bool = False):
     """Tuple of per-file stamps, or None when any file is unstampable
-    (directory, no mtime, stat failure) — which disables caching."""
+    (directory, no mtime, stat failure) — which disables caching.
+    `fresh`: stat each file now (the re-stat after a read)."""
     try:
-        stamps = tuple(_file_stamp(p) for p in paths)
+        stamps = tuple(_file_stamp(p, fresh) for p in paths)
     except OSError:
         return None
     return None if any(st is None for st in stamps) else stamps
@@ -225,7 +228,7 @@ def read_table(paths: Sequence[str], columns: Optional[Sequence[str]] = None):
         # otherwise cache new (or torn, for multi-file concat) bytes under
         # the old stamp, and the stale entry would keep validating until
         # the file changed again. Insert only when nothing moved.
-        if _stamps(paths) != stamps:
+        if _stamps(paths, fresh=True) != stamps:
             return table
         with _read_cache_lock:
             _read_cache[key] = (stamps, table)
@@ -341,7 +344,7 @@ def _stamped_batch_read(paths: Sequence[str],
     table = read_table(paths, columns=columns)
     batch = columnar.from_arrow(table, schema, device=False)
     if stamps is not None and budget > 0:
-        if _stamps(paths) != stamps:
+        if _stamps(paths, fresh=True) != stamps:
             return batch
         nbytes = _batch_nbytes(batch)
         if nbytes <= budget:

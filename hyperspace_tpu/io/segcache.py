@@ -795,7 +795,8 @@ class SegmentCache:
         nbytes = _batch_nbytes(batch)
         if not cacheable:
             return batch, nbytes
-        if stamps is not None and parquet._stamps(paths) != stamps:
+        if stamps is not None \
+                and parquet._stamps(paths, fresh=True) != stamps:
             # Unversioned read raced a rewrite: serve, never cache.
             return batch, nbytes
         with self._cv:

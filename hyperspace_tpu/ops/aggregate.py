@@ -3,7 +3,10 @@
 The reference leaves aggregation to Spark SQL's hash/sort aggregates; here
 groups are formed by ONE stable multi-key sort (32-bit lanes) and reduced
 with XLA segment ops — TPU-friendly: no scatter contention, fully
-vectorized, one host sync (the group count) to size the output.
+vectorized, one host sync (the group count) to size the output. One
+group (a global aggregate) is reduced by plain reductions instead: a
+segment op into one slot is a scatter that adds every row into it in
+turn.
 
 SQL null semantics: sum/min/max/avg ignore null inputs; count(col) counts
 non-null; count(*) counts rows; a group whose inputs are all null yields
@@ -307,14 +310,32 @@ def _group_finish(segment_ids, keys, columns, plan, num_groups: int):
          jnp.take(validity, firsts) if validity is not None else None)
         for raw, validity in keys)
 
-    def segment_sum(x):
-        return jax.ops.segment_sum(x, segment_ids, num_segments=num_groups)
+    if num_groups == 1:
+        # One group (a global aggregate): a reduction a column, which
+        # XLA lowers to a tree. A scatter into one slot adds the rows in
+        # turn, which on the chip is serial and, in its f32-pair
+        # float64, loses about 1e-12 of a sum of a million products
+        # (a tree: about 1e-15).
+        def segment_sum(x):
+            return jnp.sum(x, axis=0, keepdims=True)
 
-    def segment_min(x):
-        return jax.ops.segment_min(x, segment_ids, num_segments=num_groups)
+        def segment_min(x):
+            return jnp.min(x, axis=0, keepdims=True)
 
-    def segment_max(x):
-        return jax.ops.segment_max(x, segment_ids, num_segments=num_groups)
+        def segment_max(x):
+            return jnp.max(x, axis=0, keepdims=True)
+    else:
+        def segment_sum(x):
+            return jax.ops.segment_sum(x, segment_ids,
+                                       num_segments=num_groups)
+
+        def segment_min(x):
+            return jax.ops.segment_min(x, segment_ids,
+                                       num_segments=num_groups)
+
+        def segment_max(x):
+            return jax.ops.segment_max(x, segment_ids,
+                                       num_segments=num_groups)
 
     out = []
     for (func, dtype, out_dtype, exact), entry in zip(plan, columns):

@@ -144,9 +144,12 @@ class FilterIndexRule(Rule):
         rewritten: LogicalPlan = Filter(filt.condition, source)
         if project is not None:
             rewritten = Project(project.columns, rewritten)
-        else:
+        elif source.schema.names != scan.schema.names:
             # Bare Filter(Scan): restore the base relation's column order —
-            # enabling indexes must not change result shape.
+            # enabling indexes must not change result shape. A source that
+            # has it already (the skipping rewrite's pruned scan) takes no
+            # Project: one over every column would make the scan read all
+            # of them, whatever the operators above it read.
             rewritten = Project(scan.schema.names, rewritten)
         return rewritten
 
@@ -189,31 +192,51 @@ class FilterIndexRule(Rule):
                       "files_pruned": len(pruned),
                       "bytes_pruned": bytes_pruned}])
 
-    def _prune_file_list(self, condition, files):
-        """Prune `files` (source-data paths) with the best ACTIVE
-        non-Z-order skipping sketch available. Returns
-        (survivors, pruned, bytes_pruned, entry) — unchanged input and
-        entry=None when nothing applies. Sketch-blob problems degrade
-        to no pruning, never an error."""
-        if not files or not self._skipping_enabled():
-            return list(files), [], 0, None
+    def _consult(self, entry, condition, files, served: str):
+        """(survivors, pruned, bytes_pruned) of `files` under `entry`'s
+        sketches, or None where its blob is unusable or sketches none of
+        `files`: the ONE sketch consult of both rewrites, under the span
+        `hs.plan.skipping` (index, files, kept, bytes_pruned, and served:
+        how the query is served where files were pruned, "" where none
+        were). `files` None means the files the blob sketches (a Z-order
+        entry's clustered copy). Sketch-blob problems degrade to no
+        pruning, never an error."""
         from hyperspace_tpu.index.sketch import load_sketches
         from hyperspace_tpu.plan.rules.skipping import prune_files
-        for entry in self._skipping_indexes():
-            if entry.derived_dataset.zorder_by:
-                continue  # z-order entries serve whole scans, not lists
+        with telemetry.span("hs.plan.skipping", "plan",
+                            index=entry.name) as sp:
+            out = None
             try:
                 sketches = load_sketches(entry.content.root)
             except Exception as exc:
                 logger.warning("Skipping index %s blob unusable (%s); "
                                "not pruning", entry.name, exc)
-                continue
-            if not any(f in sketches.files for f in files):
-                continue  # sketches cover a different relation
-            survivors, pruned, bytes_pruned = prune_files(
-                condition, files, sketches)
-            if pruned:
-                return survivors, pruned, bytes_pruned, entry
+                sketches = None
+            if sketches is not None:
+                if files is None:
+                    files = sorted(sketches.files)
+                if any(f in sketches.files for f in files):
+                    out = prune_files(condition, files, sketches)
+            n = len(files) if files is not None else 0
+            sp.set(files=n, kept=len(out[0]) if out else n,
+                   bytes_pruned=out[2] if out else 0,
+                   served=served if out and out[1] else "")
+        return out
+
+    def _prune_file_list(self, condition, files):
+        """Prune `files` (source-data paths) with the best ACTIVE
+        non-Z-order skipping sketch available. Returns
+        (survivors, pruned, bytes_pruned, entry) — unchanged input and
+        entry=None when nothing applies."""
+        if not files or not self._skipping_enabled():
+            return list(files), [], 0, None
+        for entry in self._skipping_indexes():
+            if entry.derived_dataset.zorder_by:
+                continue  # z-order entries serve whole scans, not lists
+            found = self._consult(entry, condition, files,
+                                  served="hybrid-remainder")
+            if found is not None and found[1]:
+                return (*found, entry)
         return list(files), [], 0, None
 
     def _skipping_source(self, filt: Filter, scan: Scan):
@@ -230,8 +253,6 @@ class FilterIndexRule(Rule):
         (an unpruned rewrite would be pure churn)."""
         if not self._skipping_enabled():
             return None
-        from hyperspace_tpu.index.sketch import load_sketches
-        from hyperspace_tpu.plan.rules.skipping import prune_files
         from hyperspace_tpu.plan.schema import Schema
 
         files = scan.files()
@@ -253,41 +274,28 @@ class FilterIndexRule(Rule):
                 if not scan_names <= {f.name.lower()
                                       for f in copy_schema.fields}:
                     continue
-                try:
-                    sketches = load_sketches(entry.content.root)
-                except Exception as exc:
-                    logger.warning("Skipping index %s blob unusable "
-                                   "(%s); not serving", entry.name, exc)
-                    continue
-                copy_files = sorted(sketches.files)
-                survivors, pruned, bytes_pruned = prune_files(
-                    filt.condition, copy_files, sketches)
-                if not pruned:
+                found = self._consult(entry, filt.condition, None,
+                                      served="zorder-copy")
+                if found is None or not found[1]:
                     continue  # no win over the source scan
+                survivors, pruned, bytes_pruned = found
+                n_copy = len(survivors) + len(pruned)
                 replacement = Scan(
                     [entry.content.root], scan.schema,
                     files=survivors, index_name=entry.name,
                     pinned_version=_version_of_root(entry.content.root))
                 logger.info(
                     "FilterIndexRule: z-order skipping index %s prunes "
-                    "%d/%d copy files", entry.name, len(pruned),
-                    len(copy_files))
-                self._emit_skipping(entry, [entry.content.root],
-                                    len(copy_files), pruned, bytes_pruned,
+                    "%d/%d copy files", entry.name, len(pruned), n_copy)
+                self._emit_skipping(entry, [entry.content.root], n_copy,
+                                    pruned, bytes_pruned,
                                     served="zorder-copy")
                 return replacement
-            try:
-                sketches = load_sketches(entry.content.root)
-            except Exception as exc:
-                logger.warning("Skipping index %s blob unusable (%s); "
-                               "not pruning", entry.name, exc)
+            found = self._consult(entry, filt.condition, files,
+                                  served="source")
+            if found is None or not found[1]:
                 continue
-            if not any(f in sketches.files for f in files):
-                continue
-            survivors, pruned, bytes_pruned = prune_files(
-                filt.condition, files, sketches)
-            if not pruned:
-                continue
+            survivors, pruned, bytes_pruned = found
             logger.info("FilterIndexRule: skipping index %s prunes "
                         "%d/%d source files", entry.name, len(pruned),
                         len(files))

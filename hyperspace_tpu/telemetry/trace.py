@@ -78,11 +78,18 @@ SPAN_NAMES = {
                       "(index, then files: the listing's, appended and "
                       "deleted: what the index lacks and has lost; "
                       "appended -1 where it is declined)",
+    "hs.plan.skipping": "data skipping, inside hs.plan.optimize: one "
+                        "skipping index's sketches consulted for a "
+                        "scan's files (index, files, kept, "
+                        "bytes_pruned, served: source, zorder-copy or "
+                        "hybrid-remainder where files were pruned, "
+                        "empty where none were)",
     # operators — engine/physical wrapper, on the executing thread
     "hs.op.<Name>": "one physical operator (lane, rows; a Scan also "
                     "index: the index it reads, or source: the source "
-                    "directory's name, and a Scan of hybrid scan's "
-                    "appended files appended: the files it read)",
+                    "directory's name, files: the files it read, and "
+                    "a Scan of hybrid scan's appended files appended: "
+                    "the same)",
     # fused stage — engine/fusion.py; sync + compact also in the unfused
     # filters (engine/physical.FilterExec, engine/compiler.apply_filter)
     "hs.stage.dispatch": "the stage program's dispatch (ops, cache_hit)",

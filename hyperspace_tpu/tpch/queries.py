@@ -294,10 +294,13 @@ def q5_pandas(t):
 # ---------------------------------------------------------------------------
 
 
-def q6(dfs):
+def q6(dfs, year: int = 1994):
+    """Q6 with DATE = January 1 of `year` (the specification draws it
+    from 1993-1997; the validation run's is 1994) and the validation
+    run's DISCOUNT 0.06 and QUANTITY 24."""
     li = dfs["lineitem"].filter(
-        (col("l_shipdate") >= lit(days(1994, 1, 1)))
-        & (col("l_shipdate") < lit(days(1995, 1, 1)))
+        (col("l_shipdate") >= lit(days(year, 1, 1)))
+        & (col("l_shipdate") < lit(days(year + 1, 1, 1)))
         & col("l_discount").between(lit(0.05), lit(0.07))
         & (col("l_quantity") < lit(24)))
     return li.agg(("sum", col("l_extendedprice") * col("l_discount"),

@@ -19,6 +19,9 @@ degrade to check-then-create only under an explicit
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import os
 import posixpath
 from typing import List, Tuple
 
@@ -58,6 +61,50 @@ def canonical(path: str) -> str:
     if is_url(path):
         return path
     return os.path.abspath(path)
+
+
+# One `os.stat` per local file per query. A served query stamps the same
+# files at admission (its footprint), in the skipping prune, at its scan's
+# resolve and in the segment cache; on a network mount each stat is a
+# round trip. Inside `one_stat_per_file` a path is stat'ed once and every
+# later `stat` of it in the block reads that result, so the query sees one
+# stamp per file from admission to answer. None: no block, every call stats.
+_stat_memo: contextvars.ContextVar = contextvars.ContextVar(
+    "hs_stat_memo", default=None)
+
+
+@contextlib.contextmanager
+def one_stat_per_file():
+    """Within the block (this thread's context), `stat` stats each path
+    once; the scheduler runs each query inside one."""
+    token = _stat_memo.set({})
+    try:
+        yield
+    finally:
+        _stat_memo.reset(token)
+
+
+def forget_stats() -> None:
+    """Drop what the enclosing `one_stat_per_file` block remembers: the
+    next `stat` of each path stats it again (a query that waited in the
+    admission queue looks at its files anew)."""
+    memo = _stat_memo.get()
+    if memo is not None:
+        memo.clear()
+
+
+def stat(path: str, fresh: bool = False) -> os.stat_result:
+    """`os.stat(path)`, answered from the enclosing `one_stat_per_file`
+    block where the path was stat'ed in it already. `fresh` always stats
+    and remembers nothing: the re-stat after a read, which must see a
+    rewrite made during it. A failure is never remembered."""
+    memo = _stat_memo.get()
+    if memo is None or fresh:
+        return os.stat(path)
+    st = memo.get(path)
+    if st is None:
+        st = memo[path] = os.stat(path)
+    return st
 
 
 # Protocols whose fsspec "x" (exclusive-create) mode is genuinely atomic:

@@ -13,9 +13,11 @@ space, two programs and one host sync:
    multi-column keys comparable — then `_runs_to_counts`: key runs from
    adjacent differences, and each run's right-row count and bracket by
    five scans over the sorted rows (two prefix sums, one `cummax`, two
-   reverse `cummin`). No `searchsorted` and no gather over the sorted
-   rows: the chip scans them some 20-60 times faster than it gathers
-   them (see `_runs_to_counts`). Keys of `HASH_MATCH_MIN_LANES` lanes
+   reverse `cummin`; the brackets are `run_brackets`, which the mesh
+   match `parallel/spmd._match` runs along each shard's row). No
+   `searchsorted` and no gather over the sorted rows: the chip scans
+   them some 20-60 times faster than it gathers them (see
+   `run_brackets`). Keys of `HASH_MATCH_MIN_LANES` lanes
    and more (`_counting_match_lanes_hashed`) sort by (u64 key hash,
    side, row number) instead, the key lanes carried by that same sort
    as payload, so runs come from sorted lanes there too;
@@ -106,10 +108,14 @@ def _join_lane_operands(left: ColumnBatch, right: ColumnBatch,
     return (marker_l, *l_lanes), (marker_r, *r_lanes)
 
 
-def _runs_to_counts(differs, side_s, left_outer: bool):
-    """Shared tail of the counting match: per-run right-counts and
-    bracket starts from the (T-1) adjacent-key-difference vector over
-    the sorted (key, side, orig) sequence.
+def run_brackets(differs, side_s):
+    """Each sorted row's key-run bracket, along the LAST axis: from the
+    adjacent-key-difference vector `differs` ([..., T-1]) and the 0/1
+    side of each sorted row `side_s` ([..., T], 1 = right), the run's
+    right-row count `rights`, the sorted position of its first right
+    row `rstart` and of its last row `run_last` (all int32, [..., T]).
+    The one-chip match calls it over its one axis, the mesh match
+    (`parallel/spmd._match`) over each shard's row of [S, T].
 
     Scans only, no gather over the T sorted rows: the inclusive count
     of right rows `R` and the exclusive one `R - side_s` never
@@ -122,23 +128,36 @@ def _runs_to_counts(differs, side_s, left_outer: bool):
     import jax
     import jax.numpy as jnp
 
-    T = side_s.shape[0]
+    axis = side_s.ndim - 1
+    T = side_s.shape[axis]
     sentinel = jnp.int32(T)
     pos = jnp.arange(T, dtype=jnp.int32)
-    edge = jnp.ones(1, bool)
-    run_start = jnp.concatenate([edge, differs])
-    run_end = jnp.concatenate([differs, edge])
-    R = jnp.cumsum(side_s)  # inclusive right-element count
-    before = jax.lax.cummax(jnp.where(run_start, R - side_s, 0))
-    upto = jax.lax.cummin(jnp.where(run_end, R, sentinel), reverse=True)
+    edge = jnp.ones(side_s.shape[:-1] + (1,), bool)
+    run_start = jnp.concatenate([edge, differs], axis=axis)
+    run_end = jnp.concatenate([differs, edge], axis=axis)
+    R = jnp.cumsum(side_s, axis=axis)  # inclusive right-element count
+    before = jax.lax.cummax(jnp.where(run_start, R - side_s, 0), axis=axis)
+    upto = jax.lax.cummin(jnp.where(run_end, R, sentinel), axis=axis,
+                          reverse=True)
     rights = upto - before
     run_last = jax.lax.cummin(jnp.where(run_end, pos, sentinel),
-                              reverse=True)
+                              axis=axis, reverse=True)
     rstart = run_last - rights + 1  # first right element of the run
+    return rights, rstart, run_last
+
+
+def _runs_to_counts(differs, side_s, left_outer: bool):
+    """Shared tail of the counting match: per-run right-counts and
+    bracket starts from the (T-1) adjacent-key-difference vector over
+    the sorted (key, side, orig) sequence. The brackets are
+    `run_brackets`' scans (no gather); the counts are the left rows'."""
+    import jax.numpy as jnp
+
+    rights, rstart, _ = run_brackets(differs, side_s)
     counts = jnp.where(side_s == 0, rights, 0).astype(jnp.int32)
     if left_outer:
         counts = jnp.where(side_s == 0, jnp.maximum(counts, 1), 0)
-    starts = jnp.cumsum(counts) - counts
+    starts = jnp.cumsum(counts, axis=0) - counts
     return counts, starts, rights, rstart
 
 

@@ -81,6 +81,7 @@ from hyperspace_tpu.exceptions import HyperspaceException
 from hyperspace_tpu.io.columnar import ColumnBatch, DeviceColumn
 from hyperspace_tpu.ops import keys as keymod
 from hyperspace_tpu.ops.bucketed_join import next_pow2
+from hyperspace_tpu.ops.join import run_brackets
 from hyperspace_tpu.parallel.mesh import (DCN_AXIS, SHARD_AXIS,
                                           assemble_sharded_rows,
                                           bucket_owner, bucket_ranges,
@@ -1132,12 +1133,13 @@ def _match(l_lanes2d, r_lanes2d, l_null, r_null, l_pad, r_pad, r_gid,
            left_outer: bool, need_right: bool):
     """The counting match over the combined [S, T] layout (T = Cl + Cr).
     Per shard: ONE stable sort by (pad, null, *lanes, side, slot), run
-    grouping from adjacent lane differences, right-run brackets by
-    cumulative counting, and each left element's window
-    [starts, starts + counts) of the shard's output. Nothing here has a
-    shape that depends on the answer: the pair expansion (`_expand`) is
-    a second program, sized from `shard_total` once the host has read
-    it.
+    grouping from adjacent lane differences, right-run brackets by the
+    one-chip match's scans along each shard's row
+    (`ops/join.run_brackets`: no gather and no flip over the sorted
+    rows), and each left element's window [starts, starts + counts) of
+    the shard's output. Nothing here has a shape that depends on the
+    answer: the pair expansion (`_expand`) is a second program, sized
+    from `shard_total` once the host has read it.
 
     `r_gid` maps a right slot to its ORIGINAL global row id (identity
     for co-bucketed sides; the routed ids after an in-program
@@ -1166,27 +1168,12 @@ def _match(l_lanes2d, r_lanes2d, l_null, r_null, l_pad, r_pad, r_gid,
     side_s = results[-2]
     pos_s = results[-1]
 
-    first = jnp.ones((S, 1), dtype=bool)
-    rest = jnp.zeros((S, T - 1), dtype=bool)
+    differs = jnp.zeros((S, T - 1), dtype=bool)
     for k in lanes_s:
-        rest = rest | (k[:, 1:] != k[:, :-1])
-    rest = rest | (null_s[:, 1:] | null_s[:, :-1]
-                   | pad_s[:, 1:] | pad_s[:, :-1]).astype(bool)
-    run_start = jnp.concatenate([first, rest], axis=1)
-
-    posT = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (S, T))
-    run_first = jax.lax.cummax(jnp.where(run_start, posT, 0), axis=1)
-    nxt = jnp.flip(jax.lax.cummin(jnp.flip(
-        jnp.where(run_start, posT, jnp.int32(T)), axis=1), axis=1),
-        axis=1)
-    run_last = jnp.concatenate(
-        [nxt[:, 1:], jnp.full((S, 1), T, jnp.int32)], axis=1) - 1
-
-    R = jnp.cumsum(side_s, axis=1)
-    take = jnp.take_along_axis
-    rights = (take(R, run_last, axis=1) - take(R, run_first, axis=1)
-              + take(side_s, run_first, axis=1))
-    rstart = run_last - rights + 1
+        differs = differs | (k[:, 1:] != k[:, :-1])
+    differs = differs | (null_s[:, 1:] | null_s[:, :-1]
+                         | pad_s[:, 1:] | pad_s[:, :-1]).astype(bool)
+    rights, rstart, run_last = run_brackets(differs, side_s)
 
     is_left = (side_s == 0) & (pad_s == 0)
     matchable = is_left & (null_s == 0)
@@ -1199,12 +1186,16 @@ def _match(l_lanes2d, r_lanes2d, l_null, r_null, l_pad, r_pad, r_gid,
 
     un_gid_sorted = un_counts = None
     if need_right:
-        run_len = run_last - run_first + 1
-        lefts = run_len - rights
+        run_start = jnp.concatenate(
+            [jnp.ones((S, 1), dtype=bool), differs], axis=1)
+        run_first = jax.lax.cummax(
+            jnp.where(run_start, jnp.arange(T, dtype=jnp.int32), 0),
+            axis=1)
+        lefts = run_last - run_first + 1 - rights
         r_unmatched = ((side_s == 1) & (pad_s == 0)
                        & ((null_s == 1) | (lefts == 0)))
-        gid_sorted = take(r_gid,
-                          jnp.clip(pos_s - Cl, 0, Cr - 1), axis=1)
+        gid_sorted = jnp.take_along_axis(
+            r_gid, jnp.clip(pos_s - Cl, 0, Cr - 1), axis=1)
         un_gid = jnp.where(r_unmatched, gid_sorted, jnp.int64(-1))
         # Per-shard compaction IN-PROGRAM (unmatched gids first): the
         # host then assembles the output from contiguous prefixes with

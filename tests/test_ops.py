@@ -869,9 +869,10 @@ def test_runs_to_counts_matches_a_loop_over_runs(case, left_outer):
 
 
 def _primitives(jaxpr, into):
-    """Names of every primitive in `jaxpr`, nested calls included."""
+    """How often each primitive appears in `jaxpr`, nested calls
+    included (`into`: a `Counter`)."""
     for eqn in jaxpr.eqns:
-        into.add(eqn.primitive.name)
+        into[eqn.primitive.name] += 1
         for value in eqn.params.values():
             for sub in value if isinstance(value, (tuple, list)) else (value,):
                 sub = getattr(sub, "jaxpr", sub)
@@ -880,18 +881,35 @@ def _primitives(jaxpr, into):
     return into
 
 
-@pytest.mark.parametrize("entry", ["lanes", "ids", "hashed"])
+@pytest.mark.parametrize("entry", ["lanes", "ids", "hashed", "mesh",
+                                   "mesh_need_right"])
 def test_counting_match_holds_no_gather(entry):
     """A gather over the sorted rows costs the chip 34-108 ms where a
     scan costs 1.75 (PERF.md, PR 35); a CPU run cannot see that, so the
     traced program is held to it. The hashed match carries its seven
-    lanes (a marker and three int64 keys, q17's width) through its sort."""
+    lanes (a marker and three int64 keys, q17's width) through its sort.
+    The mesh match (`spmd._match`, over [S, T]) takes the same scans,
+    with no flip round them; a full outer join gathers only the right
+    rows' original ids."""
+    import collections
+
     import jax
     import jax.numpy as jnp
 
+    from hyperspace_tpu.parallel import spmd
+
     ids_l, ids_r = jnp.arange(12, dtype=jnp.int32) % 5, jnp.arange(
         9, dtype=jnp.int32) % 4
-    if entry == "lanes":
+    if entry.startswith("mesh"):
+        S, Cl, Cr = 3, 4, 3
+        no = jnp.zeros((S, Cl), bool), jnp.zeros((S, Cr), bool)
+        full_outer = entry == "mesh_need_right"
+        jaxpr = jax.make_jaxpr(lambda a, b, g: spmd._match(
+            [a], [b], *no, *no, g, left_outer=full_outer,
+            need_right=full_outer))(
+            ids_l.reshape(S, Cl), ids_r.reshape(S, Cr),
+            jnp.arange(S * Cr, dtype=jnp.int64).reshape(S, Cr))
+    elif entry == "lanes":
         lanes_l = (jnp.zeros(12, jnp.int32), ids_l)
         lanes_r = (jnp.zeros(9, jnp.int32), ids_r)
         jaxpr = jax.make_jaxpr(lambda a, b: join._counting_match_lanes(
@@ -910,9 +928,11 @@ def test_counting_match_holds_no_gather(entry):
     else:
         jaxpr = jax.make_jaxpr(lambda a, b: join._counting_match(
             a, b, left_outer=True))(ids_l, ids_r)
-    found = _primitives(jaxpr.jaxpr, set())
-    assert {"sort", "cumsum", "cummax", "cummin"} <= found  # walked inside
-    assert not {p for p in found if "gather" in p}, found
+    found = _primitives(jaxpr.jaxpr, collections.Counter())
+    assert {"sort", "cumsum", "cummax", "cummin"} <= found.keys()  # inside
+    gathers = sum(n for p, n in found.items() if "gather" in p)
+    assert gathers == (1 if entry == "mesh_need_right" else 0), found
+    assert "rev" not in found, found
 
 
 # -- the counting join's expansion ------------------------------------------

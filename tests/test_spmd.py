@@ -19,6 +19,8 @@ from hyperspace_tpu.parallel.build import distributed_build
 from hyperspace_tpu.parallel.mesh import (bucket_owner, bucket_ranges,
                                           make_mesh, shard_row_segments)
 
+from test_ops import BRACKET_CASES
+
 
 def make_batch(n, seed=0, keyspace=None):
     rng = np.random.default_rng(seed)
@@ -237,6 +239,118 @@ def test_skewed_join_is_sized_by_its_answer():
     (32769, 65536), (3_000_000_000, 1 << 32)])
 def test_the_expansions_ladder(pairs, rung):
     assert spmd._expand_rung(pairs) == rung
+
+
+def _mesh_match_layout(case, S):
+    """One of `test_ops.BRACKET_CASES` laid out as the mesh match's
+    [S, Cl] / [S, Cr] inputs: row i on shard i % S, its side's slots
+    in row order, then pad rows (two a side at least) whose lanes hold
+    keys of real rows."""
+    marker, value, side = (np.asarray(a) for a in BRACKET_CASES[case])
+    rng = np.random.default_rng(41 + S)
+    shard = np.arange(len(side)) % S
+    slots = {(s, d): np.flatnonzero((shard == s) & (side == d))
+             for s in range(S) for d in (0, 1)}
+    Cl = max(len(slots[s, 0]) for s in range(S)) + 2
+    Cr = max(len(slots[s, 1]) for s in range(S)) + 2
+    lanes, null, pad = {}, {}, {}
+    for d, C in ((0, Cl), (1, Cr)):
+        lanes[d] = rng.choice(value, (S, C)).astype(np.int32)
+        null[d] = np.zeros((S, C), bool)
+        pad[d] = np.ones((S, C), bool)
+        for s in range(S):
+            rows = slots[s, d]
+            lanes[d][s, :len(rows)] = value[rows]
+            null[d][s, :len(rows)] = marker[rows] != 0
+            pad[d][s, :len(rows)] = False
+    r_gid = rng.permutation(S * Cr).astype(np.int64).reshape(S, Cr)
+    return lanes, null, pad, r_gid
+
+
+def _loop_mesh_match(lanes, null, pad, r_gid, left_outer, need_right):
+    """Plain reference for `spmd._match`: per shard, sort the rows as
+    its one sort does, then walk the key runs one by one."""
+    S, Cl = lanes[0].shape
+    Cr = lanes[1].shape[1]
+    T = Cl + Cr
+    out = {name: [] for name in ("starts", "rights", "rstart", "pos_s",
+                                 "shard_total", "un_gid_sorted",
+                                 "un_counts", "is_left", "matchable")}
+    for s in range(S):
+        key = np.concatenate([lanes[0][s], lanes[1][s]])
+        nul = np.concatenate([null[0][s], null[1][s]])
+        pd_ = np.concatenate([pad[0][s], pad[1][s]])
+        side = np.repeat([0, 1], [Cl, Cr]).astype(np.int32)
+        order = np.lexsort((np.arange(T), side, key, nul, pd_))
+        key, nul, pd_, side = key[order], nul[order], pd_[order], side[order]
+        rights = np.zeros(T, np.int32)
+        rstart = np.zeros(T, np.int32)
+        lefts = np.zeros(T, np.int32)
+        first = 0
+        while first < T:
+            last = first
+            while (last + 1 < T and not (nul[last] or nul[last + 1]
+                                         or pd_[last] or pd_[last + 1])
+                   and key[last + 1] == key[first]):
+                last += 1
+            in_run = int(side[first:last + 1].sum())
+            rights[first:last + 1] = in_run
+            rstart[first:last + 1] = last - in_run + 1
+            lefts[first:last + 1] = last - first + 1 - in_run
+            first = last + 1
+        is_left = (side == 0) & ~pd_
+        matchable = is_left & ~nul
+        counts = np.where(matchable, rights, 0).astype(np.int64)
+        if left_outer:
+            counts = np.maximum(counts, is_left)
+        out["starts"].append(np.cumsum(counts) - counts)
+        out["shard_total"].append(counts.sum())
+        out["rights"].append(rights)
+        out["rstart"].append(rstart)
+        out["pos_s"].append(order.astype(np.int32))
+        out["is_left"].append(is_left)
+        out["matchable"].append(matchable)
+        if need_right:
+            unmatched = (side == 1) & ~pd_ & (nul | (lefts == 0))
+            gid = r_gid[s][np.clip(order - Cl, 0, Cr - 1)]
+            un = gid[unmatched]
+            out["un_gid_sorted"].append(np.concatenate(
+                [un, np.full(T - len(un), -1, np.int64)]))
+            out["un_counts"].append(len(un))
+    return {name: np.stack(v) if v else None for name, v in out.items()}
+
+
+@pytest.mark.parametrize("how", ["inner", "left_outer", "full_outer"])
+@pytest.mark.parametrize("S", [1, 2, 4])
+@pytest.mark.parametrize("case", sorted(BRACKET_CASES))
+def test_mesh_match_matches_a_loop_over_runs(case, S, how):
+    """The mesh match's run brackets are the one-chip match's scans
+    (`ops/join.run_brackets`) along each shard's row: its every output
+    equals a walk over each shard's key runs, element by element, on an
+    S-device mesh, with pad rows on both sides of every shard."""
+    import jax
+
+    from hyperspace_tpu.parallel.mesh import shard_rows
+
+    lanes, null, pad, r_gid = _mesh_match_layout(case, S)
+    left_outer, need_right = how != "inner", how == "full_outer"
+    rows = shard_rows(make_mesh(S))
+    args = jax.device_put(([lanes[0]], [lanes[1]], null[0], null[1],
+                           pad[0], pad[1], r_gid), rows)
+    got = jax.jit(spmd._match, static_argnums=(7, 8))(
+        *args, left_outer, need_right)
+    want = _loop_mesh_match(lanes, null, pad, r_gid, left_outer,
+                            need_right)
+    for name, g in zip(("starts", "rights", "rstart", "pos_s",
+                        "shard_total", "un_gid_sorted", "un_counts",
+                        "is_left", "matchable"), got):
+        w = want[name]
+        if w is None:
+            assert g is None, name
+            continue
+        g = np.asarray(g)
+        assert (g.shape, g.dtype) == (w.shape, w.dtype), name
+        np.testing.assert_array_equal(g, w, err_msg=name)
 
 
 @pytest.mark.parametrize("how", ["inner", "full_outer"])

@@ -673,6 +673,8 @@ class FusedStageExec(PhysicalNode):
     (host lane — early compaction wins on numpy)."""
 
     name = "FusedStage"
+    # set by `execute_masked` for the length of one execution
+    _takes_mask = None
 
     def __init__(self, root, sources: Sequence[_SourceExec], conf=None):
         self.root = root
@@ -695,6 +697,19 @@ class FusedStageExec(PhysicalNode):
         """Bucketed-protocol passthrough (regions never contain joins on
         this path — only Filter/Project chains support it)."""
         return self.root.execute_bucketed(num_buckets)
+
+    def execute_masked(self, takes_mask) -> Tuple[ColumnBatch, object]:
+        """`execute()` for a consumer that reads rows through a mask:
+        where the stage runs masked on the device and `takes_mask(batch)`
+        says yes to its full-length output, (that output, its selection
+        mask or None), with no sync, compaction or gather; else (the
+        compacted output, None)."""
+        self._takes_mask = takes_mask
+        try:
+            out = self.execute()
+        finally:
+            self._takes_mask = None
+        return out if isinstance(out, tuple) else (out, None)
 
     def execute(self, bucket: Optional[int] = None) -> ColumnBatch:
         if bucket is not None:
@@ -848,6 +863,9 @@ class FusedStageExec(PhysicalNode):
                 probe_rows=(int(sel.shape[0]) if sel is not None
                             else base.num_rows),
                 build_rows=build._batch.num_rows)
+        if (self._takes_mask is not None and not lazy_specs
+                and self._takes_mask(base)):
+            return base, sel
         idx = None
         if sel is not None:
             t0 = _time.perf_counter()

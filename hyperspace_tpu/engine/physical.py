@@ -86,7 +86,9 @@ def _instrument(fn, bucketed: bool):
             sp.set(rows=rows, lane=detail.get("lane"),
                    appended=detail.get("appended"),
                    index=detail.get("index"), source=detail.get("source"),
-                   files=detail.get("files_scanned"))
+                   files=detail.get("files_scanned"),
+                   path=detail.get("path"), groups=detail.get("groups"),
+                   masked=detail.get("masked"))
             if op is not None:
                 rec.finish_operator(op, rows_out=rows)
         # Operator-span boundary: fold a device-memory sample into the
@@ -937,28 +939,62 @@ class AggregateExec(PhysicalNode):
             specs.append(AggSpec(spec.func, name, spec.alias))
         return columnar.ColumnBatch(Schema(fields), columns), specs
 
-    def execute(self, bucket: Optional[int] = None) -> columnar.ColumnBatch:
-        from hyperspace_tpu.ops.aggregate import group_aggregate
+    def _mesh(self, batch: columnar.ColumnBatch):
+        """The mesh the aggregate distributes over, or None."""
         from hyperspace_tpu.parallel.context import should_distribute
-        batch = self.child.execute(bucket)
-        batch, specs = self._materialize_inputs(batch)
-        mesh = None
-        if (self.group_columns and batch.num_rows > 0 and specs
+        if (self.group_columns and batch.num_rows > 0 and self.aggregates
                 # count_distinct is not decomposable into mergeable
                 # per-shard partials (a value present on two shards must
                 # not count twice); it — and pure DISTINCT (no aggregate
                 # lanes) — stay on the single-device lane.
-                and not any(s.func == "count_distinct" for s in specs)):
-            mesh = should_distribute(self.conf, batch.num_rows,
+                and not any(s.func == "count_distinct"
+                            for s in self.aggregates)):
+            return should_distribute(self.conf, batch.num_rows,
                                      host_batch=batch.is_host)
+        return None
+
+    def _direct(self, batch: columnar.ColumnBatch):
+        """The key domains where the group-by takes direct address
+        (`ops/aggregate.direct_domains`), or None."""
+        from hyperspace_tpu.ops.aggregate import direct_domains
+        domains = direct_domains(batch, self.group_columns)
+        if domains is None or self._mesh(batch) is not None:
+            return None
+        return domains
+
+    def execute(self, bucket: Optional[int] = None) -> columnar.ColumnBatch:
+        from hyperspace_tpu.engine.fusion import FusedStageExec
+        from hyperspace_tpu.ops.aggregate import (direct_aggregate,
+                                                  group_aggregate)
+        mask = None
+        if bucket is None and isinstance(self.child, FusedStageExec):
+            # a direct-address group-by reads the stage's rows through
+            # its selection mask: no compaction
+            batch, mask = self.child.execute_masked(
+                lambda b: self._direct(b) is not None)
+        else:
+            batch = self.child.execute(bucket)
+        domains = self._direct(batch)
+        if domains is not None:
+            out = direct_aggregate(batch, self.group_columns,
+                                   self.aggregates, self.out_schema,
+                                   domains, row_mask=mask)
+            telemetry.annotate(groups=out.num_rows,
+                               masked=batch.num_rows if mask is not None
+                               else 0)
+            return out
+        batch, specs = self._materialize_inputs(batch)
+        mesh = self._mesh(batch)
         if mesh is not None:
             from hyperspace_tpu.parallel.aggregate import (
                 distributed_group_aggregate)
             return distributed_group_aggregate(batch, self.group_columns,
                                                specs,
                                                self.out_schema, mesh)
-        return group_aggregate(batch, self.group_columns, specs,
-                               self.out_schema)
+        out = group_aggregate(batch, self.group_columns, specs,
+                              self.out_schema)
+        telemetry.annotate(groups=out.num_rows, masked=0)
+        return out
 
 
 class LimitExec(PhysicalNode):

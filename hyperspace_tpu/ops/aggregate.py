@@ -1,12 +1,24 @@
-"""Device group-by aggregation: sort-based segment reductions.
+"""Device group-by aggregation: direct-address slots, or sort-based
+segment reductions.
 
-The reference leaves aggregation to Spark SQL's hash/sort aggregates; here
-groups are formed by ONE stable multi-key sort (32-bit lanes) and reduced
-with XLA segment ops — TPU-friendly: no scatter contention, fully
-vectorized, one host sync (the group count) to size the output. One
-group (a global aggregate) is reduced by plain reductions instead: a
-segment op into one slot is a scatter that adds every row into it in
-turn.
+The reference leaves aggregation to Spark SQL's hash/sort aggregates.
+Here a grouping whose keys all have a small domain known from their
+type (a dictionary's codes, a bool, an int8; a nullable key has one
+value more) and whose domains multiply to at most
+`DIRECT_GROUP_MAX_SLOTS` is reduced by DIRECT ADDRESS: a row's group is
+the mixed-radix code of its keys, computed on the device, and every
+aggregate is reduced slot by slot by masked reductions, which XLA
+lowers to trees, in ONE program (`_direct_reduce`): no sort, no row
+gather, no scatter. Rows may come with a mask (a filter's selection
+handed over uncompacted); a masked row belongs to no slot. The one
+host sync is the slots' row counts; empty slots are dropped after it,
+and the groups come out in key order, as the sort path gives them.
+
+Every other grouping is formed by ONE stable multi-key sort (32-bit
+lanes) and reduced with XLA segment ops, one host sync (the group
+count) sizing the output. One group (a global aggregate) is reduced by
+plain reductions instead: a segment op into one slot is a scatter that
+adds every row into it in turn.
 
 SQL null semantics: sum/min/max/avg ignore null inputs; count(col) counts
 non-null; count(*) counts rows; a group whose inputs are all null yields
@@ -31,7 +43,8 @@ import numpy as np
 
 import hyperspace_tpu._jax_config  # noqa: F401
 from hyperspace_tpu.exceptions import HyperspaceException
-from hyperspace_tpu.io.columnar import ColumnBatch, DeviceColumn
+from hyperspace_tpu.io.columnar import (HOST_NP_DTYPES, ColumnBatch,
+                                        DeviceColumn)
 from hyperspace_tpu.plan.nodes import AggSpec
 from hyperspace_tpu.plan.schema import Schema
 from hyperspace_tpu import telemetry
@@ -110,11 +123,14 @@ def _exact_moments(values, valid, segment_ids, num_groups: int):
     """Per-group count, sum and sum of squares of an integer column in
     int64, and the largest magnitude in it (which says on the host
     whether the squares overflowed)."""
-    import jax
+    return _moments(values, valid, segment_ids, _SegmentReduce(num_groups))
+
+
+def _moments(values, valid, ids, reduce):
     import jax.numpy as jnp
 
     x = jnp.where(valid, values, 0).astype(jnp.int64)
-    moments = [jax.ops.segment_sum(y, segment_ids, num_segments=num_groups)
+    moments = [reduce.sum(y, ids)
                for y in (valid.astype(jnp.int64), x, x * x)]
     return (*moments, jnp.max(jnp.abs(x).astype(jnp.uint64)))
 
@@ -122,11 +138,12 @@ def _exact_moments(values, valid, segment_ids, num_groups: int):
 _EXACT = 1 << 53  # integers float64 holds exactly
 
 
-def _exact_candidate(spec: AggSpec, src: DeviceColumn, out_dtype: str):
-    """avg / stddev of an integer column with a float64 result."""
-    return (spec.func in ("avg", "stddev") and out_dtype == "float64"
-            and not src.is_string
-            and np.dtype(src.raw.dtype).kind in "iu")
+def _exact_candidate(func: str, dtype: str, out_dtype: str):
+    """avg / stddev of an integer column with a float64 result (by the
+    logical dtype: a float64 column carries its bits as int64)."""
+    return (func in ("avg", "stddev") and out_dtype == "float64"
+            and dtype != "string"
+            and np.dtype(HOST_NP_DTYPES[dtype]).kind in "iu")
 
 
 def _finish_exact(func: str, n, total, squares, amax: int):
@@ -154,6 +171,10 @@ def _finish_exact(func: str, n, total, squares, amax: int):
 def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
                     aggregates: Sequence[AggSpec],
                     out_schema: Schema) -> ColumnBatch:
+    """The sort path (`path` on the operator's record: `sorted`, `hashed`
+    for a grouping of HASH_GROUP_MIN_LANES lanes and more, `global` for
+    none) and the host lane's mirror of it."""
+    telemetry.annotate(path="sorted" if group_columns else "global")
     if batch.is_host and batch.num_rows > 0:
         return _host_group_aggregate(batch, group_columns, aggregates,
                                      out_schema)
@@ -215,6 +236,7 @@ def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
         # Separate eager ops would each pay their own compile.
         ops = tuple(jnp.asarray(op) for op in operands)
         if len(ops) >= HASH_GROUP_MIN_LANES:
+            telemetry.annotate(path="hashed")
             perm, segment_ids, packed = _group_phase_a_hashed(ops)
             packed = int(packed)  # the one host sync
             if packed & 1:  # hash collision split a group: exact re-run
@@ -240,7 +262,8 @@ def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
         if spec.column == "*":
             continue
         src = sorted_batch.column(spec.column)
-        if _exact_candidate(spec, src, out_schema.field(spec.alias).dtype):
+        if _exact_candidate(spec.func, src.dtype,
+                            out_schema.field(spec.alias).dtype):
             valid = (src.validity if src.validity is not None
                      else jnp.ones(n, dtype=bool))
             funcs[spec.alias] = spec.func
@@ -284,24 +307,236 @@ def group_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
     return ColumnBatch(out_schema, columns)
 
 
+# Direct address serves a grouping of at most this many slots (the
+# product of its keys' domains). On a TPU v5e at 59 M rows, one float64
+# sum and a count (PERF.md section 6): direct 19 ms at 4 slots, 164 at
+# 256, 625 at 1024; the sort path 14.4 s whatever the slots, so the two
+# cross near 20,000. 256 stays far under that and serves an int8 key.
+DIRECT_GROUP_MAX_SLOTS = 256
+
+
+def _key_domain(col: DeviceColumn):
+    """The values a group key can take, known from its type (a null one
+    more), or None."""
+    if col.is_string:
+        domain = len(col.dictionary)
+    elif col.dtype == "bool":
+        domain = 2
+    elif col.dtype == "int8":
+        domain = 256
+    else:
+        return None
+    return domain + (col.validity is not None)
+
+
+def direct_domains(batch: ColumnBatch, group_columns: Sequence[str]):
+    """Each group column's domain where direct address serves the
+    grouping of `batch` (device lane, every key of known domain, at most
+    DIRECT_GROUP_MAX_SLOTS slots in all); else None."""
+    if not group_columns or batch.num_rows == 0 or batch.is_host:
+        return None
+    domains = tuple(_key_domain(batch.column(c)) for c in group_columns)
+    if None in domains or int(np.prod(domains)) > DIRECT_GROUP_MAX_SLOTS:
+        return None
+    return domains
+
+
+class _DirectPlan:
+    """What shapes one direct-address program, hashable for jit by
+    `key`: the input's schema and dictionaries (`aux`), each group key's
+    (name, domain, nullable), and each aggregate's (func, input, dtype,
+    out_dtype, exact), its input a column name, "*" or an Expression
+    computed inside the program."""
+
+    def __init__(self, batch: ColumnBatch, group_columns, domains,
+                 aggregates, out_schema):
+        import json
+
+        self.schema = batch.schema
+        self.aux = {f.name: batch.columns[f.name].dictionary
+                    for f in batch.schema.fields}
+        self.keys = tuple(
+            (batch.schema.field(c).name, d,
+             batch.column(c).validity is not None)
+            for c, d in zip(group_columns, domains))
+        self.slots = int(np.prod(domains))
+        self.specs = []
+        for spec in aggregates:
+            out_dtype = out_schema.field(spec.alias).dtype
+            if not spec.is_expression and spec.column == "*":
+                self.specs.append(("count_rows", "*", None, "int64", False))
+                continue
+            dtype = spec.input_dtype(batch.schema)
+            if not spec.is_expression:
+                src = batch.column(spec.column)
+                if src.is_string and spec.func not in ("count",
+                                                       "count_distinct"):
+                    raise HyperspaceException(
+                        f"Aggregate {spec.func} over string column "
+                        f"{spec.column} is not supported.")
+            self.specs.append((spec.func, spec.column, dtype, out_dtype,
+                               _exact_candidate(spec.func, dtype,
+                                                out_dtype)))
+        # an expression over a string column bakes its dictionary into
+        # the program: keyed by content (a union's dictionary is a new
+        # array every query)
+        read = set()
+        for spec in aggregates:
+            if spec.is_expression:
+                read |= {batch.schema.field(c).name
+                         for c in spec.references()}
+        self.key = json.dumps([
+            [(f.name, f.dtype,
+              batch.columns[f.name].validity is not None,
+              _dictionary_key(batch.columns[f.name].dictionary)
+              if f.name in read else None)
+             for f in batch.schema.fields],
+            self.keys,
+            [(func, c.to_dict() if hasattr(c, "to_dict") else c, d, o, e)
+             for func, c, d, o, e in self.specs]], sort_keys=True)
+
+    def __hash__(self):
+        return hash(self.key)
+
+    def __eq__(self, other):
+        return isinstance(other, _DirectPlan) and other.key == self.key
+
+
+def _dictionary_key(dictionary):
+    if dictionary is None:
+        return None
+    import hashlib
+    return hashlib.blake2b("\x1f".join(map(str, dictionary)).encode(),
+                           digest_size=16).hexdigest()
+
+
+@instrumented_jit("aggregate.direct_reduce", scope="hs.aggregate",
+                  static_argnames=("plan",))
+def _direct_reduce(plan: _DirectPlan, tree, row_mask):
+    """The direct-address group-by as ONE program: each row's slot (the
+    mixed-radix code of its keys, first key most significant, a null
+    key 0; a row `row_mask` drops goes to no slot), each expression
+    input, and every aggregate per slot (`_SlotReduce`). Returns (the
+    rows of each slot, the aggregates as `_reduce_plan` gives them in
+    float, the exact integer moments of each `exact` spec)."""
+    import jax.numpy as jnp
+
+    from hyperspace_tpu.engine.compiler import ExpressionCompiler
+    from hyperspace_tpu.io.columnar import tree_to_batch
+
+    batch = tree_to_batch(tree, plan.schema, plan.aux)
+    n = batch.num_rows
+    slot = jnp.zeros(n, dtype=jnp.int32)
+    for name, domain, nullable in plan.keys:
+        col = batch.column(name)
+        code = col.raw.astype(jnp.int32) + (128 if col.dtype == "int8"
+                                            else 0)
+        if nullable:
+            code = jnp.where(col.validity, code + 1, 0)
+        slot = slot * domain + code
+    if row_mask is not None:
+        slot = jnp.where(row_mask, slot, plan.slots)
+    reduce = _SlotReduce(plan.slots)
+    compiler = ExpressionCompiler(batch)
+    columns, specs, moments = [], [], []
+    for func, column, dtype, out_dtype, exact in plan.specs:
+        specs.append((func, dtype, out_dtype, False))
+        if func == "count_rows":
+            columns.append(None)
+            continue
+        src = (compiler.value_column(column, dtype)
+               if not isinstance(column, str) else batch.column(column))
+        valid = src.validity
+        if row_mask is not None:
+            valid = row_mask if valid is None else valid & row_mask
+        columns.append((src.raw, valid))
+        if exact:
+            moments.append(_moments(
+                src.data, valid if valid is not None
+                else jnp.ones(n, dtype=bool), slot, reduce))
+    rows = reduce.sum(jnp.ones(n, dtype=jnp.int32), slot)
+    return rows, _reduce_plan(tuple(specs), columns, slot, reduce), moments
+
+
+@instrumented_jit("aggregate.direct_slots", scope="hs.aggregate")
+def _take_slots(outputs, used):
+    """The used slots of every per-slot output."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree_util.tree_map(lambda a: jnp.take(a, used), outputs)
+
+
+def direct_aggregate(batch: ColumnBatch, group_columns: Sequence[str],
+                     aggregates: Sequence[AggSpec], out_schema: Schema,
+                     domains, row_mask=None) -> ColumnBatch:
+    """The group-by of `batch` by direct address (module docstring), for
+    the `domains` `direct_domains` gave. An aggregate's input may be an
+    Expression (computed inside the program); `row_mask` (bool, one a
+    row) drops the rows it is False for: a filter's selection handed
+    over uncompacted. The one host sync is each slot's row count (with
+    the exact moments); the used slots, in key order, are the groups."""
+    import jax
+    import jax.numpy as jnp
+
+    from hyperspace_tpu.io.columnar import batch_to_tree
+
+    needed = {batch.schema.field(c).name for c in group_columns}
+    for spec in aggregates:
+        needed |= {batch.schema.field(c).name for c in spec.references()}
+    sub = batch.select([f.name for f in batch.schema.fields
+                        if f.name in needed])
+    plan = _DirectPlan(sub, group_columns, domains, aggregates, out_schema)
+    tree, _ = batch_to_tree(sub, computes_on=())
+    rows, outputs, moments = _direct_reduce(plan, tree, row_mask)
+    rows, moments = jax.device_get((rows, moments))  # the one host sync
+    telemetry.annotate(path="direct")
+    telemetry.get_registry().counter("aggregate.direct.rows").inc(
+        batch.num_rows)
+    used = np.flatnonzero(rows)
+    outputs = _take_slots(outputs, jnp.asarray(used.astype(np.int32)))
+    moments = iter(moments)
+
+    columns = {}
+    digits = used
+    for name, domain, nullable in reversed(plan.keys):
+        src = batch.column(name)
+        code = digits % domain
+        digits = digits // domain
+        valid = None
+        if nullable:
+            valid, code = code > 0, np.maximum(code - 1, 0)
+        if src.dtype == "int8":
+            code = code - 128
+        columns[name] = src.with_raw(
+            jnp.asarray(code.astype(HOST_NP_DTYPES[src.dtype])),
+            jnp.asarray(valid) if valid is not None else None)
+    for spec, (func, _, _, out_dtype, exact), (data, validity) in zip(
+            aggregates, plan.specs, outputs):
+        if exact:
+            count, total, squares, amax = (np.asarray(m)[used]
+                                           if np.ndim(m) else m
+                                           for m in next(moments))
+            finished = _finish_exact(func, count, total, squares, amax)
+            if finished is not None:  # else the float value serves
+                data = jnp.asarray(finished.view(np.int64))
+        dtype = ("int64" if func in ("count", "count_distinct", "count_rows")
+                 else out_dtype)
+        columns[out_schema.field(spec.alias).name] = DeviceColumn(
+            data, dtype, validity=validity)
+    return ColumnBatch(out_schema, {f.name: columns[f.name]
+                                    for f in out_schema.fields})
+
+
 @instrumented_jit("aggregate.group_finish", scope="hs.aggregate",
                   static_argnames=("plan", "num_groups"))
 def _group_finish(segment_ids, keys, columns, plan, num_groups: int):
     """Every reduction of the group-by after its grouping sort, as ONE
     program: each group's first row (a `searchsorted` of the sorted
     segment ids) and its keys out of `keys`, then each aggregate of
-    `plan` — `(func, dtype, out_dtype, exact)` a spec, func `count_rows`
-    for count(*) — over its `columns` entry, the (raw, validity) of its
-    sorted column. An `exact` spec (integer avg / stddev) returns only its validity; the
-    host has finished its value (`_finish_exact`). SQL null semantics
-    as the module docstring gives them."""
-    import jax
+    `plan` over its `columns` entry (`_reduce_plan`)."""
     import jax.numpy as jnp
 
-    from hyperspace_tpu.io.columnar import HOST_NP_DTYPES
-    from hyperspace_tpu.ops.keys import column_sort_lanes
-
-    rows = segment_ids.shape[0]
     firsts = jnp.searchsorted(segment_ids,
                               jnp.arange(num_groups, dtype=jnp.int32),
                               side="left")
@@ -309,49 +544,119 @@ def _group_finish(segment_ids, keys, columns, plan, num_groups: int):
         (jnp.take(raw, firsts),
          jnp.take(validity, firsts) if validity is not None else None)
         for raw, validity in keys)
-
     if num_groups == 1:
         # One group (a global aggregate): a reduction a column, which
         # XLA lowers to a tree. A scatter into one slot adds the rows in
         # turn, which on the chip is serial and, in its f32-pair
         # float64, loses about 1e-12 of a sum of a million products
         # (a tree: about 1e-15).
-        def segment_sum(x):
-            return jnp.sum(x, axis=0, keepdims=True)
-
-        def segment_min(x):
-            return jnp.min(x, axis=0, keepdims=True)
-
-        def segment_max(x):
-            return jnp.max(x, axis=0, keepdims=True)
+        reduce = _GlobalReduce()
     else:
-        def segment_sum(x):
-            return jax.ops.segment_sum(x, segment_ids,
-                                       num_segments=num_groups)
+        reduce = _SegmentReduce(num_groups)
+    return out_keys, _reduce_plan(plan, columns, segment_ids, reduce)
 
-        def segment_min(x):
-            return jax.ops.segment_min(x, segment_ids,
-                                       num_segments=num_groups)
 
-        def segment_max(x):
-            return jax.ops.segment_max(x, segment_ids,
-                                       num_segments=num_groups)
+class _GlobalReduce:
+    """One group: plain reductions."""
 
+    def sum(self, x, ids):
+        import jax.numpy as jnp
+        return jnp.sum(x, axis=0, keepdims=True)
+
+    def min(self, x, ids):
+        import jax.numpy as jnp
+        return jnp.min(x, axis=0, keepdims=True)
+
+    def max(self, x, ids):
+        import jax.numpy as jnp
+        return jnp.max(x, axis=0, keepdims=True)
+
+    def back(self, per_group, ids):
+        """Each row's group's value."""
+        import jax.numpy as jnp
+        return jnp.take(per_group, ids)
+
+
+class _SegmentReduce(_GlobalReduce):
+    """Sorted segment ids: XLA's segment ops."""
+
+    def __init__(self, num_groups: int):
+        self.n = num_groups
+
+    def sum(self, x, ids):
+        import jax
+        return jax.ops.segment_sum(x, ids, num_segments=self.n)
+
+    def min(self, x, ids):
+        import jax
+        return jax.ops.segment_min(x, ids, num_segments=self.n)
+
+    def max(self, x, ids):
+        import jax
+        return jax.ops.segment_max(x, ids, num_segments=self.n)
+
+
+class _SlotReduce(_GlobalReduce):
+    """Direct-address slot ids in any order, `slots` of them; a row whose
+    id is `slots` (masked) belongs to none. Each slot is a masked
+    reduction over all rows, which XLA lowers to a tree."""
+
+    def __init__(self, slots: int):
+        self.n = slots
+
+    def _per_slot(self, fn, x, ids, fill):
+        import jax.numpy as jnp
+        hit = ids[None, :] == jnp.arange(self.n, dtype=ids.dtype)[:, None]
+        return fn(jnp.where(hit, x[None, :], fill), axis=1)
+
+    def sum(self, x, ids):
+        import jax.numpy as jnp
+        return self._per_slot(jnp.sum, x, ids, jnp.zeros((), x.dtype))
+
+    def min(self, x, ids):
+        import jax.numpy as jnp
+        return self._per_slot(jnp.min, x, ids, _dtype_max(x.dtype))
+
+    def max(self, x, ids):
+        import jax.numpy as jnp
+        return self._per_slot(jnp.max, x, ids, _dtype_min(x.dtype))
+
+    def back(self, per_group, ids):
+        import jax.numpy as jnp
+        padded = jnp.concatenate([per_group, jnp.zeros(1, per_group.dtype)])
+        return jnp.take(padded, ids)
+
+
+def _reduce_plan(plan, columns, ids, reduce):
+    """Each aggregate of `plan` — `(func, dtype, out_dtype, exact)` a
+    spec, func `count_rows` for count(*) — over its `columns` entry,
+    the (raw, validity) of its column, grouped by `ids` through
+    `reduce`. An `exact` spec (integer avg / stddev) returns only its
+    validity; the host has finished its value (`_finish_exact`). SQL
+    null semantics as the module docstring gives them."""
+    import jax
+    import jax.numpy as jnp
+
+    from hyperspace_tpu.io.columnar import HOST_NP_DTYPES
+    from hyperspace_tpu.ops.keys import column_sort_lanes
+
+    rows = ids.shape[0]
     out = []
     for (func, dtype, out_dtype, exact), entry in zip(plan, columns):
         if func == "count_rows":
-            out.append((segment_sum(jnp.ones(rows, dtype=jnp.int64)), None))
+            out.append((reduce.sum(jnp.ones(rows, dtype=jnp.int64), ids),
+                        None))
             continue
         src = DeviceColumn(entry[0], dtype, entry[1])
         valid = (src.validity if src.validity is not None
                  else jnp.ones(rows, dtype=bool))
-        counts = segment_sum(valid.astype(jnp.int64))
+        counts = reduce.sum(valid.astype(jnp.int64), ids)
         if func == "count":
             out.append((counts, None))
             continue
         if func == "count_distinct":
             # Distinct non-null values per group: ONE more device sort
-            # keyed (segment, invalid-last, *value lanes), then count run
+            # keyed (group, invalid-last, *value lanes), then count run
             # starts at valid rows. Strings count by dictionary code
             # (dictionaries are sorted+unique, so code identity is value
             # identity); nulls sort after the valid block so a shared
@@ -360,7 +665,7 @@ def _group_finish(segment_ids, keys, columns, plan, num_groups: int):
             invalid = (~valid).astype(jnp.int32)
             # Bounded width (one column: <= 5 operands) — the single
             # fused sort also returns the sorted lanes.
-            res = jax.lax.sort([segment_ids, invalid, *lanes],
+            res = jax.lax.sort([ids, invalid, *lanes],
                                num_keys=2 + len(lanes))
             seg_s, inv_s, lanes_s = res[0], res[1], res[2:]
             differs = seg_s[1:] != seg_s[:-1]
@@ -368,9 +673,8 @@ def _group_finish(segment_ids, keys, columns, plan, num_groups: int):
                 differs = differs | (lane[1:] != lane[:-1])
             run_start = jnp.concatenate(
                 [jnp.ones(1, dtype=bool), differs])
-            data = jax.ops.segment_sum(
-                (run_start & (inv_s == 0)).astype(jnp.int64), seg_s,
-                num_segments=num_groups)
+            data = reduce.sum((run_start & (inv_s == 0)).astype(jnp.int64),
+                              seg_s)
             out.append((data, None))
             continue
         validity_out = counts > 0
@@ -382,8 +686,8 @@ def _group_finish(segment_ids, keys, columns, plan, num_groups: int):
         if func in ("sum", "avg"):
             acc_dtype = (jnp.float64 if out_dtype == "float64"
                          else jnp.int64)
-            total = segment_sum(
-                jnp.where(valid, values, 0).astype(acc_dtype))
+            total = reduce.sum(
+                jnp.where(valid, values, 0).astype(acc_dtype), ids)
             if func == "sum":
                 data = total
             else:
@@ -396,22 +700,22 @@ def _group_finish(segment_ids, keys, columns, plan, num_groups: int):
             # 2 non-null inputs.
             x = jnp.where(valid, values, 0).astype(jnp.float64)
             cnt = counts.astype(jnp.float64)
-            mu = segment_sum(x) / jnp.maximum(cnt, 1)
-            dev = jnp.where(valid, x - jnp.take(mu, segment_ids), 0.0)
-            var = segment_sum(dev * dev) / jnp.maximum(cnt - 1, 1)
+            mu = reduce.sum(x, ids) / jnp.maximum(cnt, 1)
+            dev = jnp.where(valid, x - reduce.back(mu, ids), 0.0)
+            var = reduce.sum(dev * dev, ids) / jnp.maximum(cnt - 1, 1)
             data = jnp.sqrt(jnp.maximum(var, 0.0))
             validity_out = counts > 1
         elif func == "min":
             big = _dtype_max(values.dtype)
-            data = segment_min(jnp.where(valid, values, big))
+            data = reduce.min(jnp.where(valid, values, big), ids)
         else:  # max
             small = _dtype_min(values.dtype)
-            data = segment_max(jnp.where(valid, values, small))
+            data = reduce.max(jnp.where(valid, values, small), ids)
         # Validity is attached unconditionally: deciding with
         # `bool(any(~validity_out))` would cost one blocking device sync
         # per aggregate; an all-True mask is semantically identical.
         out.append((data.astype(HOST_NP_DTYPES[out_dtype]), validity_out))
-    return out_keys, tuple(out)
+    return tuple(out)
 
 
 def _dtype_max(dtype):
@@ -511,7 +815,7 @@ def _host_group_aggregate(batch: ColumnBatch,
         values = np.asarray(src.data)
         validity_out = counts > 0
         data = None
-        if _exact_candidate(spec, src, out_field.dtype):
+        if _exact_candidate(spec.func, src.dtype, out_field.dtype):
             # the device lane's exact moments, in numpy
             x = np.where(valid, values, 0).astype(np.int64)
             data = _finish_exact(spec.func, counts,

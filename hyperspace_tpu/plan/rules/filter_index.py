@@ -1,7 +1,10 @@
 """FilterIndexRule: redirect filter queries to covering indexes.
 
 Parity: reference `index/rules/FilterIndexRule.scala:41-229`.
-- Matches `Project(Filter(Scan))` and bare `Filter(Scan)`.
+- Matches `Project(Filter(Scan))` and bare `Filter(Scan)`; an
+  `Aggregate(Filter(Scan))` is judged on the columns the aggregate reads,
+  as Spark's column pruning puts a Project of them under the aggregate
+  before the reference's rule runs.
 - Candidate = ACTIVE index whose signature matches the plan AND that covers
   it: the filter must reference the index's FIRST indexed column, and
   project+filter columns must be a subset of indexed+included columns
@@ -22,7 +25,8 @@ from typing import List, Optional, Sequence
 
 from hyperspace_tpu import telemetry
 from hyperspace_tpu.index.log_entry import IndexLogEntry
-from hyperspace_tpu.plan.nodes import Filter, LogicalPlan, Project, Scan
+from hyperspace_tpu.plan.nodes import (Aggregate, Filter, LogicalPlan,
+                                       Project, Scan)
 from hyperspace_tpu.plan.rules.base import Rule, _version_of_root
 
 logger = logging.getLogger(__name__)
@@ -84,6 +88,8 @@ class FilterIndexRule(Rule):
             return plan
 
     def _rewrite(self, node: LogicalPlan) -> LogicalPlan:
+        if isinstance(node, Aggregate):
+            return self._rewrite_aggregate(node)
         # Project(Filter(Scan)) or Filter(Scan)
         if isinstance(node, Project) and isinstance(node.child, Filter) \
                 and isinstance(node.child.child, Scan):
@@ -104,13 +110,7 @@ class FilterIndexRule(Rule):
         index = self._find_covering_index(filt, scan, project_columns,
                                           filter_columns)
         if index is not None:
-            source: LogicalPlan = self.index_scan(index, bucketed=True)
-            logger.info("FilterIndexRule: applying index %s", index.name)
-            telemetry.event(
-                "rule", "FilterIndexRule", action="applied",
-                indexes=[{"name": index.name, "root": index.content.root,
-                          "num_buckets": index.num_buckets,
-                          "side": "filter"}])
+            source: LogicalPlan = self._index_source(index)
         else:
             source = self._hybrid_scan_source(filt, scan, project_columns,
                                               filter_columns)
@@ -152,6 +152,38 @@ class FilterIndexRule(Rule):
             # of them, whatever the operators above it read.
             rewritten = Project(scan.schema.names, rewritten)
         return rewritten
+
+    def _index_source(self, index: IndexLogEntry) -> LogicalPlan:
+        logger.info("FilterIndexRule: applying index %s", index.name)
+        telemetry.event(
+            "rule", "FilterIndexRule", action="applied",
+            indexes=[{"name": index.name, "root": index.content.root,
+                      "num_buckets": index.num_buckets, "side": "filter"}])
+        return self.index_scan(index, bucketed=True)
+
+    def _rewrite_aggregate(self, node: Aggregate) -> LogicalPlan:
+        """Aggregate(Filter(Scan)) served by a covering index of the
+        columns the aggregate reads: Aggregate(Project(those,
+        Filter(index scan))). Without one the node stays, and the bare
+        Filter(Scan) below it is matched as before (the hybrid and
+        skipping rewrites among it)."""
+        filt = node.child
+        if not (isinstance(filt, Filter) and isinstance(filt.child, Scan)
+                and filt.child.bucket_spec is None):
+            return node
+        scan = filt.child
+        read = {c.lower() for c in node.group_columns}
+        for spec in node.aggregates:
+            read |= {c.lower() for c in spec.references()}
+        needed = [c for c in scan.schema.names if c.lower() in read]
+        if not needed or len(needed) == len(scan.schema.names):
+            return node
+        index = self._find_covering_index(
+            filt, scan, needed, sorted(filt.condition.references()))
+        if index is None:
+            return node
+        return node.with_children([Project(needed, Filter(
+            filt.condition, self._index_source(index)))])
 
     def _skipping_enabled(self) -> bool:
         conf = getattr(self.session, "conf", None)
